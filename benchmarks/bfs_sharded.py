@@ -56,7 +56,7 @@ import os
 import sys
 import time
 
-from benchmarks.common import row, rung_filter
+from benchmarks.common import eight_device_payload, row, rung_filter
 
 _MARK = "BFS_SHARDED_JSON:"
 _PAYLOAD: dict = {}
@@ -442,22 +442,7 @@ def _run_mp_rungs(scale: int) -> dict:
 
 def run():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    from repro.util import respawn_with_host_devices
-
-    proc = respawn_with_host_devices(
-        [sys.executable, "-m", "benchmarks.bfs_sharded", "--child"], 8,
-        pythonpath=(os.path.join(repo, "src"), repo),
-        capture=True, cwd=repo, timeout=7200)
-    if proc.returncode != 0:
-        raise RuntimeError(f"sharded benchmark child failed:\n"
-                           f"{proc.stderr[-4000:]}")
-    payload = None
-    for line in proc.stdout.splitlines():
-        if line.startswith(_MARK):
-            payload = json.loads(line[len(_MARK):])
-    if payload is None:
-        raise RuntimeError(f"no payload marker in child stdout:\n"
-                           f"{proc.stdout[-2000:]}")
+    payload = eight_device_payload("bfs_sharded", _child, _MARK)
     # mp rungs run from THIS process — the launcher owns the worker
     # gang's device views; the 8-device child never sees them
     payload["multiprocess"] = _run_mp_rungs(payload["scale"])

@@ -1,7 +1,9 @@
 """Shared benchmark utilities."""
 from __future__ import annotations
 
+import json
 import os
+import sys
 import time
 
 import numpy as np
@@ -42,3 +44,33 @@ def row(name: str, us_per_call: float, derived: str) -> dict:
 def print_rows(rows):
     for r in rows:
         print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")
+
+
+def eight_device_payload(module: str, child, mark: str) -> dict:
+    """The JSON payload of a benchmark body written for 8 devices.
+
+    On the CPU, ``benchmarks.<module> --child`` runs in a child process
+    with 8 forced host devices (this process's JAX already has its own
+    device view) and prints its payload after ``mark``.  On an
+    accelerator this process holds the chips, so ``child()`` runs here,
+    on ``jax.devices()``.
+    """
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return json.loads(json.dumps(child()))  # the child's JSON types
+    from repro.util import respawn_with_host_devices
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = respawn_with_host_devices(
+        [sys.executable, "-m", f"benchmarks.{module}", "--child"], 8,
+        pythonpath=(os.path.join(repo, "src"), repo),
+        capture=True, cwd=repo, timeout=7200)
+    if proc.returncode != 0:
+        raise RuntimeError(f"{module} benchmark child failed:\n"
+                           f"{proc.stderr[-4000:]}")
+    for line in reversed(proc.stdout.splitlines()):
+        if line.startswith(mark):
+            return json.loads(line[len(mark):])
+    raise RuntimeError(f"no payload marker in child stdout:\n"
+                       f"{proc.stdout[-2000:]}")

@@ -94,7 +94,6 @@ def _write_json(payloads: dict) -> None:
     try:
         from repro.kernels import ops as kops
         doc["interpret_mode"] = kops.interpret_mode()
-        doc["interpret_mode_source"] = kops.interpret_mode_source()
     except Exception:
         pass
     with open(BENCH_JSON, "w") as f:
@@ -113,6 +112,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.rungs:
         os.environ["BENCH_RUNGS"] = args.rungs
+    from repro.util import use_compile_cache
+    use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     want = args.modules or MODULES
     print("name,us_per_call,derived")
     failures = []

@@ -33,7 +33,7 @@ import os
 import sys
 import time
 
-from benchmarks.common import row, rung_filter
+from benchmarks.common import eight_device_payload, row, rung_filter
 
 _MARK = "SSSP_JSON:"
 _PAYLOAD: dict = {}
@@ -180,22 +180,7 @@ def _fold_by_scale(payload: dict, repo: str) -> dict:
 
 def run():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    from repro.util import respawn_with_host_devices
-
-    proc = respawn_with_host_devices(
-        [sys.executable, "-m", "benchmarks.sssp", "--child"], 8,
-        pythonpath=(os.path.join(repo, "src"), repo),
-        capture=True, cwd=repo, timeout=7200)
-    if proc.returncode != 0:
-        raise RuntimeError(f"sssp benchmark child failed:\n"
-                           f"{proc.stderr[-4000:]}")
-    payload = None
-    for line in proc.stdout.splitlines():
-        if line.startswith(_MARK):
-            payload = json.loads(line[len(_MARK):])
-    if payload is None:
-        raise RuntimeError(f"no payload marker in child stdout:\n"
-                           f"{proc.stdout[-2000:]}")
+    payload = eight_device_payload("sssp", _child, _MARK)
     _SELECTED.clear()
     _SELECTED.update(payload.get("rungs_matched", []))
     _PAYLOAD.update(_fold_by_scale(payload, repo))

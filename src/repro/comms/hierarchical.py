@@ -36,7 +36,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import faults
 from repro.kernels.ref import popcount_u32
-from repro.util import axis_size, shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -59,8 +58,8 @@ def hierarchical_all_to_all(
     destination *member index* is m — the monitor collection step.
     Phase 2 (mirror group): monitors exchange across groups.
     """
-    g = axis_size(group_axis)
-    m = axis_size(member_axis)
+    g = lax.axis_size(group_axis)
+    m = lax.axis_size(member_axis)
     shape = x.shape
     blocks = shape[split_axis]
     assert blocks % (g * m) == 0, (blocks, g, m)
@@ -96,7 +95,7 @@ def hierarchical_psum(x, group_axis: str, member_axis: str):
     Equal to ``psum(x, (group, member))`` but each inter-group link carries
     1/M of the gradient bytes (the monitor forwards its shard only).
     """
-    m = axis_size(member_axis)
+    m = lax.axis_size(member_axis)
     lead = x.shape[0]
     if lead % m != 0:
         # fall back: reduce within group first, then across (still 2-phase)
@@ -121,7 +120,7 @@ def compressed_hierarchical_psum(x, group_axis: str, member_axis: str,
     """
     if jnp.issubdtype(x.dtype, jnp.integer) or x.dtype == jnp.bool_:
         return hierarchical_psum(x, group_axis, member_axis)
-    m = axis_size(member_axis)
+    m = lax.axis_size(member_axis)
     lead = x.shape[0]
     orig = x.dtype
     if lead % m != 0:
@@ -140,7 +139,7 @@ def _or_reduce_scatter(x, axis_name: str):
     destination-major blocks, then fold OR locally.  Bytes on the wire are
     identical to ``psum_scatter`` (each device sends lead/n to each peer).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     lead = x.shape[0]
     assert lead % n == 0, (lead, n)
     blocks = x.reshape(n, lead // n, *x.shape[1:])
@@ -162,7 +161,7 @@ def _or_all_reduce(x, axis_name: str, *, fault=None, level=None,
     reduced axis, so the SPMD loop stays uniform) — the dropped-forward
     failure mode of the monitor exchange.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     g = lax.all_gather(x, axis_name, axis=0, tiled=False)
     out = g[0]
     for i in range(1, n):
@@ -186,7 +185,7 @@ def hierarchical_por(x, group_axis: str, member_axis: str, *,
     if not (jnp.issubdtype(x.dtype, jnp.integer) or x.dtype == jnp.bool_):
         raise TypeError(f"hierarchical_por is for integer/bool payloads, "
                         f"got {x.dtype}")
-    m = axis_size(member_axis)
+    m = lax.axis_size(member_axis)
     if x.shape[0] % m != 0:
         # fall back: OR within group first, then across (still two-phase)
         x = _or_all_reduce(x, member_axis)
@@ -221,7 +220,7 @@ def _min_reduce_scatter(x, axis_name: str):
     no MIN flavor of ``psum_scatter`` either): all-to-all the
     destination-major blocks, fold min locally.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     lead = x.shape[0]
     assert lead % n == 0, (lead, n)
     blocks = x.reshape(n, lead // n, *x.shape[1:])
@@ -242,7 +241,7 @@ def _min_all_reduce(x, axis_name, *, fault=None, level=None,
     it fires, every receiver keeps only the axis-index-0 contribution —
     dropped monitor forwards leave the other groups' distances at INF.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     g = lax.all_gather(x, axis_name, axis=0, tiled=False)
     if isinstance(axis_name, (tuple, list)):
         g = g.reshape(n, *x.shape)
@@ -268,7 +267,7 @@ def hierarchical_pmin(x, group_axis: str, member_axis: str, *,
     if not jnp.issubdtype(x.dtype, jnp.integer):
         raise TypeError(f"hierarchical_pmin is for integer payloads, "
                         f"got {x.dtype}")
-    m = axis_size(member_axis)
+    m = lax.axis_size(member_axis)
     if x.shape[0] % m != 0:
         # fall back: min within group first, then across (still two-phase)
         x = _min_all_reduce(x, member_axis)
@@ -361,7 +360,7 @@ def _encoded_or_all_reduce(x, axis_name, *, threshold=None, fault=None,
     header; ``inter_group`` drops every contribution but index 0's after
     the decode fold (the dropped-forward mode, replicated).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     mode, payload, count = encode_delta(x, threshold=threshold)
     mode, payload, count = faults.corrupt_encoded(
         fault, mode, payload, count, level=level, device=device, root=root)
@@ -401,7 +400,7 @@ def compressed_hierarchical_por(x, group_axis: str, member_axis: str, *,
                         f"payloads, got {x.dtype}")
     if known is not None:
         x = x & ~known
-    m = axis_size(member_axis)
+    m = lax.axis_size(member_axis)
     if x.shape[0] % m != 0:
         # fall back: OR within group first, then the encoded exchange
         # across groups (still two-phase, still codec'd on the wire leg)
@@ -448,7 +447,7 @@ def all_to_all_spmd(mesh: Mesh, group_axis: str = "group",
         return flat_all_to_all(x, axes)
 
     return jax.jit(
-        shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec)
+        jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec)
     )
 
 
@@ -468,6 +467,6 @@ def psum_spmd(mesh: Mesh, group_axis: str = "group", member_axis: str = "member"
         return r[None]
 
     return jax.jit(
-        shard_map(local, mesh=mesh, in_specs=P((group_axis, member_axis)),
+        jax.shard_map(local, mesh=mesh, in_specs=P((group_axis, member_axis)),
                   out_specs=P((group_axis, member_axis)))
     )

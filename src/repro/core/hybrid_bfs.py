@@ -668,11 +668,9 @@ def _axis_names_tuple(name) -> tuple:
 
 def _shard_index(group_axis, member_axis):
     """Flat device index (group-major) of this shard inside shard_map."""
-    from repro.util import axis_size
-
     idx = jnp.int32(0)
     for n in _axis_names_tuple(group_axis) + _axis_names_tuple(member_axis):
-        idx = idx * axis_size(n) + jax.lax.axis_index(n)
+        idx = idx * jax.lax.axis_size(n) + jax.lax.axis_index(n)
     return idx
 
 

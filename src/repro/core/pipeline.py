@@ -228,13 +228,20 @@ def run(cfg: Graph500Config, built: BuiltGraph | None = None) -> tuple[BuiltGrap
 
         return run_config(cfg, built)
     built = built or build(cfg)
+    roots = search_keys(cfg, built)
+    compiled = compile_plan(cfg.to_plan(), built)
+    return built, compiled.run(roots, check=cfg.check, retries=cfg.retries,
+                               fallback=cfg.fallback).run
+
+
+def search_keys(cfg: Graph500Config, built: BuiltGraph) -> jnp.ndarray:
+    """The config's ``n_roots`` Graph500 search keys, in ``built``'s
+    (possibly degree-sorted) vertex ids."""
     edges = kronecker.generate_edges(cfg.seed, cfg.scale, cfg.edge_factor)
     roots = kronecker.sample_roots(cfg.seed, edges, cfg.n_roots)
     if built.reorder is not None:
         roots = built.reorder.new_from_old[roots]
-    compiled = compile_plan(cfg.to_plan(), built)
-    return built, compiled.run(roots, check=cfg.check, retries=cfg.retries,
-                               fallback=cfg.fallback).run
+    return roots
 
 
 def serve(cfg: Graph500Config, serve_cfg=None,

@@ -100,7 +100,7 @@ from repro.core.sssp_steps import (
 from repro.core.teps import Graph500Run, traversed_edges
 from repro.core.validate import failure_report
 from repro.kernels import ops as kops
-from repro.util import make_mesh, shard_map
+from repro.util import make_mesh
 
 VALID_LAYOUTS = (
     (),
@@ -533,12 +533,12 @@ def _root_parallel_fn(mesh, root_axis, alpha, beta, use_core, max_levels,
                     fault=fault)
             )(roots)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(root_axis), P()),
         out_specs=P(root_axis),
-        check=False,
+        check_vma=False,
     ))
     _MESH_FN_CACHE[key] = fn
     return fn
@@ -656,8 +656,29 @@ def vertex_sharded_program(
     else:
         in_specs = (P(),) + (g_spec,) * n_sharded + (P(),) + core_specs
         out_specs = (P(va), P(va), P(), P())
-    return shard_map(local, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+@dataclass(frozen=True)
+class _Program:
+    """One jitted traversal program bound to its graph operands: the
+    root(s) go in at ``root_pos``, ``statics`` are its static keywords."""
+
+    fn: Callable
+    operands: tuple
+    root_pos: int
+    statics: dict = dataclasses.field(default_factory=dict)
+
+    def _args(self, roots):
+        return (self.operands[:self.root_pos] + (roots,)
+                + self.operands[self.root_pos:])
+
+    def __call__(self, roots):
+        return self.fn(*self._args(roots), **self.statics)
+
+    def lower(self, roots):
+        return self.fn.lower(*self._args(roots), **self.statics)
 
 
 def _vertex_fn(mesh, **kw):
@@ -724,60 +745,41 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
         kernel_kw = dict(kernel="sssp", delta=bucket_width(maxw),
                          max_rounds=sssp_max_rounds(plan.max_levels))
 
+    core_arg = pg.core if use_core else None
     if not plan.layout:
-        if plan.batch_roots:
-            chunks, degree, core = pg.chunks, pg.degree, pg.core
-            n_active = jnp.sum(degree > 0).astype(jnp.int32)
-
-            if kernel == "sssp":
-                def raw(roots):
-                    return _run_sssp_batch(
-                        chunks, degree, roots,
-                        delta=kernel_kw["delta"],
-                        max_rounds=kernel_kw["max_rounds"], fault=fault)
-            else:
-                def raw(roots):
-                    return _run_batch(
-                        chunks, degree, n_active, roots,
-                        core if use_core else None,
-                        alpha=plan.alpha, beta=plan.beta, use_core=use_core,
-                        max_levels=plan.max_levels,
-                        use_pallas_core=use_pallas, fault=fault)
+        chunks, degree = pg.chunks, pg.degree
+        n_active = jnp.sum(degree > 0).astype(jnp.int32)
+        bitmap_kw = dict(alpha=plan.alpha, beta=plan.beta, use_core=use_core,
+                         max_levels=plan.max_levels, fault=fault)
+        if kernel == "sssp":
+            sssp_kw = dict(delta=kernel_kw["delta"],
+                           max_rounds=kernel_kw["max_rounds"], fault=fault)
+            program = _Program(
+                _run_sssp_batch if plan.batch_roots else _run_sssp,
+                (chunks, degree), 2, sssp_kw)
+        elif plan.batch_roots:
+            program = _Program(_run_batch, (chunks, degree, n_active, core_arg),
+                               3, dict(bitmap_kw, use_pallas_core=use_pallas))
+        elif plan.engine == "bitmap":
+            program = _Program(_run_bitmap,
+                               (chunks, degree, n_active, core_arg), 3,
+                               bitmap_kw)
         else:
-            ev, chunks, degree, core = pg.ev, pg.chunks, pg.degree, pg.core
-            n_active = jnp.sum(degree > 0).astype(jnp.int32)
-            engine = plan.engine
-            legacy_core = engine == "legacy" and use_core
-
-            def raw(root):
-                if kernel == "sssp":
-                    return _run_sssp(
-                        chunks, degree, root, delta=kernel_kw["delta"],
-                        max_rounds=kernel_kw["max_rounds"], fault=fault)
-                if engine == "bitmap":
-                    return _run_bitmap(
-                        chunks, degree, n_active, root,
-                        core if use_core else None,
-                        alpha=plan.alpha, beta=plan.beta, use_core=use_core,
-                        max_levels=plan.max_levels, fault=fault)
-                return _run_legacy(
-                    ev, degree, n_active, root,
-                    core if legacy_core else None,
-                    engine=engine, alpha=plan.alpha, beta=plan.beta,
-                    use_core=legacy_core, max_levels=plan.max_levels)
-
+            legacy_core = use_core and plan.engine == "legacy"
+            program = _Program(
+                _run_legacy,
+                (pg.ev, degree, n_active, pg.core if legacy_core else None), 3,
+                dict(engine=plan.engine, alpha=plan.alpha, beta=plan.beta,
+                     use_core=legacy_core, max_levels=plan.max_levels))
+        raw = program
         v_orig = pg.ev.num_vertices
     elif plan.layout == ("root",):
-        chunks, degree, core = pg.chunks, pg.degree, pg.core
-        n_active = jnp.sum(degree > 0).astype(jnp.int32)
+        n_active = jnp.sum(pg.degree > 0).astype(jnp.int32)
         fn = _root_parallel_fn(mesh, role["root"], plan.alpha, plan.beta,
                                use_core, plan.max_levels, use_pallas, fault,
                                **kernel_kw)
-
-        def raw(roots):
-            return fn(chunks, degree, n_active, roots,
-                      core if use_core else None)
-
+        program = _Program(fn, (pg.chunks, pg.degree, n_active, core_arg), 3)
+        raw = program
         v_orig = pg.ev.num_vertices
     else:
         sg = pg.sharded
@@ -792,7 +794,12 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
             use_pallas_core=use_pallas, batched=plan.batch_roots,
             fault=fault, **kernel_kw,
         )
+        gargs = (sg.src, sg.dst_local, sg.valid, sg.src_lo, sg.src_hi)
+        if kernel == "sssp":
+            gargs = gargs + (sg.weight,)
         core_args = (pg.core,) if use_core else ()
+        program = _Program(
+            fn, gargs + (sg.degree_local, sg.n_active) + core_args, 0)
         # Reassembly: shard outputs concatenate shard-major; under the
         # word-cyclic owner map the inverse permutation restores global
         # vertex order (identity for block, where it is skipped).
@@ -801,11 +808,7 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
                 if plan.partition != "block" else None)
 
         def raw(roots):
-            gargs = (sg.src, sg.dst_local, sg.valid, sg.src_lo, sg.src_hi)
-            if kernel == "sssp":
-                gargs = gargs + (sg.weight,)
-            parent, level, levels, sentinel = fn(
-                roots, *gargs, sg.degree_local, sg.n_active, *core_args)
+            parent, level, levels, sentinel = program(roots)
             if perm is not None:
                 parent = jnp.take(parent, perm, axis=-1)
                 level = jnp.take(level, perm, axis=-1)
@@ -829,8 +832,8 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
 
     return CompiledBFS(
         plan=plan, mesh=mesh, graph=pg, num_vertices=v_orig,
-        _raw=raw, _vertexy=vertexy, _root_axis_size=root_axis_size,
-        _axis_names=names, _fault=fault,
+        _raw=raw, _program=program, _vertexy=vertexy,
+        _root_axis_size=root_axis_size, _axis_names=names, _fault=fault,
     )
 
 
@@ -850,6 +853,7 @@ class CompiledBFS:
     graph: PreparedGraph
     num_vertices: int           # original V (before shard padding)
     _raw: Callable
+    _program: "_Program"        # the jitted traversal program inside _raw
     _vertexy: bool = False
     _root_axis_size: int = 1
     _axis_names: tuple = ()
@@ -862,6 +866,13 @@ class CompiledBFS:
             return None
         return {role: _role_size(self.mesh, name)
                 for role, name in zip(self.plan.layout, self._axis_names)}
+
+    def lower(self, roots):
+        """The traversal program lowered for ``roots`` (a scalar root for
+        per-root plans, a root vector for batched ones), without running
+        it: ``.compile().memory_analysis()`` gives its device bytes and
+        ``.as_text()`` shows its kernels and collectives."""
+        return self._program.lower(jnp.asarray(roots, jnp.int32))
 
     def bfs(self, roots):
         """Raw traversal(s).  ``batch_roots`` plans take a root vector

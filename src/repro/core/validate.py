@@ -96,16 +96,17 @@ def validate(ev: EdgeView, result: BFSResult, root: jax.Array) -> Validation:
 def validate_batch(ev: EdgeView, parents: jax.Array, levels: jax.Array,
                    roots: jax.Array) -> Validation:
     """All five spec checks for a ``[R, V]`` parent/level batch in ONE
-    vmapped program — every Validation leaf comes back ``[R]`` bool.
+    program — every Validation leaf comes back ``[R]`` bool.
 
-    This replaces the old per-root host loop (one ``validate`` dispatch
-    and one device→host sync per root): one dispatch for the whole
-    batch, and per-check booleans per root for failure attribution.
+    One dispatch for the whole batch, with per-check booleans per root
+    for failure attribution.  Roots are checked one after another
+    (``lax.map``): each check holds per-edge temporaries, so a vmap over
+    R roots needs R times the memory of one (DESIGN.md §13).
     """
-    return jax.vmap(
-        lambda p, l, r: validate(ev, BFSResult(parent=p, level=l,
-                                               stats=None), r)
-    )(parents, levels, jnp.asarray(roots, jnp.int32))
+    return jax.lax.map(
+        lambda a: validate(ev, BFSResult(parent=a[0], level=a[1],
+                                         stats=None), a[2]),
+        (parents, levels, jnp.asarray(roots, jnp.int32)))
 
 
 #: Short names of the five SSSP invariants, in SsspValidation field order.
@@ -189,11 +190,12 @@ def validate_sssp(ev: EdgeView, result: BFSResult, root: jax.Array
 @jax.jit
 def validate_sssp_batch(ev: EdgeView, parents: jax.Array, levels: jax.Array,
                         roots: jax.Array) -> SsspValidation:
-    """Batched SSSP validation — SsspValidation leaves come back [R] bool."""
-    return jax.vmap(
-        lambda p, d, r: validate_sssp(ev, BFSResult(parent=p, level=d,
-                                                    stats=None), r)
-    )(parents, levels, jnp.asarray(roots, jnp.int32))
+    """Batched SSSP validation — SsspValidation leaves come back [R] bool.
+    Roots are checked one after another, as in :func:`validate_batch`."""
+    return jax.lax.map(
+        lambda a: validate_sssp(ev, BFSResult(parent=a[0], level=a[1],
+                                              stats=None), a[2]),
+        (parents, levels, jnp.asarray(roots, jnp.int32)))
 
 
 def failure_report(val):

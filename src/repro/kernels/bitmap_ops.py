@@ -29,6 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 ROWS_PER_TILE = 8
 LANES = 128
@@ -43,15 +44,24 @@ def _popcount_tile(w):
 
 
 def _frontier_update_kernel(next_ref, vis_ref, out_next_ref, out_vis_ref, count_ref):
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        count_ref[0, 0] = jnp.int32(0)
+
     nxt = next_ref[...] & ~vis_ref[...]
     out_next_ref[...] = nxt
     out_vis_ref[...] = vis_ref[...] | nxt
-    count_ref[0, 0] = jnp.sum(_popcount_tile(nxt))
+    count_ref[0, 0] += jnp.sum(_popcount_tile(nxt))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def frontier_update(next_raw: jax.Array, visited: jax.Array, *, interpret: bool = True):
-    """Fused (mask, merge, popcount). uint32 [W] x2 -> (uint32 [W], uint32 [W], int32)."""
+    """Fused (mask, merge, popcount). uint32 [W] x2 -> (uint32 [W], uint32 [W], int32).
+
+    The popcount accumulates in one SMEM scalar across the tile grid, so
+    the grid axis is sequential (``"arbitrary"``); a per-tile ``(1, 1)``
+    VMEM count block would break Mosaic's (8, 128) block rule.
+    """
     w = next_raw.shape[0]
     assert w % WORDS_PER_TILE == 0, f"bitmap length {w} not a multiple of {WORDS_PER_TILE}"
     rows = w // LANES
@@ -59,7 +69,7 @@ def frontier_update(next_raw: jax.Array, visited: jax.Array, *, interpret: bool 
     n2 = next_raw.reshape(rows, LANES)
     v2 = visited.reshape(rows, LANES)
     tile = lambda i: (i, 0)
-    out_next, out_vis, counts = pl.pallas_call(
+    out_next, out_vis, count = pl.pallas_call(
         _frontier_update_kernel,
         grid=(grid,),
         in_specs=[
@@ -69,13 +79,14 @@ def frontier_update(next_raw: jax.Array, visited: jax.Array, *, interpret: bool 
         out_specs=[
             pl.BlockSpec((ROWS_PER_TILE, LANES), tile),
             pl.BlockSpec((ROWS_PER_TILE, LANES), tile),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.uint32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.uint32),
-            jax.ShapeDtypeStruct((grid, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(n2, v2)
-    return out_next.reshape(w), out_vis.reshape(w), jnp.sum(counts)
+    return out_next.reshape(w), out_vis.reshape(w), count[0, 0]

@@ -1,15 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Kernels compile to Mosaic on TPU backends and run with ``interpret=True``
-(traced to XLA ops) everywhere else.  The mode is auto-detected from
-``jax.default_backend()`` once, on first use; set ``REPRO_INTERPRET=0``
-(compile) or ``REPRO_INTERPRET=1`` (interpret) to override, e.g. to force
-interpret mode while bringing up a new backend.  Benchmark runs record
-the resolved mode in ``BENCH_bfs.json`` metadata (``interpret_mode``).
+Kernels compile to Mosaic on a TPU backend and run with ``interpret=True``
+(traced to XLA ops) exactly when the backend is the CPU.  Benchmark runs
+record the backend and the resolved mode in ``BENCH_bfs.json`` metadata
+(``backend``, ``interpret_mode``).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -17,41 +13,10 @@ import jax.numpy as jnp
 from repro.kernels import bitmap_ops, cin, frontier_spmv, spmv_mxu
 from repro.kernels.ref import BIG  # re-export sentinel
 
-_INTERPRET: bool | None = None
-
-_ENV_VAR = "REPRO_INTERPRET"
-_ENV_FALSE = ("0", "false", "no", "compile", "mosaic")
-_ENV_TRUE = ("1", "true", "yes", "interpret")
-
 
 def interpret_mode() -> bool:
-    """Resolved Pallas execution mode (cached after first call).
-
-    Priority: ``REPRO_INTERPRET`` env override, then backend auto-detect
-    (interpret everywhere except real TPU backends).
-    """
-    global _INTERPRET
-    if _INTERPRET is None:
-        env = os.environ.get(_ENV_VAR, "").strip().lower()
-        if env in _ENV_FALSE:
-            _INTERPRET = False
-        elif env in _ENV_TRUE:
-            _INTERPRET = True
-        elif env:
-            raise ValueError(
-                f"{_ENV_VAR}={env!r} not understood; use one of "
-                f"{_ENV_TRUE} or {_ENV_FALSE} (or unset for autodetect)")
-        else:
-            _INTERPRET = jax.default_backend() != "tpu"
-    return _INTERPRET
-
-
-def interpret_mode_source() -> str:
-    """Where the resolved mode came from — benchmark metadata."""
-    env = os.environ.get(_ENV_VAR, "").strip().lower()
-    if env in _ENV_FALSE or env in _ENV_TRUE:
-        return f"env:{_ENV_VAR}={env}"
-    return f"auto:backend={jax.default_backend()}"
+    """Whether the Pallas kernels run interpreted: only on the CPU."""
+    return jax.default_backend() == "cpu"
 
 
 def frontier_update(next_raw: jax.Array, visited: jax.Array):

@@ -9,9 +9,9 @@ __all__ = ["mesh", "multiprocess"]
 
 
 def __getattr__(name):
-    # multiprocess imported lazily: the worker path must configure gloo
-    # collectives before any jax backend touch, so keep this module's
-    # import side-effect-free for it.
+    # multiprocess imported lazily: the worker path must call
+    # jax.distributed.initialize before any jax backend touch, so keep
+    # this module's import side-effect-free for it.
     if name == "multiprocess":
         from repro.launch import multiprocess
         return multiprocess

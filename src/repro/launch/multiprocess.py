@@ -87,22 +87,6 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
-def enable_cpu_collectives() -> None:
-    """Best-effort gloo CPU collectives (must run before backend init).
-
-    jax 0.4.x needs the explicit flag; newer jax either keeps it or
-    initializes cross-process CPU collectives from
-    ``jax.distributed.initialize`` alone — so a missing/renamed option
-    is not an error here (the device-count check after init is the real
-    gate)."""
-    import jax
-
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
-
-
 def parent_digest(parent) -> str:
     """Bitwise fingerprint of a parent batch — the cross-process parity
     tests compare this against single-process runs without shipping the
@@ -157,7 +141,6 @@ def time_exchange_per_level(compiled, level_row, *, reps: int = 3) -> dict:
     from jax.sharding import PartitionSpec as P
 
     from repro.core.hybrid_bfs import _exchange_delta, _shard_index
-    from repro.util import shard_map
 
     sg = compiled.graph.sharded
     plan = compiled.plan
@@ -179,9 +162,9 @@ def time_exchange_per_level(compiled, level_row, *, reps: int = 3) -> dict:
             partition=plan.partition, known_bm=known[0] if sieve else None)
 
     va = (group_axis, member_axis)
-    prog = jax.jit(shard_map(
+    prog = jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=(P(va), P(None)), out_specs=P(),
-        check=False))
+        check_vma=False))
 
     level_row = np.asarray(level_row).reshape(-1)
 
@@ -267,7 +250,6 @@ def _worker(args) -> int:
               f"(REPRO_MP_CRASH_RANK)", file=sys.stderr, flush=True)
         return 17
 
-    enable_cpu_collectives()
     import jax
 
     jax.distributed.initialize(coordinator_address=args.coordinator,

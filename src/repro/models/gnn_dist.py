@@ -25,7 +25,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.comms.hierarchical import hierarchical_all_gather
 from repro.train.train_step import softmax_xent
-from repro.util import shard_map
 
 
 def sage_layer_local(p, h_full, h_own, src, dst_local, valid, n_loc, last):
@@ -88,7 +87,7 @@ def make_sage_dist_step(cfg, opt, mesh: Mesh, axes: tuple[str, ...],
             grads = jax.tree.map(lambda g: lax.psum(g, axes), grads)
             return loss, grads
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_loss, mesh=mesh,
             in_specs=(P(axes, None), P(axes), P(axes), P(axes), P(axes), P()),
             out_specs=(P(), P()),

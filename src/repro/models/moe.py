@@ -28,7 +28,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.util import axis_size
 
 Params = dict[str, Any]
 
@@ -142,7 +141,7 @@ def moe_ffn_local_tp(
     """
     from jax import lax
 
-    m = axis_size(model_axis)
+    m = lax.axis_size(model_axis)
     me = lax.axis_index(model_axis)
     b, s, d = x.shape
     t = b * s
@@ -206,8 +205,8 @@ def moe_ffn_monitor(
     from jax import lax
     from repro.comms.hierarchical import hierarchical_all_to_all
 
-    g = axis_size(group_axis)
-    m = axis_size(member_axis)
+    g = lax.axis_size(group_axis)
+    m = lax.axis_size(member_axis)
     pdev = g * m
     b, s, d = x.shape
     t = b * s

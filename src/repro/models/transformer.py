@@ -28,7 +28,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models import layers as L
 from repro.models import moe as M
-from repro.util import shard_map
 
 Params = dict[str, Any]
 
@@ -242,7 +241,7 @@ def _moe_monitor_sharded(cfg: LMConfig, policy: ShardingPolicy, h, moe_p):
         return out, jax.lax.pmean(aux, ba)
 
     mp = policy.model_axis
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ba, None, None), espec),
         out_specs=(P(ba, None, None), P()),
@@ -265,7 +264,7 @@ def _moe_local_tp_sharded(cfg: LMConfig, policy: ShardingPolicy, h, moe_p):
         # aux is invariant along model (router replicated); mean over batch
         return out, jax.lax.pmean(aux, ba)
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ba, None, None), espec),
         out_specs=(P(ba, None, None), P()),

@@ -9,49 +9,9 @@ import subprocess
 import jax
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check: bool = True):
-    """Version-portable ``shard_map``.
-
-    Newer jax exposes ``jax.shard_map`` (replication checking via
-    ``check_vma``); 0.4.x has ``jax.experimental.shard_map.shard_map``
-    (``check_rep``).  ``check=False`` disables the static replication
-    checker on either API — needed when an ``all_gather`` output is
-    replicated in value but the checker cannot prove it.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
-
-
-def axis_size(axis_name) -> int:
-    """Version-portable ``lax.axis_size`` (static size of a bound mesh axis).
-
-    jax 0.4.x has no ``lax.axis_size``; ``lax.psum(1, axis)`` of a Python
-    constant folds to a concrete int on every version.  A tuple of axis
-    names gives the product (the dry-run binds the vertex-sharded
-    engine's group role to several production-mesh axes).
-    """
-    from jax import lax
-    if isinstance(axis_name, (tuple, list)):
-        n = 1
-        for a in axis_name:
-            n *= axis_size(a)
-        return n
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def make_mesh(shape, axis_names):
-    """Version-portable device mesh over the first ``prod(shape)`` devices.
-
-    Tries ``jax.make_mesh`` with explicit ``Auto`` axis types (newer jax),
-    then without (jax 0.4.35–0.4.38), then falls back to a raw
-    ``jax.sharding.Mesh`` over a device-array reshape.
-    """
+    """A device mesh with ``Auto`` axes over the first ``prod(shape)``
+    visible devices."""
     shape = tuple(int(s) for s in shape)
     axis_names = tuple(axis_names)
     n = math.prod(shape)
@@ -59,19 +19,23 @@ def make_mesh(shape, axis_names):
     if len(devices) < n:
         raise ValueError(
             f"mesh shape {shape} needs {n} devices, have {len(devices)}")
-    devices = devices[:n]
-    try:
-        return jax.make_mesh(
-            shape, axis_names, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return jax.make_mesh(shape, axis_names, devices=devices)
-    except (AttributeError, TypeError):
-        import numpy as np
-        return jax.sharding.Mesh(np.asarray(devices).reshape(shape),
-                                 axis_names)
+    return jax.make_mesh(
+        shape, axis_names, devices=devices[:n],
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
+
+
+def use_compile_cache(checkout: str) -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    A directory named by ``JAX_COMPILATION_CACHE_DIR`` is JAX's own
+    setting and is left alone; otherwise the cache lives at the fixed
+    path ``<checkout>/.jax_cache``.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.abspath(checkout), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def host_device_env(n_devices: int, *, extra_env: dict | None = None,
@@ -122,7 +86,18 @@ def respawn_with_host_devices(argv, n_devices: int, *,
         given ``stdout``/``stderr`` handles; returns the ``Popen`` (the
         multi-process launcher spawns one per rank and owns the
         wait/kill policy).
+
+    Forced host devices exist only on the CPU backend.  On an
+    accelerator this process holds the chip, so a child could neither
+    reach it nor stand in for it: the call fails instead.
     """
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"refusing to start a child with {n_devices} forced host "
+            f"devices: this process runs on the {backend} backend and "
+            f"holds its devices — run the work in this process on "
+            f"jax.devices() instead")
     env = host_device_env(n_devices, extra_env=extra_env,
                           pythonpath=pythonpath)
     if background:

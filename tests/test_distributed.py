@@ -26,7 +26,7 @@ PREAMBLE = """
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.util import make_mesh, shard_map
+from repro.util import make_mesh
 mesh = make_mesh((2, 4), ("group", "member"))
 """
 
@@ -101,7 +101,7 @@ def local(x, p):
                                    member_axis="member")
     return out
 
-f = jax.jit(shard_map(local, mesh=mesh,
+f = jax.jit(jax.shard_map(local, mesh=mesh,
         in_specs=(P(("group", "member")), P()), out_specs=P(("group", "member"))))
 y = f(x, p)
 assert y.shape == x.shape
@@ -139,11 +139,11 @@ def local_step(params, tokens, labels):
         if g.size % 4 == 0 else jax.lax.psum(g, ("group", "member")), grads)
     return jax.lax.psum(loss, ("group", "member")), grads
 
-# check=False: all_gather output is replicated in VALUE but the
+# check_vma=False: all_gather output is replicated in VALUE but the
 # static varying-axis checker cannot prove it; numerics verified below.
-f = jax.jit(shard_map(local_step, mesh=mesh,
+f = jax.jit(jax.shard_map(local_step, mesh=mesh,
         in_specs=(P(), P(("group", "member")), P(("group", "member"))),
-        out_specs=(P(), P()), check=False))
+        out_specs=(P(), P()), check_vma=False))
 loss, grads = f(params, batch["tokens"], batch["labels"])
 assert np.isfinite(float(loss))
 flat = jax.tree.leaves(grads)

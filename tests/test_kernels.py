@@ -21,15 +21,22 @@ def rand_u32(rng, shape, density=0.5):
 
 @pytest.mark.parametrize("n_words", [1024, 4096, 8192])
 @pytest.mark.parametrize("density", [0.01, 0.5])
-def test_frontier_update_matches_ref(n_words, density):
+@pytest.mark.parametrize("batch", [None, 8], ids=["one", "vmap8"])
+def test_frontier_update_matches_ref(n_words, density, batch):
+    # vmap8: the popcount accumulates across the tile grid, per root
     rng = np.random.default_rng(n_words)
-    nxt = jnp.asarray(rand_u32(rng, (n_words,), density))
-    vis = jnp.asarray(rand_u32(rng, (n_words,), density))
-    out_n, out_v, count = frontier_update(nxt, vis, interpret=True)
-    ref_n, ref_v, ref_c = ref.frontier_update_ref(nxt, vis)
+    lead = () if batch is None else (batch,)
+    nxt = jnp.asarray(rand_u32(rng, lead + (n_words,), density))
+    vis = jnp.asarray(rand_u32(rng, lead + (n_words,), density))
+    fu = lambda n, v: frontier_update(n, v, interpret=True)
+    ref_fu = ref.frontier_update_ref
+    if batch is not None:
+        fu, ref_fu = jax.vmap(fu), jax.vmap(ref_fu)
+    out_n, out_v, count = fu(nxt, vis)
+    ref_n, ref_v, ref_c = ref_fu(nxt, vis)
     np.testing.assert_array_equal(np.asarray(out_n), np.asarray(ref_n))
     np.testing.assert_array_equal(np.asarray(out_v), np.asarray(ref_v))
-    assert int(count) == int(ref_c)
+    np.testing.assert_array_equal(np.asarray(count), np.asarray(ref_c))
 
 
 def test_frontier_update_popcount_exact():

@@ -9,7 +9,8 @@ from hypothesis import given, settings, strategies as st
 import jax
 import jax.numpy as jnp
 
-from repro.core.heavy import pack_bitmap, testbit, unpack_bitmap
+from repro.core.heavy import pack_bitmap, unpack_bitmap
+from repro.core.heavy import testbit as bit_at  # alias: pytest must not collect
 from repro.core.reorder import degree_reorder
 from repro.comms.topology import TreeTopology, elect_monitors
 from repro.kernels import ref
@@ -35,7 +36,7 @@ def test_bitmap_testbit_agrees_with_mask(bits, seed):
     mask = np.array(bits)
     bm = pack_bitmap(jnp.asarray(mask), (len(bits) + 31) // 32)
     idx = np.random.default_rng(seed).integers(0, len(bits), size=32)
-    got = np.asarray(testbit(bm, jnp.asarray(idx, jnp.int32)))
+    got = np.asarray(bit_at(bm, jnp.asarray(idx, jnp.int32)))
     assert np.array_equal(got, mask[idx])
 
 

@@ -506,7 +506,7 @@ import functools
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.util import make_mesh, shard_map
+from repro.util import make_mesh
 from repro.comms.hierarchical import (
     compressed_hierarchical_psum, hierarchical_por, hierarchical_psum)
 
@@ -515,10 +515,10 @@ rng = np.random.default_rng(0)
 
 # OR reduction: exact vs the numpy fold, full bit range
 x = jnp.asarray(rng.integers(0, 2**32, size=(8, 64), dtype=np.uint32))
-f = jax.jit(shard_map(
+f = jax.jit(jax.shard_map(
     lambda v: hierarchical_por(v[0], "group", "member")[None],
     mesh=mesh, in_specs=P(("group", "member")),
-    out_specs=P(("group", "member")), check=False))
+    out_specs=P(("group", "member")), check_vma=False))
 got = np.asarray(f(x))
 want = functools.reduce(np.bitwise_or, np.asarray(x))
 assert all(np.array_equal(got[i], want) for i in range(8))
@@ -531,11 +531,11 @@ assert all(np.array_equal(got2[i], want2) for i in range(8))
 
 # float payloads are rejected (OR is meaningless there)
 try:
-    jax.jit(shard_map(
+    jax.jit(jax.shard_map(
         lambda v: hierarchical_por(v[0].astype(jnp.float32),
                                    "group", "member")[None],
         mesh=mesh, in_specs=P(("group", "member")),
-        out_specs=P(("group", "member")), check=False))(x)
+        out_specs=P(("group", "member")), check_vma=False))(x)
     raise SystemExit("expected TypeError")
 except TypeError:
     pass
@@ -543,10 +543,10 @@ except TypeError:
 # compressed psum: integer payloads bypass the bfloat16 cast (lossless).
 # These values need >8 mantissa bits, so the float path would corrupt them.
 xi = jnp.asarray(rng.integers(2**20, 2**24, size=(8, 64), dtype=np.uint32))
-fc = jax.jit(shard_map(
+fc = jax.jit(jax.shard_map(
     lambda v: compressed_hierarchical_psum(v[0], "group", "member")[None],
     mesh=mesh, in_specs=P(("group", "member")),
-    out_specs=P(("group", "member")), check=False))
+    out_specs=P(("group", "member")), check_vma=False))
 got3 = np.asarray(fc(xi))
 want3 = np.sum(np.asarray(xi, np.uint64), axis=0).astype(np.uint32)
 assert np.array_equal(got3[0], want3)
@@ -561,28 +561,51 @@ print("OK")
     assert "OK" in out
 
 
-def test_interpret_mode_env_override():
-    """Satellite: REPRO_INTERPRET env var overrides the backend autodetect."""
-    code = """
-from repro.kernels import ops
-print("mode", ops.interpret_mode(), ops.interpret_mode_source())
-"""
-    out = run_sub(code, extra_env={"REPRO_INTERPRET": "0"})
-    assert "mode False env:REPRO_INTERPRET=0" in out
-    out = run_sub(code, extra_env={"REPRO_INTERPRET": "interpret"})
-    assert "mode True env:REPRO_INTERPRET=interpret" in out
-    out = run_sub(code, extra_env={"REPRO_INTERPRET": ""})
-    assert "mode True auto:backend=cpu" in out
-    # typos fail loudly instead of silently falling back to autodetect
-    out = run_sub("""
-from repro.kernels import ops
-try:
-    ops.interpret_mode()
-    print("no raise")
-except ValueError as e:
-    print("raises:", e)
-""", extra_env={"REPRO_INTERPRET": "bogus"})
-    assert "raises:" in out and "bogus" in out
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_interpret_mode_follows_backend(monkeypatch, backend):
+    """Kernels run interpreted exactly on the CPU backend; nothing
+    overrides that."""
+    import jax
+
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_INTERPRET", "1")   # must be ignored
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops.interpret_mode() is (backend == "cpu")
+
+
+def test_respawn_refuses_on_accelerator(monkeypatch):
+    """On an accelerator this process holds the chip: no child with
+    forced host devices is started."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="forced host devices"):
+        respawn_with_host_devices([sys.executable, "-c", "raise SystemExit(7)"],
+                                  8, capture=True, timeout=60)
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_location(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR is JAX's own setting and is left alone;
+    without it the cache goes to <checkout>/.jax_cache.  The cache is
+    never actually turned on here."""
+    import jax
+
+    from repro.util import use_compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(tmp_path / ".jax_cache")
+        assert use_compile_cache(str(tmp_path)) == want
+        assert updates == [("jax_compilation_cache_dir", want)]
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert use_compile_cache(str(tmp_path)) == env_dir
+        assert updates == []
 
 
 def test_pipeline_mesh_rung_single_device():
