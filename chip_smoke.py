@@ -3,7 +3,6 @@ a user calls.
 
     python chip_smoke.py               # one chip: offline harness + serving
     python chip_smoke.py --four-chips  # four chips: vertex-sharded plan only
-    python chip_smoke.py --scale 16    # a smaller graph, for a quicker run
 
 One chip, offline phase: ``pipeline.build`` + ``pipeline.run`` of the
 ``pre-g500`` rung (per-root bitmap engine, dense heavy core, compiled
@@ -277,10 +276,6 @@ def main(argv=None) -> int:
                     help="run only the four-chip vertex-sharded phase")
     ap.add_argument("--seed", type=int, default=42,
                     help="seed of the graph, the search keys and the trace")
-    ap.add_argument("--scale", type=int, default=OFFLINE_SCALE,
-                    help=f"graph scale of the offline and four-chip phases "
-                         f"(default {OFFLINE_SCALE}); the serving phase runs "
-                         f"at the smaller of this and {SERVE_SCALE}")
     args = ap.parse_args(argv)
 
     src = os.path.join(HERE, "src")
@@ -306,12 +301,11 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     try:
         if args.four_chips:
-            four_chip_phase(args.scale, args.seed, devices)
+            four_chip_phase(OFFLINE_SCALE, args.seed, devices)
         else:
-            offline_phase(args.scale, args.seed, dev)
+            offline_phase(OFFLINE_SCALE, args.seed, dev)
             gc.collect()
-            serve_phase(min(args.scale, SERVE_SCALE), args.seed,
-                        SERVE_QUERIES, dev)
+            serve_phase(SERVE_SCALE, args.seed, SERVE_QUERIES, dev)
     except SmokeFailure as e:
         print(f"chip_smoke.py: FAILED: {e}", file=sys.stderr)
         return 1

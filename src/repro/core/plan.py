@@ -677,8 +677,15 @@ class _Program:
     def __call__(self, roots):
         return self.fn(*self._args(roots), **self.statics)
 
-    def lower(self, roots):
-        return self.fn.lower(*self._args(roots), **self.statics)
+    def lower(self, roots, sharding=None):
+        if sharding is None:
+            return self.fn.lower(*self._args(roots), **self.statics)
+        # Shapes only, placed by ``sharding``, traced afresh: jit's trace
+        # cache would hand back a trace made for the backend of the arrays.
+        fresh = jax.jit(functools.partial(self.fn.__wrapped__, **self.statics))
+        return fresh.lower(*jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+            self._args(roots)))
 
 
 def _vertex_fn(mesh, **kw):
@@ -746,6 +753,7 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
                          max_rounds=sssp_max_rounds(plan.max_levels))
 
     core_arg = pg.core if use_core else None
+    perm = None
     if not plan.layout:
         chunks, degree = pg.chunks, pg.degree
         n_active = jnp.sum(degree > 0).astype(jnp.int32)
@@ -771,7 +779,6 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
                 (pg.ev, degree, n_active, pg.core if legacy_core else None), 3,
                 dict(engine=plan.engine, alpha=plan.alpha, beta=plan.beta,
                      use_core=legacy_core, max_levels=plan.max_levels))
-        raw = program
         v_orig = pg.ev.num_vertices
     elif plan.layout == ("root",):
         n_active = jnp.sum(pg.degree > 0).astype(jnp.int32)
@@ -779,7 +786,6 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
                                use_core, plan.max_levels, use_pallas, fault,
                                **kernel_kw)
         program = _Program(fn, (pg.chunks, pg.degree, n_active, core_arg), 3)
-        raw = program
         v_orig = pg.ev.num_vertices
     else:
         sg = pg.sharded
@@ -803,19 +809,12 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
         # Reassembly: shard outputs concatenate shard-major; under the
         # word-cyclic owner map the inverse permutation restores global
         # vertex order (identity for block, where it is skipped).
-        perm = (jnp.asarray(partition_permutation(
-                    sg.n_devices, sg.w_loc, plan.partition))
-                if plan.partition != "block" else None)
-
-        def raw(roots):
-            parent, level, levels, sentinel = program(roots)
-            if perm is not None:
-                parent = jnp.take(parent, perm, axis=-1)
-                level = jnp.take(level, perm, axis=-1)
-            return parent, level, levels, sentinel
-
+        if plan.partition != "block":
+            perm = jnp.asarray(partition_permutation(
+                sg.n_devices, sg.w_loc, plan.partition))
         v_orig = sg.v_orig
 
+    replicate = None
     if mesh_process_count(mesh) > 1:
         # Cross-process mesh (DESIGN.md §15): the raw program's outputs
         # are sharded over devices this process cannot address, so one
@@ -824,16 +823,14 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
         # arrays addressably and the runner/validation/TEPS machinery
         # below works unchanged on every rank.
         from jax.sharding import NamedSharding
-        rep = jax.jit(lambda t: t, out_shardings=NamedSharding(mesh, P()))
-        inner_raw = raw
-
-        def raw(roots):
-            return rep(inner_raw(roots))
+        replicate = jax.jit(lambda t: t,
+                            out_shardings=NamedSharding(mesh, P()))
 
     return CompiledBFS(
         plan=plan, mesh=mesh, graph=pg, num_vertices=v_orig,
-        _raw=raw, _program=program, _vertexy=vertexy,
-        _root_axis_size=root_axis_size, _axis_names=names, _fault=fault,
+        _program=program, _perm=perm, _replicate=replicate,
+        _vertexy=vertexy, _root_axis_size=root_axis_size, _axis_names=names,
+        _fault=fault,
     )
 
 
@@ -852,8 +849,9 @@ class CompiledBFS:
     mesh: Any
     graph: PreparedGraph
     num_vertices: int           # original V (before shard padding)
-    _raw: Callable
-    _program: "_Program"        # the jitted traversal program inside _raw
+    _program: _Program          # the jitted traversal program
+    _perm: Any = None           # vertex layouts: shard-major -> global order
+    _replicate: Any = None      # cross-process meshes: replicate outputs
     _vertexy: bool = False
     _root_axis_size: int = 1
     _axis_names: tuple = ()
@@ -867,12 +865,26 @@ class CompiledBFS:
         return {role: _role_size(self.mesh, name)
                 for role, name in zip(self.plan.layout, self._axis_names)}
 
-    def lower(self, roots):
+    def lower(self, roots, sharding=None):
         """The traversal program lowered for ``roots`` (a scalar root for
         per-root plans, a root vector for batched ones), without running
         it: ``.compile().memory_analysis()`` gives its device bytes and
-        ``.as_text()`` shows its kernels and collectives."""
-        return self._program.lower(jnp.asarray(roots, jnp.int32))
+        ``.as_text()`` shows its kernels and collectives.  With
+        ``sharding``, every operand is a shape placed by it, so a
+        one-device program lowers for a device that holds no arrays."""
+        return self._program.lower(jnp.asarray(roots, jnp.int32), sharding)
+
+    def _raw(self, roots):
+        """Run the program; outputs in global vertex order, addressable
+        by this process."""
+        out = self._program(roots)
+        if self._perm is not None:
+            parent, level, levels, sentinel = out
+            out = (jnp.take(parent, self._perm, axis=-1),
+                   jnp.take(level, self._perm, axis=-1), levels, sentinel)
+        if self._replicate is not None:
+            out = self._replicate(out)
+        return out
 
     def bfs(self, roots):
         """Raw traversal(s).  ``batch_roots`` plans take a root vector
