@@ -260,7 +260,7 @@ def _child() -> dict:
                 f"validation failed — {detail or 'unknown check'}")
         # modeled per-level wire bytes (raw / post-sieve / post-codec per
         # exchange leg, DESIGN.md §12) recovered from the first root's
-        # level array — surfaced by benchmarks/breakdown.py
+        # level array
         wire = modeled_wire_bytes(
             result.level[0], n_devices=shape[0] * shape[1],
             w_loc=compiled.graph.sharded.w_loc,

@@ -49,10 +49,8 @@ MODULES = [
     "sorting_policies",   # Fig. 12/13
     "heavy_threshold",    # Fig. 14
     "monitor_policies",   # Fig. 15/16
-    "breakdown",          # Fig. 17
     "version_ladder",     # Fig. 18
     "kernels_micro",      # kernel-level validation throughputs
-    "roofline",           # deliverable (g) summary from the dry-run JSONs
 ]
 
 

@@ -29,9 +29,19 @@ Engines (the Fig. 18 ladder, DESIGN.md §3):
 
 Everything is a single ``lax.while_loop`` under jit; per-level statistics
 (direction, frontier size, scanned edges, scanned chunks) land in
-fixed-size arrays for the Fig. 17 breakdown benchmark.  ``bfs_batch``
-vmaps the bitmap engine over the 64 Graph500 search keys so the whole
-benchmark is one jitted program (see ``core/teps.py``).
+fixed-size arrays (``BFSStats``).  ``bfs_batch`` vmaps the bitmap engine
+over the 64 Graph500 search keys so the whole benchmark is one jitted
+program (see ``core/teps.py``).
+
+The bitmap engines name their phases with ``jax.named_scope``, so a
+device trace charges every op to one: ``bfs.init`` (state set-up),
+``bfs.td_relax`` (chunk mask and chunked top-down relax), ``bfs.bu_core``
+(the dense-core kernel and its winners' scatter-min), ``bfs.bu_relax``
+(the flat bottom-up relax over the tail edges), ``bfs.epilogue`` (the
+direction switch, delta pack, ``frontier_update``, sentinels and the
+state update), ``bfs.exchange`` (the sharded engine's delta exchange)
+and ``bfs.finish`` (the parent unpack).  Scopes are op metadata only:
+the compiled program is the same with or without them.
 """
 from __future__ import annotations
 
@@ -372,105 +382,115 @@ def _run_bitmap_impl(
 ) -> BFSResult:
     v = chunks.num_vertices
     w = padded_bitmap_words(v)
-    nnz_total = jnp.sum(degree).astype(jnp.int32)
+    with jax.named_scope("bfs.init"):
+        nnz_total = jnp.sum(degree).astype(jnp.int32)
 
-    parent_ext = jnp.full((v + 1,), v, jnp.int32).at[root].set(root)
-    level = jnp.full((v,), -1, jnp.int32).at[root].set(0)
-    # pack once at init: the root is the only set bit.
-    root_bit = jnp.uint32(1) << (root % 32).astype(jnp.uint32)
-    frontier_bm = jnp.zeros((w,), jnp.uint32).at[root // 32].set(root_bit)
-    visited_bm = frontier_bm
-    deg_root = degree[root].astype(jnp.int32)
+        parent_ext = jnp.full((v + 1,), v, jnp.int32).at[root].set(root)
+        level = jnp.full((v,), -1, jnp.int32).at[root].set(0)
+        # pack once at init: the root is the only set bit.
+        root_bit = jnp.uint32(1) << (root % 32).astype(jnp.uint32)
+        frontier_bm = jnp.zeros((w,), jnp.uint32).at[root // 32].set(root_bit)
+        visited_bm = frontier_bm
+        deg_root = degree[root].astype(jnp.int32)
 
-    # Flat edge views for the bottom-up pass: BU frontiers are large (the
-    # whole point of the direction switch), so chunk skipping cannot win
-    # there — one vectorized relax over the (tail) edges is strictly
-    # better than 64 dependent chunk iterations.
-    src_flat = chunks.src.reshape(-1)
-    dst_flat = chunks.dst.reshape(-1)
-    if use_core:
-        tail_flat = (chunks.valid
-                     & ~((chunks.src < core.k) & (chunks.dst < core.k))
-                     ).reshape(-1)
-    else:
-        tail_flat = chunks.valid.reshape(-1)
+        # Flat edge views for the bottom-up pass: BU frontiers are large (the
+        # whole point of the direction switch), so chunk skipping cannot win
+        # there — one vectorized relax over the (tail) edges is strictly
+        # better than 64 dependent chunk iterations.
+        src_flat = chunks.src.reshape(-1)
+        dst_flat = chunks.dst.reshape(-1)
+        if use_core:
+            tail_flat = (chunks.valid
+                         & ~((chunks.src < core.k) & (chunks.dst < core.k))
+                         ).reshape(-1)
+        else:
+            tail_flat = chunks.valid.reshape(-1)
 
     def cond(s: _ResidentState):
         return (s.in_count > 0) & (s.lvl < max_levels)
 
     def body(s: _ResidentState):
-        # Under vmap (bfs_batch) the while loop runs until *all* roots are
-        # done; `alive` masks the state update for roots already finished.
-        alive = s.in_count > 0
-
-        direction = _switch_direction(
-            s.direction, s.in_count, s.vis_count, n_active, alpha, beta)
+        with jax.named_scope("bfs.epilogue"):
+            # Under vmap (bfs_batch) the while loop runs until *all* roots
+            # are done; `alive` masks the state update for roots already
+            # finished.
+            alive = s.in_count > 0
+            direction = _switch_direction(
+                s.direction, s.in_count, s.vis_count, n_active, alpha, beta)
+            bottom_up = direction == BOTTOM_UP
 
         def bu(_):
             # Dense-core kernel step (consuming the resident bitmap), then
             # ONE vectorized relax over the tail edges — BU frontiers are
             # large, so there is nothing for chunk skipping to skip.
             if use_core:
-                p1 = _core_bottom_up_resident(
-                    core, s.frontier_bm, s.visited_bm, s.parent_ext,
-                    v, use_pallas_core)
+                with jax.named_scope("bfs.bu_core"):
+                    p1 = _core_bottom_up_resident(
+                        core, s.frontier_bm, s.visited_bm, s.parent_ext,
+                        v, use_pallas_core)
             else:
                 p1 = s.parent_ext
-            p2 = _relax_edges(
-                src_flat, dst_flat, tail_flat, s.frontier_bm, s.visited_bm,
-                p1, v)
+            with jax.named_scope("bfs.bu_relax"):
+                p2 = _relax_edges(
+                    src_flat, dst_flat, tail_flat, s.frontier_bm,
+                    s.visited_bm, p1, v)
             return p2, jnp.int32(chunks.n_chunks)  # full scan
 
         def td(_):
-            live = chunk_frontier_mask(chunks, s.frontier_bm)
-            return _chunked_relax(
-                chunks, live, s.frontier_bm, s.visited_bm, s.parent_ext, v)
+            with jax.named_scope("bfs.td_relax"):
+                live = chunk_frontier_mask(chunks, s.frontier_bm)
+                return _chunked_relax(chunks, live, s.frontier_bm,
+                                      s.visited_bm, s.parent_ext, v)
 
-        new_parent, nsc = jax.lax.cond(direction == BOTTOM_UP, bu, td, None)
+        new_parent, nsc = jax.lax.cond(bottom_up, bu, td, None)
 
-        # Epilogue: the newly-found delta (needed for level bookkeeping
-        # anyway) packs word-wise into next_raw (I3), then the fused
-        # kernel does mask / merge / popcount in one pass (T1).
-        newly = (new_parent[:v] != v) & (s.parent_ext[:v] == v)
-        if fault is not None and fault.site == "parent":
-            pv = faults.corrupt_parent(
-                fault, new_parent[:v], newly,
-                jnp.arange(v, dtype=jnp.int32), jnp.int32(v),
-                level=s.lvl, root=root)
-            new_parent = jnp.concatenate([pv, new_parent[v:]])
-        found = _pack_delta_words(newly, w)
-        next_bm, new_visited_bm, count = kops.frontier_update(found, s.visited_bm)
+        with jax.named_scope("bfs.epilogue"):
+            # Epilogue: the newly-found delta (needed for level bookkeeping
+            # anyway) packs word-wise into next_raw (I3), then the fused
+            # kernel does mask / merge / popcount in one pass (T1).
+            newly = (new_parent[:v] != v) & (s.parent_ext[:v] == v)
+            if fault is not None and fault.site == "parent":
+                pv = faults.corrupt_parent(
+                    fault, new_parent[:v], newly,
+                    jnp.arange(v, dtype=jnp.int32), jnp.int32(v),
+                    level=s.lvl, root=root)
+                new_parent = jnp.concatenate([pv, new_parent[v:]])
+            found = _pack_delta_words(newly, w)
+            next_bm, new_visited_bm, count = kops.frontier_update(
+                found, s.visited_bm)
 
-        # In-loop sentinels (§13): delta conservation (no found bit was
-        # already visited), frontier ∩ visited = ∅, level bound.
-        s1 = count.astype(jnp.int32) == jnp.sum(
-            popcount_u32(found)).astype(jnp.int32)
-        s2 = jnp.sum(popcount_u32(next_bm & s.visited_bm)) == 0
-        s3 = s.lvl + 1 <= jnp.int32(max_levels)
-        ok_mask = (s1.astype(jnp.int32) + 2 * s2.astype(jnp.int32)
-                   + 4 * s3.astype(jnp.int32))
+            # In-loop sentinels (§13): delta conservation (no found bit was
+            # already visited), frontier ∩ visited = ∅, level bound.
+            s1 = count.astype(jnp.int32) == jnp.sum(
+                popcount_u32(found)).astype(jnp.int32)
+            s2 = jnp.sum(popcount_u32(next_bm & s.visited_bm)) == 0
+            s3 = s.lvl + 1 <= jnp.int32(max_levels)
+            ok_mask = (s1.astype(jnp.int32) + 2 * s2.astype(jnp.int32)
+                       + 4 * s3.astype(jnp.int32))
 
-        new_level = jnp.where(newly, s.lvl + 1, s.level)
-        m_next = jnp.sum(jnp.where(newly, degree, 0)).astype(jnp.int32)
+            new_level = jnp.where(newly, s.lvl + 1, s.level)
+            m_next = jnp.sum(jnp.where(newly, degree, 0)).astype(jnp.int32)
 
-        # scanned-edge estimate, maintained incrementally (paper Fig. 17):
-        # TD scans frontier adjacency (m_f), BU scans unvisited adjacency.
-        m_u = nnz_total - s.deg_vis
-        scanned = jnp.where(direction == TOP_DOWN, s.m_f, m_u).astype(jnp.int32)
+            # scanned-edge estimate, kept incrementally (paper Fig. 17):
+            # TD scans frontier adjacency (m_f), BU unvisited adjacency.
+            m_u = nnz_total - s.deg_vis
+            scanned = jnp.where(direction == TOP_DOWN, s.m_f,
+                                m_u).astype(jnp.int32)
 
-        nxt = _ResidentState(
-            new_parent, new_level, next_bm, new_visited_bm,
-            count.astype(jnp.int32), s.vis_count + count.astype(jnp.int32),
-            m_next, s.deg_vis + m_next,
-            s.lvl + 1, direction,
-            s.stats_dir.at[s.lvl].set(direction),
-            s.stats_fs.at[s.lvl].set(s.in_count),
-            s.stats_se.at[s.lvl].set(scanned),
-            s.stats_ch.at[s.lvl].set(nsc),
-            s.stats_ok.at[s.lvl].set(ok_mask),
-        )
-        return jax.tree_util.tree_map(
-            lambda new, old: jnp.where(alive, new, old), nxt, s)
+            nxt = _ResidentState(
+                new_parent, new_level, next_bm, new_visited_bm,
+                count.astype(jnp.int32),
+                s.vis_count + count.astype(jnp.int32),
+                m_next, s.deg_vis + m_next,
+                s.lvl + 1, direction,
+                s.stats_dir.at[s.lvl].set(direction),
+                s.stats_fs.at[s.lvl].set(s.in_count),
+                s.stats_se.at[s.lvl].set(scanned),
+                s.stats_ch.at[s.lvl].set(nsc),
+                s.stats_ok.at[s.lvl].set(ok_mask),
+            )
+            return jax.tree_util.tree_map(
+                lambda new, old: jnp.where(alive, new, old), nxt, s)
 
     init = _ResidentState(
         parent_ext, level, frontier_bm, visited_bm,
@@ -485,7 +505,8 @@ def _run_bitmap_impl(
     s = jax.lax.while_loop(cond, body, init)
     # unpack once at exit: outputs are the parent/level arrays (the resident
     # bitmaps never leave packed form).
-    parent = jnp.where(s.parent_ext[:v] == v, -1, s.parent_ext[:v])
+    with jax.named_scope("bfs.finish"):
+        parent = jnp.where(s.parent_ext[:v] == v, -1, s.parent_ext[:v])
     return BFSResult(
         parent=parent,
         level=s.level,
@@ -865,42 +886,45 @@ def _run_bitmap_sharded(
             return (dev + (slots_loc // 32) * n_dev) * 32 + slots_loc % 32
         return slots_loc + start
 
-    # --- init: the root bit is set once; owner holds parent/level/visited.
-    is_mine, root_slot = to_local(root)
-    slots = jnp.arange(v_loc, dtype=jnp.int32)
-    parent_loc = jnp.where((slots == root_slot) & is_mine, root,
-                           jnp.int32(v_pad))
-    parent_loc = jnp.concatenate(
-        [parent_loc, jnp.full((1,), v_pad, jnp.int32)])
-    level_loc = jnp.where((slots == root_slot) & is_mine, 0, -1)
-    level_loc = level_loc.astype(jnp.int32)
-    root_bit = jnp.uint32(1) << (root % 32).astype(jnp.uint32)
-    frontier_bm = jnp.zeros((w_pad,), jnp.uint32).at[root // 32].set(root_bit)
-    word_slot = jnp.clip(root_slot // 32, 0, w_loc - 1)
-    visited_loc = jnp.where(
-        jnp.arange(w_loc) == word_slot,
-        jnp.where(is_mine, root_bit, jnp.uint32(0)),
-        jnp.uint32(0),
-    )
-    deg_root = jax.lax.psum(
-        jnp.where(is_mine,
-                  degree_loc[jnp.clip(root_slot, 0, v_loc - 1)],
-                  0).astype(jnp.int32), axes)
-    nnz_total = jax.lax.psum(jnp.sum(degree_loc).astype(jnp.int32), axes)
+    with jax.named_scope("bfs.init"):
+        # The root bit is set once; its owner holds parent/level/visited.
+        is_mine, root_slot = to_local(root)
+        slots = jnp.arange(v_loc, dtype=jnp.int32)
+        parent_loc = jnp.where((slots == root_slot) & is_mine, root,
+                               jnp.int32(v_pad))
+        parent_loc = jnp.concatenate(
+            [parent_loc, jnp.full((1,), v_pad, jnp.int32)])
+        level_loc = jnp.where((slots == root_slot) & is_mine, 0, -1)
+        level_loc = level_loc.astype(jnp.int32)
+        root_bit = jnp.uint32(1) << (root % 32).astype(jnp.uint32)
+        frontier_bm = jnp.zeros((w_pad,), jnp.uint32).at[root // 32].set(
+            root_bit)
+        word_slot = jnp.clip(root_slot // 32, 0, w_loc - 1)
+        visited_loc = jnp.where(
+            jnp.arange(w_loc) == word_slot,
+            jnp.where(is_mine, root_bit, jnp.uint32(0)),
+            jnp.uint32(0),
+        )
+        deg_root = jax.lax.psum(
+            jnp.where(is_mine,
+                      degree_loc[jnp.clip(root_slot, 0, v_loc - 1)],
+                      0).astype(jnp.int32), axes)
+        nnz_total = jax.lax.psum(jnp.sum(degree_loc).astype(jnp.int32),
+                                 axes)
 
-    # Bottom-up scans the owned chunks front-to-back; the dense core
-    # covers (src < K) & (dst < K), so shards owning core rows drop those
-    # edges from their tail.  Shard padding is a contiguous per-chunk
-    # tail (shard_graph), so the all-invalid chunks (sentinel
-    # src_hi = -1) form a suffix: BU relaxes only the live prefix — a
-    # light shard of a skewed partition never scans its pure-padding
-    # chunks (the chunk_range_mask kills the same chunks in TD).
-    if use_core:
-        dst_global = to_global(dst_loc)
-        tail = valid & ~((src < core.k) & (dst_global < core.k))
-    else:
-        tail = valid
-    n_live_chunks = jnp.sum(src_hi >= 0).astype(jnp.int32)
+        # Bottom-up scans the owned chunks front-to-back; the dense core
+        # covers (src < K) & (dst < K), so shards owning core rows drop
+        # those edges from their tail.  Shard padding is a contiguous
+        # per-chunk tail (shard_graph), so the all-invalid chunks (sentinel
+        # src_hi = -1) form a suffix: BU relaxes only the live prefix — a
+        # light shard of a skewed partition never scans its pure-padding
+        # chunks (the chunk_range_mask kills the same chunks in TD).
+        if use_core:
+            dst_global = to_global(dst_loc)
+            tail = valid & ~((src < core.k) & (dst_global < core.k))
+        else:
+            tail = valid
+        n_live_chunks = jnp.sum(src_hi >= 0).astype(jnp.int32)
 
     def core_step(frontier, visited, parent):
         """Dense-core bottom-up: full-core SpMV (replicated work), winners
@@ -939,14 +963,18 @@ def _run_bitmap_sharded(
         return (s.in_count > 0) & (s.lvl < max_levels)
 
     def body(s: _ShardState):
-        alive = s.in_count > 0   # batched-roots guard (vmap over roots)
-
-        direction = _switch_direction(
-            s.direction, s.in_count, s.vis_count, n_active, alpha, beta)
+        with jax.named_scope("bfs.epilogue"):
+            alive = s.in_count > 0   # batched-roots guard (vmap over roots)
+            direction = _switch_direction(
+                s.direction, s.in_count, s.vis_count, n_active, alpha, beta)
+            bottom_up = direction == BOTTOM_UP
 
         def bu(_):
-            p1 = (core_step(s.frontier_bm, s.visited_loc, s.parent_loc)
-                  if use_core else s.parent_loc)
+            if use_core:
+                with jax.named_scope("bfs.bu_core"):
+                    p1 = core_step(s.frontier_bm, s.visited_loc, s.parent_loc)
+            else:
+                p1 = s.parent_loc
 
             def body(c, p):
                 sc = jax.lax.dynamic_index_in_dim(src, c, 0, keepdims=False)
@@ -959,81 +987,88 @@ def _run_bitmap_sharded(
             # Only the live-chunk prefix: BU frontiers are large so there
             # is nothing for *frontier*-range skipping to win, but a light
             # shard's padding suffix is dead for every frontier.
-            p2 = jax.lax.fori_loop(0, n_live_chunks, body, p1)
+            with jax.named_scope("bfs.bu_relax"):
+                p2 = jax.lax.fori_loop(0, n_live_chunks, body, p1)
             return p2, n_live_chunks
 
         def td(_):
-            return chunked_td(s.frontier_bm, s.visited_loc, s.parent_loc)
+            with jax.named_scope("bfs.td_relax"):
+                return chunked_td(s.frontier_bm, s.visited_loc, s.parent_loc)
 
-        new_parent, nsc = jax.lax.cond(direction == BOTTOM_UP, bu, td, None)
+        new_parent, nsc = jax.lax.cond(bottom_up, bu, td, None)
 
         # Epilogue: pack the owned delta words (I3), OR-combine across the
         # mesh (T3 two-phase), fuse the owned-slice mask/merge/popcount.
-        newly = (new_parent[:v_loc] != v_pad) & (s.parent_loc[:v_loc] == v_pad)
-        if fault is not None and fault.site == "parent":
-            pv = faults.corrupt_parent(
-                fault, new_parent[:v_loc], newly, to_global(slots),
-                jnp.int32(v_pad), level=s.lvl, device=dev, root=root)
-            new_parent = jnp.concatenate([pv, new_parent[v_loc:]])
-        delta_loc = _pack_delta_words(newly, w_loc)
-        next_bm = _exchange_delta(
-            delta_loc, dev, w_loc, n_dev, exchange=exchange,
-            group_axis=group_axis, member_axis=member_axis,
-            partition=partition, known_bm=s.known_bm,
-            fault=fault, level=s.lvl, root=root)
-        in_count = jnp.sum(popcount_u32(next_bm)).astype(jnp.int32)
+        with jax.named_scope("bfs.epilogue"):
+            newly = ((new_parent[:v_loc] != v_pad)
+                     & (s.parent_loc[:v_loc] == v_pad))
+            if fault is not None and fault.site == "parent":
+                pv = faults.corrupt_parent(
+                    fault, new_parent[:v_loc], newly, to_global(slots),
+                    jnp.int32(v_pad), level=s.lvl, device=dev, root=root)
+                new_parent = jnp.concatenate([pv, new_parent[v_loc:]])
+            delta_loc = _pack_delta_words(newly, w_loc)
+        with jax.named_scope("bfs.exchange"):
+            next_bm = _exchange_delta(
+                delta_loc, dev, w_loc, n_dev, exchange=exchange,
+                group_axis=group_axis, member_axis=member_axis,
+                partition=partition, known_bm=s.known_bm,
+                fault=fault, level=s.lvl, root=root)
+        with jax.named_scope("bfs.epilogue"):
+            in_count = jnp.sum(popcount_u32(next_bm)).astype(jnp.int32)
 
-        # In-loop sentinels (§13): exchange conservation (the combined
-        # next frontier must carry exactly the bits the shards packed —
-        # owner words are disjoint, so popcounts add), frontier ∩ visited
-        # = ∅ over the owned slice, level bound.  A corrupted exchange
-        # (dropped leg, mangled codec, stale sieve, flipped word) breaks
-        # one of the first two the moment it fires.
-        delta_sum = jax.lax.psum(
-            jnp.sum(popcount_u32(delta_loc)).astype(jnp.int32), axes)
-        if cyclic:
-            own_next = jnp.take(next_bm.reshape(w_loc, n_dev), dev, axis=1)
-        else:
-            own_next = jax.lax.dynamic_slice(next_bm, (dev * w_loc,),
-                                             (w_loc,))
-        overlap = jax.lax.psum(
-            jnp.sum(popcount_u32(own_next & s.visited_loc)).astype(jnp.int32),
-            axes)
-        s1 = in_count == delta_sum
-        s2 = overlap == 0
-        s3 = s.lvl + 1 <= jnp.int32(max_levels)
-        ok_mask = (s1.astype(jnp.int32) + 2 * s2.astype(jnp.int32)
-                   + 4 * s3.astype(jnp.int32))
-        if w_loc % WORDS_PER_TILE == 0:
-            _, new_visited_loc, _ = kops.frontier_update(
-                delta_loc, s.visited_loc)
-        else:
-            # owned word blocks below the kernel tile: plain fused OR
-            # (delta bits are never already-visited — owner exactness).
-            new_visited_loc = s.visited_loc | delta_loc
+            # In-loop sentinels (§13): exchange conservation (the combined
+            # next frontier must carry exactly the bits the shards packed —
+            # owner words are disjoint, so popcounts add), frontier ∩ visited
+            # = ∅ over the owned slice, level bound.  A corrupted exchange
+            # (dropped leg, mangled codec, stale sieve, flipped word) breaks
+            # one of the first two the moment it fires.
+            delta_sum = jax.lax.psum(
+                jnp.sum(popcount_u32(delta_loc)).astype(jnp.int32), axes)
+            if cyclic:
+                own_next = jnp.take(next_bm.reshape(w_loc, n_dev), dev, axis=1)
+            else:
+                own_next = jax.lax.dynamic_slice(next_bm, (dev * w_loc,),
+                                                 (w_loc,))
+            overlap = jax.lax.psum(jnp.sum(
+                popcount_u32(own_next & s.visited_loc)).astype(jnp.int32),
+                axes)
+            s1 = in_count == delta_sum
+            s2 = overlap == 0
+            s3 = s.lvl + 1 <= jnp.int32(max_levels)
+            ok_mask = (s1.astype(jnp.int32) + 2 * s2.astype(jnp.int32)
+                       + 4 * s3.astype(jnp.int32))
+            if w_loc % WORDS_PER_TILE == 0:
+                _, new_visited_loc, _ = kops.frontier_update(
+                    delta_loc, s.visited_loc)
+            else:
+                # owned word blocks below the kernel tile: plain fused OR
+                # (delta bits are never already-visited — owner exactness).
+                new_visited_loc = s.visited_loc | delta_loc
 
-        new_level = jnp.where(newly, s.lvl + 1, s.level_loc)
-        m_next = jax.lax.psum(
-            jnp.sum(jnp.where(newly, degree_loc, 0)).astype(jnp.int32), axes)
-        nsc_all = jax.lax.psum(nsc, axes)
+            new_level = jnp.where(newly, s.lvl + 1, s.level_loc)
+            m_next = jax.lax.psum(jnp.sum(
+                jnp.where(newly, degree_loc, 0)).astype(jnp.int32), axes)
+            nsc_all = jax.lax.psum(nsc, axes)
 
-        m_u = nnz_total - s.deg_vis
-        scanned = jnp.where(direction == TOP_DOWN, s.m_f, m_u).astype(jnp.int32)
+            m_u = nnz_total - s.deg_vis
+            scanned = jnp.where(direction == TOP_DOWN, s.m_f,
+                                m_u).astype(jnp.int32)
 
-        nxt = _ShardState(
-            new_parent, new_level, next_bm, new_visited_loc,
-            s.known_bm | next_bm,
-            in_count, s.vis_count + in_count,
-            m_next, s.deg_vis + m_next,
-            s.lvl + 1, direction,
-            s.stats_dir.at[s.lvl].set(direction),
-            s.stats_fs.at[s.lvl].set(s.in_count),
-            s.stats_se.at[s.lvl].set(scanned),
-            s.stats_ch.at[s.lvl].set(nsc_all),
-            s.stats_ok.at[s.lvl].set(ok_mask),
-        )
-        return jax.tree_util.tree_map(
-            lambda new, old: jnp.where(alive, new, old), nxt, s)
+            nxt = _ShardState(
+                new_parent, new_level, next_bm, new_visited_loc,
+                s.known_bm | next_bm,
+                in_count, s.vis_count + in_count,
+                m_next, s.deg_vis + m_next,
+                s.lvl + 1, direction,
+                s.stats_dir.at[s.lvl].set(direction),
+                s.stats_fs.at[s.lvl].set(s.in_count),
+                s.stats_se.at[s.lvl].set(scanned),
+                s.stats_ch.at[s.lvl].set(nsc_all),
+                s.stats_ok.at[s.lvl].set(ok_mask),
+            )
+            return jax.tree_util.tree_map(
+                lambda new, old: jnp.where(alive, new, old), nxt, s)
 
     init = _ShardState(
         parent_loc, level_loc, frontier_bm, visited_loc, frontier_bm,
@@ -1046,7 +1081,9 @@ def _run_bitmap_sharded(
         jnp.full((max_levels,), -1, jnp.int32),
     )
     s = jax.lax.while_loop(cond, body, init)
-    parent = jnp.where(s.parent_loc[:v_loc] == v_pad, -1, s.parent_loc[:v_loc])
+    with jax.named_scope("bfs.finish"):
+        parent = jnp.where(s.parent_loc[:v_loc] == v_pad, -1,
+                           s.parent_loc[:v_loc])
     return BFSResult(
         parent=parent,
         level=s.level_loc,

@@ -889,22 +889,25 @@ class CompiledBFS:
     def bfs(self, roots):
         """Raw traversal(s).  ``batch_roots`` plans take a root vector
         (padded to the root-axis size with ``roots[0]`` and sliced back);
-        per-root plans take a scalar root."""
-        if not self.plan.batch_roots:
-            out = self._raw(jnp.asarray(roots, jnp.int32))
-            return ShardedRun(*out) if self._vertexy else out
-        roots = jnp.asarray(roots, jnp.int32)
-        n = roots.shape[0]
-        pad = (-n) % self._root_axis_size
-        if pad:
-            roots = jnp.concatenate(
-                [roots, jnp.broadcast_to(roots[:1], (pad,))])
-        out = self._raw(roots)
-        if self._vertexy:
-            out = ShardedRun(*out)
-        if pad:
-            out = jax.tree_util.tree_map(lambda x: x[:n], out)
-        return out
+        per-root plans take a scalar root.  A profiler trace shows the
+        placement of the roots and the dispatch of the program as the host
+        span ``bfs.call``."""
+        with jax.profiler.TraceAnnotation("bfs.call"):
+            if not self.plan.batch_roots:
+                out = self._raw(jnp.asarray(roots, jnp.int32))
+                return ShardedRun(*out) if self._vertexy else out
+            roots = jnp.asarray(roots, jnp.int32)
+            n = roots.shape[0]
+            pad = (-n) % self._root_axis_size
+            if pad:
+                roots = jnp.concatenate(
+                    [roots, jnp.broadcast_to(roots[:1], (pad,))])
+            out = self._raw(roots)
+            if self._vertexy:
+                out = ShardedRun(*out)
+            if pad:
+                out = jax.tree_util.tree_map(lambda x: x[:n], out)
+            return out
 
     def _strip(self, x):    # drop shard padding on the device, not via H2D
         v = self.num_vertices

@@ -351,3 +351,48 @@ for shape, axes in (((2, 4), ("data", "model")),
 print("OK")
 """)
     assert "OK" in out
+
+
+# ---------------------------------------------------------------------------
+# Names a profiler trace finds: the engine's phase scopes and the host span
+# of a call.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def per_root_s8():
+    from repro.core import pipeline
+
+    cfg = pipeline.Graph500Config.ladder("pre-g500", scale=8, n_roots=1)
+    return compile_plan(cfg.to_plan(), pipeline.build(cfg))
+
+
+def test_per_root_program_names_its_phases(per_root_s8):
+    import re
+
+    text = per_root_s8.lower(0).compile().as_text()
+    phases = {p for m in re.findall(r'op_name="([^"]*)"', text)
+              for p in m.split("/") if p.startswith("bfs.")}
+    assert phases == {"bfs.init", "bfs.td_relax", "bfs.bu_core",
+                      "bfs.bu_relax", "bfs.epilogue", "bfs.finish"}, phases
+
+
+def test_bfs_call_is_a_host_span(per_root_s8, tmp_path):
+    """``CompiledBFS.bfs`` writes one ``bfs.call`` span per call into a
+    profiler trace, on the clock the device ops share."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    per_root_s8.bfs(0).parent.block_until_ready()      # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for root in (0, 1):
+            per_root_s8.bfs(root).parent.block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = [e.name for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events]
+    assert names.count("bfs.call") == 2

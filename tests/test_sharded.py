@@ -469,6 +469,32 @@ print("OK")
     assert "OK" in out
 
 
+def test_vertex_sharded_program_names_its_phases():
+    """The vertex-sharded program carries the single-device engine's
+    phase scopes, and its delta exchange's collectives sit under
+    ``bfs.exchange``, apart from the epilogue's counts (``psum``)."""
+    out = run_sub(PREAMBLE + """
+import re
+from repro.core.distributed_bfs import shard_graph
+g, ev, core, chunks = sorted_graph(9, seed=5, threshold=8)
+src, dst, valid = (np.asarray(t) for t in csr_to_edge_arrays(g))
+sg = shard_graph(src, dst, valid, g.num_vertices, 8)
+mesh = make_mesh((2, 4), ("group", "member"))
+text = vertex_plan(mesh, sg, core=core, degree=g.degree).lower(5).compile(
+    ).as_text()
+paths = [m.split("/") for m in re.findall(r'op_name="([^"]*)"', text)]
+phases = {p for path in paths for p in path if p.startswith("bfs.")}
+assert phases == {"bfs.init", "bfs.td_relax", "bfs.bu_core", "bfs.bu_relax",
+                  "bfs.epilogue", "bfs.exchange", "bfs.finish"}, phases
+moves = re.findall(r" (?:all-gather|all-to-all)(?:-start)?\\(.*", text)
+assert moves
+for inst in moves:
+    assert "/bfs.exchange/" in inst, inst[:200]
+print("OK")
+""")
+    assert "OK" in out
+
+
 def test_vertex_sharded_runner_harness():
     out = run_sub(PREAMBLE + """
 from repro.core import sample_roots

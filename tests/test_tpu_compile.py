@@ -10,6 +10,7 @@ pytest-xdist workers different test lists.
 """
 import functools
 import os
+import re
 
 import pytest
 
@@ -72,11 +73,87 @@ def test_core_spmv_compiles(one_chip, batch):
     assert "tpu_custom_call" in _compiled_text(fn, a, f)
 
 
-def test_per_root_program_compiles(one_chip, monkeypatch):
-    """One whole per-root ``_run_bitmap`` program, as ``compile_plan``
-    builds it for the ``pre-g500`` rung, with compiled kernels."""
+@pytest.fixture(scope="module")
+def per_root_text(one_chip):
+    """The compiled text of one whole per-root ``_run_bitmap`` program,
+    as ``compile_plan`` builds it for the ``pre-g500`` rung, with
+    compiled kernels."""
     cfg = pipeline.Graph500Config.ladder("pre-g500", scale=10, n_roots=1)
     compiled = compile_plan(cfg.to_plan(), pipeline.build(cfg))
-    monkeypatch.setattr(kops, "interpret_mode", lambda: False)
-    text = compiled.lower(0, sharding=one_chip).compile().as_text()
-    assert text.count("tpu_custom_call") >= 2   # epilogue + dense core
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kops, "interpret_mode", lambda: False)
+        return compiled.lower(0, sharding=one_chip).compile().as_text()
+
+
+def test_per_root_program_compiles(per_root_text):
+    assert per_root_text.count("tpu_custom_call") >= 2   # epilogue + core
+
+
+_CALLEES = re.compile(r"(?:body|condition|calls|to_apply)=%([\w.\-]+)"
+                      r"|branch_computations=\{([^}]*)\}")
+
+
+def _computations(text) -> dict:
+    """Computation name -> its instruction lines, from compiled HLO text."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) ", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and line.strip():
+            comps[name].append(line.strip())
+    return comps
+
+
+def _level_loop_ops(text) -> list:
+    """The fusions and custom calls in the level loop's body and in every
+    computation it calls (branches, inner loops, fusions)."""
+    comps = _computations(text)
+    entry = re.search(r"^ENTRY %(\S+) ", text, re.M).group(1)
+    loop = [i for i in comps[entry] if " while(" in i]
+    assert len(loop) == 1, loop
+    todo, seen = [re.search(r"body=%([\w.\-]+)", loop[0]).group(1)], set()
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for inst in comps[name]:
+            for one, many in _CALLEES.findall(inst):
+                todo += [one] if one else [
+                    c.strip().lstrip("%") for c in many.split(",")]
+    return [i for name in seen for i in comps[name]
+            if re.search(r" (fusion|custom-call)\(", i)]
+
+
+def _op_path(inst) -> list:
+    m = re.search(r'op_name="([^"]*)"', inst)
+    return m.group(1).split("/") if m else []
+
+
+def test_per_root_level_loop_ops_carry_bfs_scopes(per_root_text):
+    """Every fusion and custom call the level loop runs names its engine
+    phase, so a device trace can charge its time to one.  Instructions
+    XLA makes itself carry no traced path (no op_name, or the bare name
+    of a cached JAX lowering such as ``reduce_window_sum``): no program
+    code can scope those, and a trace shows their time under no phase."""
+    ops = _level_loop_ops(per_root_text)
+    traced = [i for i in ops if len(_op_path(i)) > 1]
+    assert len(traced) >= 20, len(traced)
+    unscoped = [i[:160] for i in traced
+                if not any(p.startswith("bfs.") for p in _op_path(i))]
+    assert not unscoped, unscoped
+    phases = {p for i in traced for p in _op_path(i) if p.startswith("bfs.")}
+    assert phases == {"bfs.epilogue", "bfs.td_relax", "bfs.bu_core",
+                      "bfs.bu_relax"}, phases
+
+
+def test_per_root_kernels_keep_their_names(per_root_text):
+    """The Pallas kernels compile to custom calls named after their
+    wrappers, which the roofline readers match by name."""
+    kernels = re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                         r'"tpu_custom_call"', per_root_text)
+    assert sorted(set(kernels)) == ["core_spmv", "frontier_update"], kernels
