@@ -8,16 +8,16 @@ One chip, offline phase: ``pipeline.build`` + ``pipeline.run`` of the
 ``pre-g500`` rung (per-root bitmap engine, dense heavy core, compiled
 Pallas kernels) at scale 20, edgefactor 16, 64 search keys, every root
 spec-validated.  Memory would allow scale 21 on one v5e (16 GB): the
-scale-21 per-root program needs 1.5 GB of arguments and 1.4 GB of
+scale-21 per-root program needs 1.5 GB of arguments and 0.3 GB of
 temporaries, the check phase 2.9 GB.  Time does not: at scale 21 the 64
 searches and their checks took 757 s on a v5e, and the whole run must
 stay well inside 1200 s.
 
 One chip, serving phase: ``pipeline.serve`` with the default
 ``ServeConfig`` batch of 8 at scale 18, answering a short Poisson x
-Zipf trace.  Memory would allow scale 20 (the batch program needs 10 GB
-there), but under ``vmap`` every level runs both directions for all 8
-roots, and one scale-20 batch takes minutes.  Every answer is
+Zipf trace.  Memory would allow scale 21 (the batch program needs
+6.2 GB there), but under ``vmap`` every level runs both directions for
+all 8 roots, and one scale-20 batch takes minutes.  Every answer is
 spec-checked and must equal the offline per-root engine's parents for
 the same root.
 

@@ -4,8 +4,13 @@ A BFS level over an undirected graph is a Boolean-semiring SpMV
 (DESIGN.md §2). With JAX's static-shape constraint the natural TPU-native
 formulation is *edge-parallel relaxation*: every directed CSR entry
 ``(u -> v)`` tests ``frontier[u] & ~visited[v]`` and scatter-mins its source
-into ``parent[v]``. Top-down and bottom-up coincide in this fully
-vectorized form — the *direction* distinction re-appears in
+into ``parent[v]`` — the push, which these reference steps and the bitmap
+engine's top-down use.  The bitmap engine's bottom-up reads the same
+slots as rows instead: the CSR is symmetric and src-sorted, so each
+unvisited ``x`` takes the min frontier ``u`` over its own contiguous
+slots ``(x, u)`` — a pull with one bit gather per slot and a dense
+segmented min, no scatter (``hybrid_bfs._pull_relax``).  Direction also
+shows in
 
   * the kernelized bottom-up core step (``kernels/frontier_spmv``), which
     scans the dense heavy-vertex corner bitmap-wide with early-exit-free

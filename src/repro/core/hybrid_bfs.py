@@ -19,13 +19,17 @@ Engines (the Fig. 18 ladder, DESIGN.md §3):
     measured "before" rung for BENCH_bfs.json.
   * ``bitmap``    — the bitmap-resident Pre-G500 engine (T1 + T2):
     ``frontier`` and ``visited`` live as packed ``uint32 [W]`` across the
-    whole ``lax.while_loop`` (bits set once at init, never unpacked inside
-    the loop), the level epilogue (mask / merge / popcount) runs the fused
-    ``kernels.ops.frontier_update`` Pallas kernel, the bottom-up core step
-    consumes the resident bitmap directly, and top-down is *chunked*: the
-    degree-sorted edge array is split into fixed chunks whose source-vertex
-    ranges are tested against the frontier bitmap so small frontiers skip
-    most of the edge scan (frontier-proportional work, DESIGN.md §3).
+    whole ``lax.while_loop`` (bits set once at init, never re-packed
+    inside the loop), the level epilogue (mask / merge / popcount) runs
+    the fused ``kernels.ops.frontier_update`` Pallas kernel, the bottom-up
+    core step consumes the resident bitmap directly, and top-down is
+    *chunked*: the degree-sorted edge array is split into fixed chunks
+    whose source-vertex ranges are tested against the frontier bitmap so
+    small frontiers skip most of the edge scan (frontier-proportional
+    work, DESIGN.md §3).
+    Bottom-up, the tail edges are a *pull* over the src-sorted rows: each
+    unvisited row takes the min frontier neighbour among its own slots,
+    a dense segmented min with no scatter (``_pull_relax``).
 
 Everything is a single ``lax.while_loop`` under jit; per-level statistics
 (direction, frontier size, scanned edges, scanned chunks) land in
@@ -37,7 +41,7 @@ The bitmap engines name their phases with ``jax.named_scope``, so a
 device trace charges every op to one: ``bfs.init`` (state set-up),
 ``bfs.td_relax`` (chunk mask and chunked top-down relax), ``bfs.bu_core``
 (the dense-core kernel and its winners' scatter-min), ``bfs.bu_relax``
-(the flat bottom-up relax over the tail edges), ``bfs.epilogue`` (the
+(the pull over the tail edges' rows), ``bfs.epilogue`` (the
 direction switch, delta pack, ``frontier_update``, sentinels and the
 state update), ``bfs.exchange`` (the sharded engine's delta exchange)
 and ``bfs.finish`` (the parent unpack).  Scopes are op metadata only:
@@ -45,7 +49,9 @@ the compiled program is the same with or without them.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -252,8 +258,9 @@ def _run_legacy(
 # Loop invariants:
 #   I1. frontier_bm / visited_bm are packed uint32 [W] for the *whole*
 #       traversal — bits are set once at init and the resident state is
-#       never unpacked inside the while body (membership tests are
-#       single-bit word gathers).
+#       never re-packed inside the while body.  Per-slot membership tests
+#       are single-bit word gathers; the bottom-up pull's per-row visited
+#       test reads visited_bm by a dense shift/reshape, no gather.
 #   I2. in_count == popcount(frontier_bm); it comes from the fused
 #       frontier_update epilogue of the previous level, never recounted.
 #   I3. next-frontier bits are derived from the parent-array *delta*: the
@@ -261,9 +268,11 @@ def _run_legacy(
 #       and the epilogue packs it word-wise (O(V/32) output work) before
 #       the fused frontier_update — no per-edge bit bookkeeping, and no
 #       round trip of the resident frontier/visited state.
-#   I4. parent_ext is the scatter-min array of the boolean-semiring SpMV;
-#       the bitmap engine's parent/level outputs are byte-identical to the
-#       reference engine's.
+#   I4. parent_ext holds the min frontier neighbour of each newly found
+#       vertex: a scatter-min top-down and in the core step, a pull over
+#       the src-sorted rows bottom-up (the same min, since the CSR and the
+#       tail mask are symmetric); the bitmap engine's parent/level outputs
+#       are byte-identical to the reference engine's.
 # ---------------------------------------------------------------------------
 
 class _ResidentState(NamedTuple):
@@ -304,8 +313,7 @@ def _core_bottom_up_resident(core: HeavyCore, frontier_bm, visited_bm,
 
 
 def _relax_edges(sc, dc, vc, frontier_bm, visited_bm, parent, v):
-    """One edge-parallel relax pass in bitmap space (shared by the chunked
-    top-down and the flat bottom-up tail).
+    """One edge-parallel relax pass in bitmap space (the chunked top-down).
 
     Frontier/visited membership tests are single-bit gathers from the
     resident bitmaps; newly found vertices surface later as the parent
@@ -342,6 +350,123 @@ def _chunked_relax(chunks: ChunkedEdgeView, live, frontier_bm,
     return jax.lax.fori_loop(
         0, chunks.n_chunks, body, (parent_ext, jnp.int32(0))
     )
+
+
+#: Slots per tile of the bottom-up pull's in-tile scan (at most; it is
+#: ``gcd(chunk_size, PULL_TILE)``, so tiles always divide a chunk).
+PULL_TILE = 1024
+
+
+def _segmented_min(key: jax.Array, val: jax.Array) -> jax.Array:
+    """Inclusive running min of ``val`` along the last axis, restarting
+    wherever ``key`` changes.
+
+    ``key`` is sorted along that axis, so two slots ``k`` apart hold the
+    same key only if every slot between them does: log2(n) shifted
+    compare-and-min steps (Hillis-Steele), all dense, no scatter.
+    """
+    n = val.shape[-1]
+    k = 1
+    while k < n:
+        same = key[..., k:] == key[..., :-k]
+        upd = jnp.where(same, jnp.minimum(val[..., k:], val[..., :-k]),
+                        val[..., k:])
+        val = jnp.concatenate([val[..., :k], upd], axis=-1)
+        k *= 2
+    return val
+
+
+def _row_ends(degree: jax.Array) -> jax.Array:
+    """Each row's last slot in the src-sorted edge array, -1 for a row
+    with none: ``degree`` counts a row's valid slots, which come first."""
+    return jnp.where(degree > 0, jnp.cumsum(degree).astype(jnp.int32) - 1,
+                     -1)
+
+
+def _pull_relax(chunks: ChunkedEdgeView, core_k: int | None, row_end,
+                frontier_bm, visited_bm, parent_ext, v):
+    """Bottom-up tail relax as a pull over the src-sorted rows.
+
+    The CSR is symmetric and sorted by ``(src, dst)`` with padding at the
+    tail, and the tail mask (valid slots not inside the dense core) is
+    symmetric too, so the push ``parent[d] = min{s in frontier : (s, d)}``
+    over the tail equals, for each unvisited row ``x``, the min of the
+    frontier ``u`` over the row's own slots ``(x, u)`` (I4 holds by
+    symmetry).  Per slot that is one bit gather (frontier by ``dst``);
+    the min over each row's contiguous slots is a dense segmented scan:
+    in tiles of ``PULL_TILE`` slots, then over the tiles' last slots,
+    carried from chunk to chunk in the loop (hub rows span many tiles
+    and chunks).  Each row's min is read at its last slot ``row_end[x]``
+    (``_row_ends``), and the visited test is per row.
+    """
+    c_size = chunks.chunk_size
+    tile = math.gcd(c_size, PULL_TILE)
+
+    def body(c, carry):
+        rowmin, last_src, last_min = carry
+        s2, d2, v2 = (jax.lax.dynamic_index_in_dim(
+            a, c, 0, keepdims=False).reshape(-1, tile)
+            for a in (chunks.src, chunks.dst, chunks.valid))
+        tail = v2 if core_k is None else v2 & ~((s2 < core_k)
+                                                & (d2 < core_k))
+        m2 = _segmented_min(
+            s2, jnp.where(tail & testbit(frontier_bm, d2), d2, v))
+        # the running min of the row open at each tile's first slot
+        t_src = jnp.concatenate([last_src[None], s2[:, -1]])
+        t_min = _segmented_min(t_src,
+                               jnp.concatenate([last_min[None], m2[:, -1]]))
+        m2 = jnp.where(s2 == t_src[:-1, None],
+                       jnp.minimum(m2, t_min[:-1, None]), m2)
+        rowmin = jax.lax.dynamic_update_slice_in_dim(
+            rowmin, m2, c * (c_size // tile), 0)
+        return rowmin, t_src[-1], t_min[-1]
+
+    rowmin, _, _ = jax.lax.fori_loop(
+        0, chunks.n_chunks, body,
+        (jnp.full((chunks.n_chunks * c_size // tile, tile), v, jnp.int32),
+         jnp.int32(-1), jnp.int32(v)))
+    shifts = jnp.arange(32, dtype=jnp.uint32)
+    visited = ((visited_bm[:, None] >> shifts) & jnp.uint32(1)
+               ).reshape(-1)[:v].astype(bool)
+    end = jnp.maximum(row_end, 0)
+    found = jnp.where((row_end >= 0) & ~visited,
+                      rowmin[end // tile, end % tile], v)
+    return jnp.minimum(parent_ext, jnp.pad(found, (0, 1), constant_values=v))
+
+
+def _by_direction(bu, td):
+    """``lax.cond(bottom_up, bu, td, *args)``, batched without copying
+    the graph for bottom-up.
+
+    vmap of a ``cond`` whose predicate is batched (each root has its own
+    direction) runs both branches and selects, with every operand
+    broadcast to the batch first: the edge chunks and the dense core,
+    once per root.  Here ``td`` is batched just so (its chunk loop slices
+    the per-root chunks), while ``bu`` is vmapped over only the arguments
+    that are batched, so the pull and the core step read the one graph.
+    """
+    @jax.custom_batching.custom_vmap
+    def step(bottom_up, *args):
+        return jax.lax.cond(bottom_up, bu, td, *args)
+
+    @step.def_vmap
+    def _batched(axis_size, in_batched, bottom_up, *args):
+        ib = tuple(in_batched[1:])
+        axes = jax.tree.map(lambda b: 0 if b else None, ib)
+        every = jax.tree.map(
+            lambda x, b: x if b else jnp.broadcast_to(
+                x, (axis_size,) + x.shape), args, ib)
+        up = jnp.broadcast_to(bottom_up, (axis_size,))
+
+        def pick(a, b):
+            return jnp.where(up.reshape((-1,) + (1,) * (a.ndim - 1)), a, b)
+
+        out = jax.tree.map(
+            pick, jax.vmap(bu, in_axes=axes, axis_size=axis_size)(*args),
+            jax.vmap(td)(*every))
+        return out, jax.tree.map(lambda _: True, out)
+
+    return step
 
 
 def _pack_delta_words(newly: jax.Array, w: int) -> jax.Array:
@@ -393,18 +518,7 @@ def _run_bitmap_impl(
         visited_bm = frontier_bm
         deg_root = degree[root].astype(jnp.int32)
 
-        # Flat edge views for the bottom-up pass: BU frontiers are large (the
-        # whole point of the direction switch), so chunk skipping cannot win
-        # there — one vectorized relax over the (tail) edges is strictly
-        # better than 64 dependent chunk iterations.
-        src_flat = chunks.src.reshape(-1)
-        dst_flat = chunks.dst.reshape(-1)
-        if use_core:
-            tail_flat = (chunks.valid
-                         & ~((chunks.src < core.k) & (chunks.dst < core.k))
-                         ).reshape(-1)
-        else:
-            tail_flat = chunks.valid.reshape(-1)
+        row_end = _row_ends(degree)   # for the bottom-up pull
 
     def cond(s: _ResidentState):
         return (s.in_count > 0) & (s.lvl < max_levels)
@@ -419,30 +533,35 @@ def _run_bitmap_impl(
                 s.direction, s.in_count, s.vis_count, n_active, alpha, beta)
             bottom_up = direction == BOTTOM_UP
 
-        def bu(_):
+        def bu(ch, a_core, row_end, frontier_bm, visited_bm, parent_ext):
             # Dense-core kernel step (consuming the resident bitmap), then
-            # ONE vectorized relax over the tail edges — BU frontiers are
-            # large, so there is nothing for chunk skipping to skip.
+            # the pull over every tail slot's row: BU frontiers are large,
+            # so there is nothing for chunk skipping to skip.
             if use_core:
                 with jax.named_scope("bfs.bu_core"):
+                    # a_core is the step's argument, so that batched it
+                    # stays one copy (_by_direction); k is static.
                     p1 = _core_bottom_up_resident(
-                        core, s.frontier_bm, s.visited_bm, s.parent_ext,
+                        dataclasses.replace(core, a_core=a_core),
+                        frontier_bm, visited_bm, parent_ext,
                         v, use_pallas_core)
             else:
-                p1 = s.parent_ext
+                p1 = parent_ext
             with jax.named_scope("bfs.bu_relax"):
-                p2 = _relax_edges(
-                    src_flat, dst_flat, tail_flat, s.frontier_bm,
-                    s.visited_bm, p1, v)
-            return p2, jnp.int32(chunks.n_chunks)  # full scan
+                p2 = _pull_relax(
+                    ch, core.k if use_core else None, row_end,
+                    frontier_bm, visited_bm, p1, v)
+            return p2, jnp.int32(ch.n_chunks)  # full scan
 
-        def td(_):
+        def td(ch, a_core, row_end, frontier_bm, visited_bm, parent_ext):
             with jax.named_scope("bfs.td_relax"):
-                live = chunk_frontier_mask(chunks, s.frontier_bm)
-                return _chunked_relax(chunks, live, s.frontier_bm,
-                                      s.visited_bm, s.parent_ext, v)
+                live = chunk_frontier_mask(ch, frontier_bm)
+                return _chunked_relax(ch, live, frontier_bm,
+                                      visited_bm, parent_ext, v)
 
-        new_parent, nsc = jax.lax.cond(bottom_up, bu, td, None)
+        new_parent, nsc = _by_direction(bu, td)(
+            bottom_up, chunks, core.a_core if use_core else None, row_end,
+            s.frontier_bm, s.visited_bm, s.parent_ext)
 
         with jax.named_scope("bfs.epilogue"):
             # Epilogue: the newly-found delta (needed for level bookkeeping

@@ -261,6 +261,76 @@ def test_bitmap_engine_byte_identical_to_reference(scale):
         assert bool(validate(ev, res, jnp.int32(root)).ok)
 
 
+def _hub_graph():
+    """Vertex 0 joined to 3998 others, so its row spans four tiles of the
+    bottom-up pull and crosses the first of four chunk boundaries; vertex
+    17 and 4000-4095 isolated; self loops and a duplicate that the build
+    turns into padding slots."""
+    from repro.core import EdgeList
+
+    n = 4096
+    rng = np.random.default_rng(5)
+    spokes = np.setdiff1d(np.arange(1, 4000), [17])
+    a, b = (np.where(x == 17, 18, x) for x in rng.integers(1, 4000, (2, 93)))
+    src = np.concatenate([np.zeros_like(spokes), a, [7, 8, 9, 11], [0]])
+    dst = np.concatenate([spokes, b, [7, 8, 9, 11], [5]])
+    g = build_csr(EdgeList(src=jnp.asarray(src, jnp.int32),
+                           dst=jnp.asarray(dst, jnp.int32), num_vertices=n))
+    return g, chunk_edge_view(edge_view(g), 4)
+
+
+@pytest.mark.parametrize("visited", ["random", "all"])
+@pytest.mark.parametrize("case", ["pre-g500-s14", "hub", "hub-core64"])
+def test_bottom_up_pull_matches_push(case, visited):
+    """The bottom-up pull over src-sorted rows gives the same parents as
+    the push relax over the same tail slots, on random frontier and
+    visited bitmaps (DESIGN.md §3: I4 holds by symmetry)."""
+    import importlib
+    hb = importlib.import_module("repro.core.hybrid_bfs")
+    from repro.core.heavy import padded_bitmap_words
+
+    if case == "pre-g500-s14":
+        g, _, core, chunks = _sorted_graph(14, threshold=100)
+        core_k = core.k
+    else:
+        g, chunks = _hub_graph()
+        core_k = 64 if case == "hub-core64" else None
+    v = g.num_vertices
+    src, dst = chunks.src.reshape(-1), chunks.dst.reshape(-1)
+    tail = chunks.valid.reshape(-1)
+    if core_k is not None:
+        tail = tail & ~((src < core_k) & (dst < core_k))
+    deg = np.asarray(g.degree)
+    assert 0 < int(tail.sum()) and (deg == 0).any()
+    assert not bool(chunks.valid.reshape(-1)[-1])      # padding slots
+    if case != "pre-g500-s14":
+        tile = hb.PULL_TILE
+        assert chunks.chunk_size % tile == 0 and deg[0] >= 3 * tile
+        assert deg[0] > chunks.chunk_size and deg[17] == 0
+
+    row_end = hb._row_ends(g.degree)
+    push = jax.jit(lambda f, vis, p: hb._relax_edges(
+        src, dst, tail, f, vis, p, v))
+    pull = jax.jit(lambda f, vis, p: hb._pull_relax(
+        chunks, core_k, row_end, f, vis, p, v))
+    w = padded_bitmap_words(v)
+    rng = np.random.default_rng(0)
+    for _ in range(3):
+        vm = (np.ones(v, bool) if visited == "all"
+              else rng.random(v) < rng.uniform(0.05, 0.95))
+        fm = vm & (rng.random(v) < rng.uniform(0.05, 1.0))
+        ids = rng.integers(0, v, v + 1)
+        # unvisited rows hold the sentinel v, or a core-step winner
+        p = np.where(np.append(vm, True) | (rng.random(v + 1) < 0.1), ids, v)
+        p[v] = v
+        args = (pack_bitmap(jnp.asarray(fm), w),
+                pack_bitmap(jnp.asarray(vm), w), jnp.asarray(p, jnp.int32))
+        want = np.asarray(push(*args))
+        np.testing.assert_array_equal(np.asarray(pull(*args)), want)
+        if visited == "random":
+            assert (want != p).any()   # the draw relaxed something
+
+
 def test_bitmap_engine_never_packs_inside_loop(monkeypatch):
     """Zero pack_bitmap calls in the bitmap engine's traced program: the
     resident frontier/visited state never round-trips through bool (the

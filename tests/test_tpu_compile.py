@@ -157,3 +157,40 @@ def test_per_root_kernels_keep_their_names(per_root_text):
     kernels = re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target="
                          r'"tpu_custom_call"', per_root_text)
     assert sorted(set(kernels)) == ["core_spmv", "frontier_update"], kernels
+
+
+def _scatters(text, scope) -> list:
+    """Instructions, fused ones included, that scatter under ``scope``."""
+    return [i.strip()[:160] for i in text.splitlines()
+            if scope in _op_path(i)
+            and (" scatter(" in i
+                 or any(p.startswith("scatter") for p in _op_path(i)))]
+
+
+def test_per_root_bu_relax_has_no_scatter(per_root_text):
+    """The bottom-up relax is a pull: a bit gather per slot and a dense
+    segmented min, with no scatter.  The dense core's winners still
+    scatter-min, which shows the search would see one."""
+    assert any("bfs.bu_relax" in _op_path(i)
+               for i in _level_loop_ops(per_root_text))
+    assert not _scatters(per_root_text, "bfs.bu_relax"), \
+        _scatters(per_root_text, "bfs.bu_relax")
+    assert _scatters(per_root_text, "bfs.bu_core")
+
+
+def test_batched_program_keeps_one_copy_of_the_core(one_chip):
+    """Batched over roots, the bottom-up step reads the dense core as it
+    is: no instruction holds a per-root copy of it, which a vmapped
+    ``cond`` with each root's own direction would broadcast to the batch
+    on every level."""
+    cfg = pipeline.Graph500Config.ladder("pre-g500-batch", scale=10,
+                                         n_roots=ROOTS)
+    built = pipeline.build(cfg)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kops, "interpret_mode", lambda: False)
+        compiled = compile_plan(cfg.to_plan(), built)
+        text = compiled.lower(list(range(ROOTS)),
+                              sharding=one_chip).compile().as_text()
+    per_root = ",".join(map(str, (ROOTS,) + built.core.a_core.shape))
+    assert "tpu_custom_call" in text
+    assert f"[{per_root}]" not in text
