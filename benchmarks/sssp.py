@@ -79,7 +79,8 @@ def _child() -> dict:
     g0 = build_csr(edges)
     r = degree_reorder(g0.degree)
     g = build_csr(relabel_edges(edges, r))
-    ev = with_edge_weights(edge_view(g), seed=seed)
+    ev = with_edge_weights(edge_view(g), seed=seed,
+                           old_from_new=r.old_from_new)
     chunks = chunk_edge_view(ev)
     roots = np.asarray(sample_roots(seed, edges, n_roots))
     roots = np.asarray(r.new_from_old)[roots].astype(np.int32)

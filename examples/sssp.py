@@ -17,7 +17,7 @@ where SSSP and BFS traversal behave most differently.
 import numpy as np
 
 from repro.core import (
-    Graph500Config, PreparedGraph, TraversalPlan, build_csr,
+    WEIGHT_UNIT, Graph500Config, PreparedGraph, TraversalPlan, build_csr,
     chunk_edge_view, compile_plan, edge_view, run, sssp_oracle,
     with_edge_weights,
 )
@@ -68,5 +68,5 @@ gpar, gdist = sssp_oracle(ev.src, ev.dst, ev.valid, ev.weight,
 assert np.array_equal(np.asarray(gres.parent)[:g.num_vertices], gpar)
 assert np.array_equal(np.asarray(gres.level)[:g.num_vertices], gdist)
 print(f"grid 32x32      : {int(gres.stats.levels)} rounds, "
-      f"max distance {int(gdist.max())} — the high-diameter regime "
-      f"(oracle parity holds)")
+      f"max distance {gdist.max() * WEIGHT_UNIT:.4f} — the high-diameter "
+      f"regime (oracle parity holds)")

@@ -11,7 +11,7 @@ from repro.core.heavy import (
 from repro.core.bfs_steps import (
     ChunkedEdgeView, EdgeView, chunk_edge_view, edge_view, with_edge_weights,
 )
-from repro.core.graph_build import DEFAULT_MAX_WEIGHT, edge_weights
+from repro.core.graph_build import WEIGHT_UNIT, edge_weights
 from repro.core.hybrid_bfs import (
     BFSResult, bfs_batch, bfs_batch_sharded, hybrid_bfs,
 )
@@ -55,7 +55,7 @@ __all__ = [
     "HeavyCore", "build_heavy_core", "pack_bitmap", "padded_bitmap_words",
     "unpack_bitmap",
     "ChunkedEdgeView", "EdgeView", "chunk_edge_view", "edge_view",
-    "with_edge_weights", "DEFAULT_MAX_WEIGHT", "edge_weights",
+    "with_edge_weights", "WEIGHT_UNIT", "edge_weights",
     "BFSResult", "bfs_batch", "bfs_batch_sharded", "hybrid_bfs",
     "FAULT_CLASSES", "FaultSpec",
     "CHECK_NAMES", "SSSP_CHECK_NAMES", "validate", "validate_batch",

@@ -39,10 +39,10 @@ class EdgeView:
     """Edge-parallel view of a CSR graph (static shapes).
 
     ``weight`` is the optional per-entry uint32 weight plane the SSSP
-    kernel consumes (``graph_build.edge_weights``; symmetric, 0 on invalid
-    slots); ``None`` for unweighted BFS graphs — the pytree registration
-    treats a ``None`` field as an empty subtree, so every existing BFS
-    program is byte-identical.
+    kernel consumes (``graph_build.edge_weights``, in units of 2**-20;
+    symmetric, 0 on invalid slots); ``None`` for unweighted BFS graphs —
+    the pytree registration treats a ``None`` field as an empty subtree,
+    so every existing BFS program is byte-identical.
     """
 
     src: jax.Array    # [E_pad] int32 (sentinel V on padding)
@@ -59,15 +59,24 @@ def edge_view(g: CSRGraph) -> EdgeView:
     return EdgeView(s, d, valid, g.num_vertices)
 
 
-def with_edge_weights(ev: EdgeView, *, seed: int = 0,
-                      max_weight: int | None = None) -> EdgeView:
-    """The same view with a deterministic symmetric weight plane attached
-    (``graph_build.edge_weights`` of the canonical endpoint pair)."""
-    from repro.core.graph_build import DEFAULT_MAX_WEIGHT, edge_weights
+def with_edge_weights(ev: EdgeView, *, seed: int,
+                      old_from_new: jax.Array | None = None) -> EdgeView:
+    """The same view with Graph500 kernel 3's weight plane attached
+    (``graph_build.edge_weights``, in units of ``WEIGHT_UNIT``).
 
-    w = edge_weights(ev.src, ev.dst, ev.valid, seed=seed,
-                     max_weight=(DEFAULT_MAX_WEIGHT if max_weight is None
-                                 else max_weight))
+    The weights are drawn over the edges' original generator ids:
+    ``old_from_new`` maps the view's ids back to them when the graph was
+    relabelled (``Reordering.old_from_new``); without it the view's ids
+    are the original ones."""
+    from repro.core.graph_build import edge_weights
+
+    src, dst = ev.src, ev.dst
+    if old_from_new is not None:
+        # Padding slots carry the sentinel V; their weight is 0 anyway.
+        orig = jnp.concatenate([jnp.asarray(old_from_new, jnp.int32),
+                                jnp.full((1,), ev.num_vertices, jnp.int32)])
+        src, dst = orig[src], orig[dst]
+    w = edge_weights(src, dst, ev.valid, seed=seed)
     return EdgeView(ev.src, ev.dst, ev.valid, ev.num_vertices, w)
 
 

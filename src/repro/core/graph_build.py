@@ -11,6 +11,10 @@ Layout decisions (DESIGN.md §6):
     bit-scatter core builder in ``heavy.py`` (add == or only without dups);
   * all arrays keep a static length ``2 * M``; invalid slots carry the
     sentinel ``src == num_vertices`` and sort to the tail.
+
+Kernel 3's edge weights (``edge_weights``) are drawn here too: uniform
+over the multiples of ``WEIGHT_UNIT`` = 2**-20 in (0, 1), a hash of the
+seed and the edge's original endpoint pair.
 """
 from __future__ import annotations
 
@@ -102,18 +106,25 @@ def csr_to_edge_arrays(g: CSRGraph) -> tuple[jax.Array, jax.Array, jax.Array]:
 
 
 # ---------------------------------------------------------------------------
-# Deterministic edge weights (the SSSP kernel's input, DESIGN.md §16).
+# Edge weights of Graph500 kernel 3 (SSSP, DESIGN.md §16).
 #
-# Graph500's SSSP kernel draws uniform weights per *undirected* edge; with
-# the CSR holding both directed entries of each edge, the weight must be a
-# pure function of the unordered endpoint pair so w(u,v) == w(v,u) without
-# ever materializing an undirected edge list.  A 32-bit finalizer hash of
-# the canonical (min, max) pair gives exactly that — same bits on numpy
-# and jnp inputs, so the host Dijkstra oracle and the device engines see
-# identical weights by construction.
+# The spec draws each edge's weight uniformly from [0, 1).  Here the draw
+# is a 32-bit finalizer hash of the seed and the unordered pair of the
+# edge's *original* generator ids, so w(u, v) == w(v, u) without an
+# undirected edge list, and the weights do not depend on the degree
+# relabelling or on any later layout.  A weight is ``k * WEIGHT_UNIT``
+# with ``k`` in ``[1, 2**WEIGHT_BITS - 1]``: on that dyadic grid every
+# path length is an exact sum (in integer units, or in float32 below 16),
+# and every tree edge adds at least one unit, so the min-source parent
+# rule always gives a tree.  A continuous draw could let a tiny weight
+# round away, and two vertices at equal distance adopt each other.  The
+# engines therefore keep weights and distances as uint32 counts of
+# WEIGHT_UNIT, and every distance they return is in that unit.
 # ---------------------------------------------------------------------------
 
-DEFAULT_MAX_WEIGHT = 255
+WEIGHT_BITS = 20
+#: The unit of every weight and distance the SSSP kernel holds: 2**-20.
+WEIGHT_UNIT = 2.0 ** -WEIGHT_BITS
 
 
 def _mix32(h):
@@ -126,19 +137,23 @@ def _mix32(h):
     return h ^ (h >> u32(16))
 
 
-def edge_weights(src, dst, valid, *, seed: int = 0,
-                 max_weight: int = DEFAULT_MAX_WEIGHT):
-    """uint32 weight in ``[1, max_weight]`` per directed edge entry, 0 on
-    invalid slots; symmetric (``w(u,v) == w(v,u)``) and deterministic in
-    ``seed``.  Works on numpy or jnp arrays (integer-exact either way)."""
-    if max_weight < 1:
-        raise ValueError(f"max_weight must be >= 1, got {max_weight}")
+def edge_weights(src, dst, valid, *, seed: int):
+    """uint32 weight per directed edge entry, in units of WEIGHT_UNIT:
+    ``k`` in ``[1, 2**WEIGHT_BITS - 1]``, 0 on invalid slots.
+
+    ``src`` and ``dst`` are original generator ids.  The weight is a
+    hash of ``seed`` (all of its bits up to 64) and the unordered pair,
+    so it is symmetric.  ``k`` is the hash modulo ``2**20 - 1``, plus 1:
+    uniform over the grid to within one part in 4096.  Works on numpy or
+    jnp arrays (integer-exact either way)."""
     u32 = jnp.uint32
+    seed = int(seed) & 0xFFFFFFFFFFFFFFFF
     s = jnp.asarray(src).astype(u32)
     d = jnp.asarray(dst).astype(u32)
     a = jnp.minimum(s, d)
     b = jnp.maximum(s, d)
-    h = _mix32(a * u32(0x9E3779B9) + u32(seed & 0xFFFFFFFF))
+    key = _mix32(u32(seed & 0xFFFFFFFF) ^ _mix32(u32(seed >> 32)))
+    h = _mix32(a * u32(0x9E3779B9) + key)
     h = _mix32(h ^ (b * u32(0x85EBCA6B)))
-    w = u32(1) + h % u32(max_weight)
+    w = u32(1) + h % u32((1 << WEIGHT_BITS) - 1)
     return jnp.where(jnp.asarray(valid), w, u32(0))

@@ -26,6 +26,15 @@ Every rung is executed by constructing a :class:`repro.core.plan.BFSPlan`
   pre-g500-mesh3   : ``layout=("root", "group", "member")`` — the
                      composed 3-axis plan (root batch over its own mesh
                      axis outside the vertex-sharded SPMD program).
+
+Graph500 kernel 3 is ``kernel="sssp"`` on the same build and plan path:
+``build`` attaches the spec's weights, uniform over the multiples of
+2**-20 in (0, 1) and drawn over the original generator ids
+(``graph_build.edge_weights``), and the per-root δ-stepping engine
+returns each vertex's distance as an integer count of
+``graph_build.WEIGHT_UNIT``.  The grid is fine enough that a weight
+behaves as a draw from [0, 1), and coarse enough that every distance is
+an exact sum, so the shortest-path tree is exact and checkable.
 """
 from __future__ import annotations
 
@@ -38,7 +47,7 @@ import jax.numpy as jnp
 
 from repro.core import kronecker
 from repro.core.bfs_steps import EdgeView, edge_view, with_edge_weights
-from repro.core.graph_build import DEFAULT_MAX_WEIGHT, build_csr
+from repro.core.graph_build import build_csr
 from repro.core.heavy import HeavyCore, build_heavy_core
 from repro.core.plan import BFSPlan, compile_plan
 from repro.core.reorder import Reordering, degree_reorder, relabel_edges
@@ -94,12 +103,11 @@ class Graph500Config:
     # single-process.
     procs: int = 1
     devices_per_proc: Optional[int] = None
-    # Graph500 kernel (DESIGN.md §16): "bfs" or "sssp".  Under "sssp" the
-    # build step attaches the deterministic symmetric weight plane
-    # (seeded from cfg.seed, uniform in [1, max_weight]) and the plan
-    # runs the δ-stepping engine with the min-combine exchange family.
+    # Graph500 kernel (DESIGN.md §16): "bfs" (kernel 2) or "sssp"
+    # (kernel 3).  Under "sssp" the build step attaches the spec's weight
+    # plane (seeded from cfg.seed, see ``build``) and the plan runs the
+    # δ-stepping engine with the min-combine exchange family.
     kernel: str = "bfs"
-    max_weight: int = DEFAULT_MAX_WEIGHT
 
     @staticmethod
     def ladder(rung: str, **kw) -> "Graph500Config":
@@ -190,7 +198,8 @@ class BuiltGraph:
 
 
 def build(cfg: Graph500Config) -> BuiltGraph:
-    """Steps 1-2 (untimed for TEPS, but we record construction time)."""
+    """Steps 1-2 (untimed for TEPS, but we record construction time).
+    Under ``kernel="sssp"`` the edge view carries kernel 3's weights."""
     t0 = time.perf_counter()
     edges = kronecker.generate_edges(cfg.seed, cfg.scale, cfg.edge_factor)
     g = build_csr(edges)
@@ -204,9 +213,13 @@ def build(cfg: Graph500Config) -> BuiltGraph:
         core = build_heavy_core(g, threshold=cfg.heavy_threshold)
     ev = edge_view(g)
     if cfg.kernel == "sssp":
-        # The weight plane is a pure function of the *relabelled* global
-        # endpoint pair — the oracle and every engine hash the same ids.
-        ev = with_edge_weights(ev, seed=cfg.seed, max_weight=cfg.max_weight)
+        # Kernel 3's weights: uniform over the multiples of 2**-20 in
+        # (0, 1), drawn over the *original* generator ids, so the problem
+        # is the same whatever the relabelling; the engine's distances
+        # come back in that unit (graph_build.WEIGHT_UNIT).
+        ev = with_edge_weights(
+            ev, seed=cfg.seed,
+            old_from_new=None if reord is None else reord.old_from_new)
     ev.src.block_until_ready()
     return BuiltGraph(
         ev=ev, degree=g.degree, core=core, reorder=reord,

@@ -1,7 +1,11 @@
 """Delta-stepping SSSP engines (DESIGN.md §16) — the second Graph500 kernel.
 
-Graph500's SSSP benchmark runs single-source shortest paths over the same
-Kronecker graph with uniform edge weights.  The traversal lifecycle is the
+Graph500's SSSP benchmark (kernel 3) runs single-source shortest paths
+over the same Kronecker graph with edge weights uniform in [0, 1).  Here a
+weight is an integer count of ``graph_build.WEIGHT_UNIT`` = 2**-20 in
+``[1, 2**20 - 1]`` (``graph_build.edge_weights``), so every distance is
+an exact uint32 sum and every tree edge adds at least one unit; the
+engines return distances in that unit.  The traversal lifecycle is the
 BFS one with three substitutions (the kernel interface of §16):
 
   * **state carrier** — the packed ``changed`` bitmap replaces the BFS
@@ -34,9 +38,17 @@ integer distances decreasing monotonically per vertex.
 
 Parents stay global vertex ids with the BFS sentinel conventions and the
 distance plane is surfaced through the ``BFSResult.level`` slot as int32
-(-1 unreached), so validation, serving, fault recovery, and the
-multiprocess launcher run the SSSP kernel through the exact machinery
-built for BFS.
+counts of ``WEIGHT_UNIT`` (-1 unreached), so validation, serving, fault
+recovery, and the multiprocess launcher run the SSSP kernel through the
+exact machinery built for BFS.  ``BFSStats.levels`` counts the rounds and
+``scanned_edges`` each round's frontier degree.
+
+The engines name each phase of a round with ``jax.named_scope``, so a
+device trace charges every op to one: ``sssp.init``, ``sssp.select``
+(the changed set's minimum bucket gives the frontier; the next changed
+set and the round's counters), ``sssp.relax`` (pass A), ``sssp.parent``
+(pass B), ``sssp.exchange`` (vertex-sharded engine only) and
+``sssp.finish``.
 """
 from __future__ import annotations
 
@@ -75,7 +87,8 @@ DEFAULT_MAX_ROUNDS = 512
 
 
 def bucket_width(max_weight: int) -> int:
-    """The δ of δ-stepping, chosen host-side from the max edge weight.
+    """The δ of δ-stepping, chosen host-side from the max edge weight
+    (in units of ``WEIGHT_UNIT``: about 2**19, so δ is about one half).
 
     ``δ = max(1, maxw // 2)`` keeps the bucket count proportional to the
     weighted diameter in units of the heaviest edge — small enough that
@@ -132,16 +145,18 @@ def _run_sssp_impl(
     w = padded_bitmap_words(v)
     d32 = jnp.uint32(delta)
     inf = jnp.uint32(_hier.INF_U32)
-    src = chunks.src.reshape(-1)
-    dst = chunks.dst.reshape(-1)
-    valid = chunks.valid.reshape(-1)
-    wgt = chunks.weight.reshape(-1)
-    ids = jnp.arange(v, dtype=jnp.int32)
+    with jax.named_scope("sssp.init"):
+        src = chunks.src.reshape(-1)
+        dst = chunks.dst.reshape(-1)
+        valid = chunks.valid.reshape(-1)
+        wgt = chunks.weight.reshape(-1)
+        ids = jnp.arange(v, dtype=jnp.int32)
 
-    parent_ext = jnp.full((v + 1,), v, jnp.int32).at[root].set(root)
-    dist = jnp.full((v,), _hier.INF_U32, jnp.uint32).at[root].set(jnp.uint32(0))
-    root_bit = jnp.uint32(1) << (root % 32).astype(jnp.uint32)
-    changed_bm = jnp.zeros((w,), jnp.uint32).at[root // 32].set(root_bit)
+        parent_ext = jnp.full((v + 1,), v, jnp.int32).at[root].set(root)
+        dist = jnp.full((v,), _hier.INF_U32, jnp.uint32).at[root].set(
+            jnp.uint32(0))
+        root_bit = jnp.uint32(1) << (root % 32).astype(jnp.uint32)
+        changed_bm = jnp.zeros((w,), jnp.uint32).at[root // 32].set(root_bit)
 
     def cond(s: _SsspState):
         return (s.n_changed > 0) & (s.rnd < max_rounds)
@@ -150,83 +165,92 @@ def _run_sssp_impl(
         alive = s.n_changed > 0   # batched-roots guard (vmap over roots)
 
         # Derive the frontier: changed vertices in the minimum bucket.
-        changed = testbit(s.changed_bm, ids)
-        bkt = jnp.where(changed, s.dist // d32, inf)
-        b = jnp.min(bkt)
-        front = changed & (bkt == b)
-        frontier_bm = _pack_delta_words(front, w)
-        popped_bm = s.changed_bm & ~frontier_bm
+        with jax.named_scope("sssp.select"):
+            changed = testbit(s.changed_bm, ids)
+            bkt = jnp.where(changed, s.dist // d32, inf)
+            b = jnp.min(bkt)
+            front = changed & (bkt == b)
+            frontier_bm = _pack_delta_words(front, w)
+            popped_bm = s.changed_bm & ~frontier_bm
 
         # Pass A: distance min-relax over frontier out-edges.
-        dist_ext = jnp.concatenate(
-            [s.dist, jnp.full((1,), _hier.INF_U32, jnp.uint32)])
-        active = valid & testbit(frontier_bm, jnp.clip(src, 0, v - 1))
-        cand = jnp.where(active, dist_ext[src] + wgt, inf)
-        tgt = jnp.where(active, dst, v)
-        new_dist_ext = dist_ext.at[tgt].min(cand)
-        new_dist = new_dist_ext[:v]
-        improved = new_dist < s.dist
+        with jax.named_scope("sssp.relax"):
+            dist_ext = jnp.concatenate(
+                [s.dist, jnp.full((1,), _hier.INF_U32, jnp.uint32)])
+            active = valid & testbit(frontier_bm, jnp.clip(src, 0, v - 1))
+            cand = jnp.where(active, dist_ext[src] + wgt, inf)
+            tgt = jnp.where(active, dst, v)
+            new_dist_ext = dist_ext.at[tgt].min(cand)
+            new_dist = new_dist_ext[:v]
+            improved = new_dist < s.dist
 
         # Pass B: parent = min source achieving the post-relax distance.
         # Distance-improved slots reset to the sentinel first; equality
         # winners min-merge (they never re-enter the changed set — the
         # fixpoint parent is a pure function of the final distances).
-        pbase = jnp.where(improved, v, s.parent_ext[:v])
-        pext = jnp.concatenate([pbase, jnp.full((1,), v, jnp.int32)])
-        won = active & (cand == new_dist_ext[tgt])
-        new_parent_ext = pext.at[jnp.where(won, dst, v)].min(
-            jnp.where(won, src, v).astype(jnp.int32))
-        if fault is not None and fault.site == "parent":
-            pv = faults.corrupt_parent(
-                fault, new_parent_ext[:v], improved, ids, jnp.int32(v),
-                level=s.rnd, root=root)
-            new_parent_ext = jnp.concatenate([pv, new_parent_ext[v:]])
+        with jax.named_scope("sssp.parent"):
+            pbase = jnp.where(improved, v, s.parent_ext[:v])
+            pext = jnp.concatenate([pbase, jnp.full((1,), v, jnp.int32)])
+            won = active & (cand == new_dist_ext[tgt])
+            new_parent_ext = pext.at[jnp.where(won, dst, v)].min(
+                jnp.where(won, src, v).astype(jnp.int32))
+            if fault is not None and fault.site == "parent":
+                pv = faults.corrupt_parent(
+                    fault, new_parent_ext[:v], improved, ids, jnp.int32(v),
+                    level=s.rnd, root=root)
+                new_parent_ext = jnp.concatenate([pv, new_parent_ext[v:]])
 
-        new_changed = popped_bm | _pack_delta_words(improved, w)
-        n_changed = jnp.sum(popcount_u32(new_changed)).astype(jnp.int32)
+        # The next round's changed set: the popped rest plus every
+        # improved vertex; then the round's counters.
+        with jax.named_scope("sssp.select"):
+            new_changed = popped_bm | _pack_delta_words(improved, w)
+            n_changed = jnp.sum(popcount_u32(new_changed)).astype(jnp.int32)
 
-        # In-loop sentinels (§13): bucket monotone, frontier nonempty,
-        # round within bound — a healthy round reads SENTINEL_OK == 7.
-        fs = jnp.sum(popcount_u32(frontier_bm)).astype(jnp.int32)
-        s1 = b >= s.prev_b
-        s2 = fs > 0
-        s3 = s.rnd + 1 <= jnp.int32(max_rounds)
-        ok_mask = (s1.astype(jnp.int32) + 2 * s2.astype(jnp.int32)
-                   + 4 * s3.astype(jnp.int32))
-        scanned = jnp.sum(jnp.where(front, degree, 0)).astype(jnp.int32)
-        b_i32 = jnp.minimum(b, jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
+            # In-loop sentinels (§13): bucket monotone, frontier
+            # nonempty, round within bound — a healthy round reads
+            # SENTINEL_OK == 7.
+            fs = jnp.sum(popcount_u32(frontier_bm)).astype(jnp.int32)
+            s1 = b >= s.prev_b
+            s2 = fs > 0
+            s3 = s.rnd + 1 <= jnp.int32(max_rounds)
+            ok_mask = (s1.astype(jnp.int32) + 2 * s2.astype(jnp.int32)
+                       + 4 * s3.astype(jnp.int32))
+            scanned = jnp.sum(jnp.where(front, degree, 0)).astype(jnp.int32)
+            b_i32 = jnp.minimum(b, jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
 
-        nxt = _SsspState(
-            new_parent_ext, new_dist, new_changed, n_changed, b,
-            s.rnd + 1,
-            s.stats_b.at[s.rnd].set(b_i32),
-            s.stats_fs.at[s.rnd].set(fs),
-            s.stats_se.at[s.rnd].set(scanned),
-            s.stats_ok.at[s.rnd].set(ok_mask),
+            nxt = _SsspState(
+                new_parent_ext, new_dist, new_changed, n_changed, b,
+                s.rnd + 1,
+                s.stats_b.at[s.rnd].set(b_i32),
+                s.stats_fs.at[s.rnd].set(fs),
+                s.stats_se.at[s.rnd].set(scanned),
+                s.stats_ok.at[s.rnd].set(ok_mask),
+            )
+            return jax.tree_util.tree_map(
+                lambda new, old: jnp.where(alive, new, old), nxt, s)
+
+    with jax.named_scope("sssp.init"):
+        init = _SsspState(
+            parent_ext, dist, changed_bm,
+            jnp.int32(1), jnp.uint32(0), jnp.int32(0),
+            jnp.full((max_rounds,), -1, jnp.int32),
+            jnp.zeros((max_rounds,), jnp.int32),
+            jnp.zeros((max_rounds,), jnp.int32),
+            jnp.full((max_rounds,), -1, jnp.int32),
         )
-        return jax.tree_util.tree_map(
-            lambda new, old: jnp.where(alive, new, old), nxt, s)
-
-    init = _SsspState(
-        parent_ext, dist, changed_bm,
-        jnp.int32(1), jnp.uint32(0), jnp.int32(0),
-        jnp.full((max_rounds,), -1, jnp.int32),
-        jnp.zeros((max_rounds,), jnp.int32),
-        jnp.zeros((max_rounds,), jnp.int32),
-        jnp.full((max_rounds,), -1, jnp.int32),
-    )
     s = jax.lax.while_loop(cond, body, init)
-    parent = jnp.where(s.parent_ext[:v] == v, -1, s.parent_ext[:v])
-    dist_i = jnp.where(s.dist == inf, -1, s.dist.astype(jnp.int32))
-    return BFSResult(
-        parent=parent,
-        level=dist_i,   # the distance plane rides the level slot (int32)
-        stats=BFSStats(
-            s.stats_b, s.stats_fs, s.stats_se, s.rnd,
-            jnp.full((max_rounds,), -1, jnp.int32), jnp.int32(0),
-            s.stats_ok,
-        ),
-    )
+    with jax.named_scope("sssp.finish"):
+        parent = jnp.where(s.parent_ext[:v] == v, -1, s.parent_ext[:v])
+        dist_i = jnp.where(s.dist == inf, -1, s.dist.astype(jnp.int32))
+        return BFSResult(
+            parent=parent,
+            level=dist_i,   # the distance plane rides the level slot (int32)
+            stats=BFSStats(
+                s.stats_b, s.stats_fs, s.stats_se, s.rnd,
+                jnp.full((max_rounds,), -1, jnp.int32), jnp.int32(0),
+                s.stats_ok,
+            ),
+        )
 
 
 _SSSP_STATICS = ("delta", "max_rounds", "fault")
@@ -336,15 +360,17 @@ def _run_sssp_sharded(
     delta_wire = "flat" if exchange == "flat" else "hier_or_packed"
 
     # --- init: root bit set once; owner holds the root parent.
-    is_mine, root_slot = to_local(root)
-    parent_loc = jnp.where((slots == root_slot) & is_mine, root,
-                           jnp.int32(v_pad))
-    parent_loc = jnp.concatenate(
-        [parent_loc, jnp.full((1,), v_pad, jnp.int32)])
-    dist_full = jnp.full((v_pad,), _hier.INF_U32, jnp.uint32).at[root].set(
-        jnp.uint32(0))
-    root_bit = jnp.uint32(1) << (root % 32).astype(jnp.uint32)
-    changed_bm = jnp.zeros((w_pad,), jnp.uint32).at[root // 32].set(root_bit)
+    with jax.named_scope("sssp.init"):
+        is_mine, root_slot = to_local(root)
+        parent_loc = jnp.where((slots == root_slot) & is_mine, root,
+                               jnp.int32(v_pad))
+        parent_loc = jnp.concatenate(
+            [parent_loc, jnp.full((1,), v_pad, jnp.int32)])
+        dist_full = jnp.full((v_pad,), _hier.INF_U32, jnp.uint32).at[
+            root].set(jnp.uint32(0))
+        root_bit = jnp.uint32(1) << (root % 32).astype(jnp.uint32)
+        changed_bm = jnp.zeros((w_pad,), jnp.uint32).at[root // 32].set(
+            root_bit)
 
     def cond(s: _SsspShardState):
         return (s.n_changed > 0) & (s.rnd < max_rounds)
@@ -354,122 +380,133 @@ def _run_sssp_sharded(
 
         # Bucket selection is replicated work on replicated state — every
         # shard computes the same frontier with zero communication.
-        changed = testbit(s.changed_bm, ids_full)
-        bkt = jnp.where(changed, s.dist_full // d32, inf)
-        b = jnp.min(bkt)
-        front_full = changed & (bkt == b)
-        frontier_bm = _pack_delta_words(front_full, w_pad)
-        popped_bm = s.changed_bm & ~frontier_bm
+        with jax.named_scope("sssp.select"):
+            changed = testbit(s.changed_bm, ids_full)
+            bkt = jnp.where(changed, s.dist_full // d32, inf)
+            b = jnp.min(bkt)
+            front_full = changed & (bkt == b)
+            frontier_bm = _pack_delta_words(front_full, w_pad)
+            popped_bm = s.changed_bm & ~frontier_bm
 
         # Pass A over dst-owned edges: frontier membership from the
         # replicated bitmap, distance scatter-min into owned slots.
-        dist_loc = s.dist_full[gslots]
-        dist_ext = jnp.concatenate(
-            [s.dist_full, jnp.full((1,), _hier.INF_U32, jnp.uint32)])
-        active = valid_flat & testbit(
-            frontier_bm, jnp.clip(src_flat, 0, v_pad - 1))
-        cand = jnp.where(
-            active, dist_ext[jnp.clip(src_flat, 0, v_pad)] + wgt_flat, inf)
-        tgt = jnp.where(active, dst_flat, v_loc)
-        dist_loc_ext = jnp.concatenate(
-            [dist_loc, jnp.full((1,), _hier.INF_U32, jnp.uint32)])
-        new_dist_loc_ext = dist_loc_ext.at[tgt].min(cand)
-        new_dist_loc = new_dist_loc_ext[:v_loc]
-        improved_loc = new_dist_loc < dist_loc
+        with jax.named_scope("sssp.relax"):
+            dist_loc = s.dist_full[gslots]
+            dist_ext = jnp.concatenate(
+                [s.dist_full, jnp.full((1,), _hier.INF_U32, jnp.uint32)])
+            active = valid_flat & testbit(
+                frontier_bm, jnp.clip(src_flat, 0, v_pad - 1))
+            cand = jnp.where(
+                active, dist_ext[jnp.clip(src_flat, 0, v_pad)] + wgt_flat,
+                inf)
+            tgt = jnp.where(active, dst_flat, v_loc)
+            dist_loc_ext = jnp.concatenate(
+                [dist_loc, jnp.full((1,), _hier.INF_U32, jnp.uint32)])
+            new_dist_loc_ext = dist_loc_ext.at[tgt].min(cand)
+            new_dist_loc = new_dist_loc_ext[:v_loc]
+            improved_loc = new_dist_loc < dist_loc
 
         # Pass B: parent = min source achieving the post-relax distance.
-        pbase = jnp.where(improved_loc, v_pad, s.parent_loc[:v_loc])
-        pext = jnp.concatenate([pbase, jnp.full((1,), v_pad, jnp.int32)])
-        won = active & (cand == new_dist_loc_ext[tgt])
-        new_parent = pext.at[jnp.where(won, dst_flat, v_loc)].min(
-            jnp.where(won, src_flat, v_pad).astype(jnp.int32))
-        if fault is not None and fault.site == "parent":
-            pv = faults.corrupt_parent(
-                fault, new_parent[:v_loc], improved_loc, gslots,
-                jnp.int32(v_pad), level=s.rnd, device=dev, root=root)
-            new_parent = jnp.concatenate([pv, new_parent[v_loc:]])
+        with jax.named_scope("sssp.parent"):
+            pbase = jnp.where(improved_loc, v_pad, s.parent_loc[:v_loc])
+            pext = jnp.concatenate([pbase, jnp.full((1,), v_pad, jnp.int32)])
+            won = active & (cand == new_dist_loc_ext[tgt])
+            new_parent = pext.at[jnp.where(won, dst_flat, v_loc)].min(
+                jnp.where(won, src_flat, v_pad).astype(jnp.int32))
+            if fault is not None and fault.site == "parent":
+                pv = faults.corrupt_parent(
+                    fault, new_parent[:v_loc], improved_loc, gslots,
+                    jnp.int32(v_pad), level=s.rnd, device=dev, root=root)
+                new_parent = jnp.concatenate([pv, new_parent[v_loc:]])
 
-        # Exchange 1 — distance plane: owner slots carry the new values,
-        # everyone else contributes INF; the min-reduction reassembles
-        # the replicated plane (T3 two-phase under hier_min).
-        contrib = jnp.full((v_pad,), _hier.INF_U32, jnp.uint32).at[gslots].set(
-            new_dist_loc)
-        if exchange == "flat":
-            new_dist_full = _hier._min_all_reduce(
-                contrib, axes, fault=fault, level=s.rnd, device=dev,
-                root=root)
-        else:
-            new_dist_full = _hier.hierarchical_pmin(
-                contrib, group_axis, member_axis, fault=fault, level=s.rnd,
-                device=dev, root=root)
+        with jax.named_scope("sssp.exchange"):
+            # Exchange 1 — distance plane: owner slots carry the new
+            # values, everyone else contributes INF; the min-reduction
+            # reassembles the replicated plane (T3 two-phase under
+            # hier_min).
+            contrib = jnp.full((v_pad,), _hier.INF_U32, jnp.uint32).at[
+                gslots].set(new_dist_loc)
+            if exchange == "flat":
+                new_dist_full = _hier._min_all_reduce(
+                    contrib, axes, fault=fault, level=s.rnd, device=dev,
+                    root=root)
+            else:
+                new_dist_full = _hier.hierarchical_pmin(
+                    contrib, group_axis, member_axis, fault=fault,
+                    level=s.rnd, device=dev, root=root)
 
-        # Exchange 2 — changed-set delta bitmap (OR family + codec).
-        delta_bm_loc = _pack_delta_words(improved_loc, w_loc)
-        changed_delta_full = _exchange_delta(
-            delta_bm_loc, dev, w_loc, n_dev, exchange=delta_wire,
-            group_axis=group_axis, member_axis=member_axis,
-            partition=partition, known_bm=None,
-            fault=fault, level=s.rnd, root=root)
-        new_changed = popped_bm | changed_delta_full
-        n_changed = jnp.sum(popcount_u32(new_changed)).astype(jnp.int32)
+            # Exchange 2 — changed-set delta bitmap (OR family + codec).
+            delta_bm_loc = _pack_delta_words(improved_loc, w_loc)
+            changed_delta_full = _exchange_delta(
+                delta_bm_loc, dev, w_loc, n_dev, exchange=delta_wire,
+                group_axis=group_axis, member_axis=member_axis,
+                partition=partition, known_bm=None,
+                fault=fault, level=s.rnd, root=root)
 
-        # In-loop sentinels (§13): exchange conservation (owner deltas
-        # are disjoint, popcounts add), replicated-vs-owned distance
-        # agreement (a dropped min leg desynchronizes the plane), bucket
-        # monotone within the round bound.
-        delta_sum = jax.lax.psum(
-            jnp.sum(popcount_u32(delta_bm_loc)).astype(jnp.int32), axes)
-        got_sum = jnp.sum(popcount_u32(changed_delta_full)).astype(jnp.int32)
-        mism = jax.lax.psum(
-            jnp.sum((new_dist_full[gslots] != new_dist_loc)
-                    .astype(jnp.int32)), axes)
-        s1 = got_sum == delta_sum
-        s2 = mism == 0
-        s3 = (b >= s.prev_b) & (s.rnd + 1 <= jnp.int32(max_rounds))
-        ok_mask = (s1.astype(jnp.int32) + 2 * s2.astype(jnp.int32)
-                   + 4 * s3.astype(jnp.int32))
+        with jax.named_scope("sssp.select"):
+            new_changed = popped_bm | changed_delta_full
+            n_changed = jnp.sum(popcount_u32(new_changed)).astype(jnp.int32)
 
-        fs = jnp.sum(popcount_u32(frontier_bm)).astype(jnp.int32)
-        front_owned = testbit(frontier_bm, gslots)
-        scanned = jax.lax.psum(
-            jnp.sum(jnp.where(front_owned, degree_loc, 0)).astype(jnp.int32),
-            axes)
-        b_i32 = jnp.minimum(b, jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
+            # In-loop sentinels (§13): exchange conservation (owner
+            # deltas are disjoint, popcounts add), replicated-vs-owned
+            # distance agreement (a dropped min leg desynchronizes the
+            # plane), bucket monotone within the round bound.
+            delta_sum = jax.lax.psum(
+                jnp.sum(popcount_u32(delta_bm_loc)).astype(jnp.int32), axes)
+            got_sum = jnp.sum(
+                popcount_u32(changed_delta_full)).astype(jnp.int32)
+            mism = jax.lax.psum(
+                jnp.sum((new_dist_full[gslots] != new_dist_loc)
+                        .astype(jnp.int32)), axes)
+            s1 = got_sum == delta_sum
+            s2 = mism == 0
+            s3 = (b >= s.prev_b) & (s.rnd + 1 <= jnp.int32(max_rounds))
+            ok_mask = (s1.astype(jnp.int32) + 2 * s2.astype(jnp.int32)
+                       + 4 * s3.astype(jnp.int32))
 
-        nxt = _SsspShardState(
-            new_parent, new_dist_full, new_changed, n_changed, b,
-            s.rnd + 1,
-            s.stats_b.at[s.rnd].set(b_i32),
-            s.stats_fs.at[s.rnd].set(fs),
-            s.stats_se.at[s.rnd].set(scanned),
-            s.stats_ok.at[s.rnd].set(ok_mask),
+            fs = jnp.sum(popcount_u32(frontier_bm)).astype(jnp.int32)
+            front_owned = testbit(frontier_bm, gslots)
+            scanned = jax.lax.psum(
+                jnp.sum(jnp.where(front_owned, degree_loc, 0))
+                .astype(jnp.int32), axes)
+            b_i32 = jnp.minimum(b, jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
+
+            nxt = _SsspShardState(
+                new_parent, new_dist_full, new_changed, n_changed, b,
+                s.rnd + 1,
+                s.stats_b.at[s.rnd].set(b_i32),
+                s.stats_fs.at[s.rnd].set(fs),
+                s.stats_se.at[s.rnd].set(scanned),
+                s.stats_ok.at[s.rnd].set(ok_mask),
+            )
+            return jax.tree_util.tree_map(
+                lambda new, old: jnp.where(alive, new, old), nxt, s)
+
+    with jax.named_scope("sssp.init"):
+        init = _SsspShardState(
+            parent_loc, dist_full, changed_bm,
+            jnp.int32(1), jnp.uint32(0), jnp.int32(0),
+            jnp.full((max_rounds,), -1, jnp.int32),
+            jnp.zeros((max_rounds,), jnp.int32),
+            jnp.zeros((max_rounds,), jnp.int32),
+            jnp.full((max_rounds,), -1, jnp.int32),
         )
-        return jax.tree_util.tree_map(
-            lambda new, old: jnp.where(alive, new, old), nxt, s)
-
-    init = _SsspShardState(
-        parent_loc, dist_full, changed_bm,
-        jnp.int32(1), jnp.uint32(0), jnp.int32(0),
-        jnp.full((max_rounds,), -1, jnp.int32),
-        jnp.zeros((max_rounds,), jnp.int32),
-        jnp.zeros((max_rounds,), jnp.int32),
-        jnp.full((max_rounds,), -1, jnp.int32),
-    )
     s = jax.lax.while_loop(cond, body, init)
-    parent = jnp.where(s.parent_loc[:v_loc] == v_pad, -1,
-                       s.parent_loc[:v_loc])
-    dist_own = s.dist_full[gslots]
-    dist_i = jnp.where(dist_own == inf, -1,
-                       dist_own.astype(jnp.int32))
-    return BFSResult(
-        parent=parent,
-        level=dist_i,
-        stats=BFSStats(
-            s.stats_b, s.stats_fs, s.stats_se, s.rnd,
-            jnp.full((max_rounds,), -1, jnp.int32), jnp.int32(0),
-            s.stats_ok,
-        ),
-    )
+    with jax.named_scope("sssp.finish"):
+        parent = jnp.where(s.parent_loc[:v_loc] == v_pad, -1,
+                           s.parent_loc[:v_loc])
+        dist_own = s.dist_full[gslots]
+        dist_i = jnp.where(dist_own == inf, -1,
+                           dist_own.astype(jnp.int32))
+        return BFSResult(
+            parent=parent,
+            level=dist_i,
+            stats=BFSStats(
+                s.stats_b, s.stats_fs, s.stats_se, s.rnd,
+                jnp.full((max_rounds,), -1, jnp.int32), jnp.int32(0),
+                s.stats_ok,
+            ),
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -480,7 +517,8 @@ def sssp_oracle(src, dst, valid, weight, num_vertices: int, root: int):
     """Host Dijkstra + deterministic min-source parents.
 
     Returns ``(parent, dist)`` int32 numpy arrays matching the engine's
-    output contract exactly: ``dist`` -1 for unreached, ``parent`` -1 for
+    output contract exactly: ``dist`` in the weights' integer unit, -1 for
+    unreached, ``parent`` -1 for
     unreached / root's parent is itself; for every reached non-root
     vertex ``parent[v] = min{u : dist[u] + w(u,v) == dist[v]}`` — the
     engines' fixpoint parent rule, so equality is bitwise.

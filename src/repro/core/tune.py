@@ -268,14 +268,17 @@ def _plan_sort_key(plan: BFSPlan) -> str:
     return json.dumps(plan.to_dict(), sort_keys=True)
 
 
-def _build_inputs(scale: int, seed: int, edge_factor: int, n_roots: int):
+def _build_inputs(scale: int, seed: int, edge_factor: int, n_roots: int,
+                  kernel: str = "bfs"):
     """Shared Kronecker inputs: one degree-sorted graph + root sample
-    reused by every candidate (and by the oracle)."""
+    reused by every candidate (and by the oracle).  Under
+    ``kernel="sssp"`` the edge view carries kernel 3's weights, drawn
+    over the original ids as ``pipeline.build`` draws them."""
     from repro.core.graph_build import build_csr
     from repro.core.heavy import build_heavy_core
     from repro.core.kronecker import generate_edges, sample_roots
     from repro.core.reorder import degree_reorder, relabel_edges
-    from repro.core.bfs_steps import edge_view
+    from repro.core.bfs_steps import edge_view, with_edge_weights
 
     edges = generate_edges(seed, scale, edge_factor)
     g0 = build_csr(edges)
@@ -283,6 +286,8 @@ def _build_inputs(scale: int, seed: int, edge_factor: int, n_roots: int):
     g = build_csr(relabel_edges(edges, r))
     core = build_heavy_core(g, threshold=100 if scale >= 13 else 8)
     ev = edge_view(g)
+    if kernel == "sssp":
+        ev = with_edge_weights(ev, seed=seed, old_from_new=r.old_from_new)
     roots = np.asarray(sample_roots(seed, edges, n_roots))
     roots = np.asarray(r.new_from_old)[roots].astype(np.int32)
     pg = PreparedGraph(ev=ev, degree=g.degree, core=core)

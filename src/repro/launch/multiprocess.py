@@ -282,11 +282,8 @@ def _worker(args) -> int:
     fault = parse_inject(args.inject)
     kernel = args.kernel
     pg, degree, roots, v = _build_inputs(args.scale, args.seed,
-                                         args.edge_factor, args.roots)
-    if kernel == "sssp":
-        from repro.core.bfs_steps import with_edge_weights
-
-        pg.ev = with_edge_weights(pg.ev, seed=args.seed)
+                                         args.edge_factor, args.roots,
+                                         kernel=kernel)
 
     # In-process single-device oracle: runs on this rank's local device,
     # no mesh.  Every rank computes it (deterministic), every rank

@@ -52,7 +52,8 @@ def weighted_graph(scale=SCALE, seed=3, wseed=1):
     g0 = build_csr(edges)
     r = degree_reorder(g0.degree)
     g = build_csr(relabel_edges(edges, r))
-    ev = with_edge_weights(edge_view(g), seed=wseed)
+    ev = with_edge_weights(edge_view(g), seed=wseed,
+                           old_from_new=r.old_from_new)
     return g, ev
 
 
@@ -133,6 +134,95 @@ def test_families_are_deterministic_in_seed():
 
 
 # ---------------------------------------------------------------------------
+# Graph500 kernel 3 through the normal path: pipeline.build -> compile_plan
+# -> CompiledBFS.bfs, with the spec's weights
+# ---------------------------------------------------------------------------
+
+SPEC_SCALE = 11
+SPEC_SEED = 2**31 + 9
+
+
+@pytest.fixture(scope="module")
+def spec_builds():
+    """The kernel-3 configuration built with and without the degree
+    relabelling, and its per-root plan."""
+    from repro.core import pipeline
+
+    cfgs = {sort: pipeline.Graph500Config.ladder(
+        "pre-g500", scale=SPEC_SCALE, seed=SPEC_SEED, n_roots=4,
+        degree_sort=sort, heavy_threshold=None, kernel="sssp")
+        for sort in (True, False)}
+    return {sort: (cfg, pipeline.build(cfg)) for sort, cfg in cfgs.items()}
+
+
+def original_pair_weights(built) -> dict:
+    """{(min, max) original endpoint ids: weight} over the valid slots."""
+    V = built.n_vertices
+    old = (np.arange(V) if built.reorder is None
+           else np.asarray(built.reorder.old_from_new))
+    valid = np.asarray(built.ev.valid)
+    s = old[np.asarray(built.ev.src)[valid]]
+    d = old[np.asarray(built.ev.dst)[valid]]
+    w = np.asarray(built.ev.weight)[valid]
+    return dict(zip(zip(np.minimum(s, d).tolist(), np.maximum(s, d).tolist()),
+                    w.tolist()))
+
+
+def test_spec_weights_on_the_grid_symmetric_and_layout_free(spec_builds):
+    from repro.core import WEIGHT_UNIT
+
+    assert WEIGHT_UNIT == 2.0 ** -20
+    pairs = {}
+    for sort, (_, built) in spec_builds.items():
+        ev = built.ev
+        valid = np.asarray(ev.valid)
+        w = np.asarray(ev.weight)
+        assert w.dtype == np.uint32
+        assert not w[~valid].any()
+        real = w[valid] * WEIGHT_UNIT          # k * 2**-20, k an integer
+        assert real.min() > 0 and real.max() < 1
+        assert w[valid].min() >= 1 and w[valid].max() <= 2**20 - 1
+        # symmetric: each undirected pair's two slots carry one weight
+        n = built.n_vertices
+        src = np.asarray(ev.src)[valid].astype(np.int64)
+        dst = np.asarray(ev.dst)[valid].astype(np.int64)
+        fwd = dict(zip((src * n + dst).tolist(), w[valid].tolist()))
+        assert all(fwd[b * n + a] == x for a, b, x in
+                   zip(src.tolist(), dst.tolist(), w[valid].tolist()))
+        pairs[sort] = original_pair_weights(built)
+    # the relabelling does not change the problem
+    assert pairs[True] == pairs[False]
+    # and the draw spreads over (0, 1)
+    ks = np.fromiter(pairs[True].values(), np.int64)
+    assert abs(ks.mean() * WEIGHT_UNIT - 0.5) < 0.02
+
+
+def test_pipeline_sssp_equals_the_reference(spec_builds):
+    """Distances equal the host Dijkstra exactly, and parents are the
+    min-source shortest-path parents, with the reference's weights drawn
+    here over the original ids."""
+    from repro.core.graph_build import edge_weights
+    from repro.core.plan import compile_plan
+
+    cfg, built = spec_builds[True]
+    V = built.n_vertices
+    ev = built.ev
+    old = np.append(np.asarray(built.reorder.old_from_new), V)
+    src, dst, valid = (np.asarray(ev.src), np.asarray(ev.dst),
+                       np.asarray(ev.valid))
+    w = np.asarray(edge_weights(old[src], old[dst], valid, seed=SPEC_SEED))
+    compiled = compile_plan(cfg.to_plan(), built)
+    assert not compiled.plan.batch_roots and compiled.graph.core is None
+    degree = np.asarray(built.degree)
+    for root in (0, 1, int(np.count_nonzero(degree)) - 1, V - 1):
+        res = compiled.bfs(root)
+        p, d = sssp_oracle(src, dst, valid, w, V, root)
+        np.testing.assert_array_equal(np.asarray(res.parent), p)
+        np.testing.assert_array_equal(np.asarray(res.level), d)
+        assert int(res.stats.levels) >= (2 if degree[root] else 1)
+
+
+# ---------------------------------------------------------------------------
 # Plan layer: the kernel axis
 # ---------------------------------------------------------------------------
 
@@ -187,7 +277,7 @@ edges = generate_edges(3, {SCALE})
 g0 = build_csr(edges)
 r = degree_reorder(g0.degree)
 g = build_csr(relabel_edges(edges, r))
-ev = with_edge_weights(edge_view(g), seed=1)
+ev = with_edge_weights(edge_view(g), seed=1, old_from_new=r.old_from_new)
 pg = PreparedGraph(ev=ev, degree=g.degree, core=None)
 roots = np.arange({ROOTS}, dtype=np.int32)
 V = g.num_vertices
@@ -252,7 +342,7 @@ edges = generate_edges(11, 9)
 g0 = build_csr(edges)
 r = degree_reorder(g0.degree)
 g = build_csr(relabel_edges(edges, r))
-ev = with_edge_weights(edge_view(g), seed=1)
+ev = with_edge_weights(edge_view(g), seed=1, old_from_new=r.old_from_new)
 pg = PreparedGraph(ev=ev, degree=g.degree, core=None)
 roots = np.arange(4, dtype=np.int32)
 plan = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 2),

@@ -178,6 +178,32 @@ def test_per_root_bu_relax_has_no_scatter(per_root_text):
     assert _scatters(per_root_text, "bfs.bu_core")
 
 
+@pytest.fixture(scope="module")
+def sssp_text(one_chip):
+    """The compiled text of one per-root δ-stepping program, as
+    ``compile_plan`` builds it for Graph500 kernel 3."""
+    cfg = pipeline.Graph500Config.ladder("pre-g500", scale=10, n_roots=1,
+                                         heavy_threshold=None, kernel="sssp")
+    compiled = compile_plan(cfg.to_plan(), pipeline.build(cfg))
+    return compiled.lower(0, sharding=one_chip).compile().as_text()
+
+
+def test_sssp_round_loop_ops_carry_sssp_scopes(sssp_text):
+    """Every fusion the δ-stepping round loop runs names its phase, so a
+    device trace can charge its time to one; the kernel-2 kernels are not
+    on this path."""
+    ops = _level_loop_ops(sssp_text)
+    traced = [i for i in ops if len(_op_path(i)) > 1]
+    assert len(traced) >= 5, len(traced)
+    unscoped = [i[:160] for i in traced
+                if not any(p.startswith("sssp.") for p in _op_path(i))]
+    assert not unscoped, unscoped
+    phases = {p for i in traced for p in _op_path(i)
+              if p.startswith("sssp.")}
+    assert phases == {"sssp.select", "sssp.relax", "sssp.parent"}, phases
+    assert "tpu_custom_call" not in sssp_text
+
+
 def test_batched_program_keeps_one_copy_of_the_core(one_chip):
     """Batched over roots, the bottom-up step reads the dense core as it
     is: no instruction holds a per-root copy of it, which a vmapped
