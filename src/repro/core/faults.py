@@ -24,9 +24,11 @@ values inside the loop:
                         Kinds: ``zero`` (drop the outgoing delta),
                         ``flip`` (XOR one bit into it).
   site ``parent``     — the parent scatter-min epilogue of the bitmap
-                        engines (single-device AND sharded).  Kinds:
-                        ``self`` (newly-found vertices become their own
-                        parent), ``offset`` (parent ids bumped +1 mod V).
+                        engines (single-device AND sharded): per level
+                        in BFS, once per search in SSSP (pass B after
+                        the round loop).  Kinds: ``self`` (newly-found
+                        vertices become their own parent), ``offset``
+                        (parent ids bumped +1 mod V).
   site ``codec``      — the encoded wire representation between
                         ``comms.hierarchical.encode_delta`` and
                         ``decode_delta`` on the inter-group leg
@@ -157,6 +159,13 @@ def corrupt_parent(fault, parent, newly, self_ids, sentinel, *, level,
     vertex range (global ids, unvisited marked ``sentinel``), ``newly``
     the vertices found this level, ``self_ids`` each slot's own global
     vertex id.
+
+    The SSSP engines build parents once per search, after the round
+    loop, and call this once there: ``level`` is then the search's last
+    round index (rounds minus one) and ``newly`` every reached vertex
+    but the root.  A fault with ``level=k`` fires on a search whose last
+    round is ``k`` (``persistent``: at least ``k``), and corrupts the
+    whole tree.
     """
     act = fires(fault, "parent", level=level, device=device, root=root)
     if act is None:

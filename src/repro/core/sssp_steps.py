@@ -12,13 +12,16 @@ BFS one with three substitutions (the kernel interface of §16):
     frontier/visited pair, and a ``uint32`` distance plane rides along
     (``INF_U32`` = unreached); the per-round frontier is *derived*: the
     changed vertices in the minimum δ-bucket.
-  * **relax rule** — two scatter-min passes per round instead of one:
-    pass A min-relaxes distances (``dist[v] <- min(dist[v],
-    dist[u] + w)`` over frontier out-edges), pass B rebuilds parents as
-    the *minimum source among edges achieving the post-relax distance*.
-    That tie-break makes the final parent a pure function of the final
-    distances — ``parent[v] = min{u : dist[u] + w(u,v) == dist[v]}`` —
-    so it is bitwise-checkable against the host Dijkstra oracle below.
+  * **relax rule** — each round runs one scatter-min pass, pass A, which
+    min-relaxes distances (``dist[v] <- min(dist[v], dist[u] + w)`` over
+    frontier out-edges).  Parents are not tracked through the rounds:
+    pass B runs once per search, after the round loop, and takes each
+    vertex's parent as the *minimum source among edges achieving its
+    final distance* — ``parent[v] = min{u : dist[u] + w(u,v) ==
+    dist[v]}`` (:func:`_min_source_parents`).  Every weight is at least
+    one unit, so those edges form a tree rooted at the root, and the
+    parent is a pure function of the final distances, bitwise-checkable
+    against the host Dijkstra oracle below.
   * **exchange combine** — distances combine across shards with the
     min-reduction family (``comms.hierarchical.hierarchical_pmin``, the
     T3 two-phase monitor shape), while the changed-set *delta* bitmap
@@ -46,9 +49,9 @@ exact machinery built for BFS.  ``BFSStats.levels`` counts the rounds and
 The engines name each phase of a round with ``jax.named_scope``, so a
 device trace charges every op to one: ``sssp.init``, ``sssp.select``
 (the changed set's minimum bucket gives the frontier; the next changed
-set and the round's counters), ``sssp.relax`` (pass A), ``sssp.parent``
-(pass B), ``sssp.exchange`` (vertex-sharded engine only) and
-``sssp.finish``.
+set and the round's counters), ``sssp.relax`` (pass A),
+``sssp.exchange`` (vertex-sharded engine only) in the round loop, and
+after it ``sssp.parent`` (pass B, once per search) and ``sssp.finish``.
 """
 from __future__ import annotations
 
@@ -105,12 +108,33 @@ def sssp_max_rounds(max_levels: int) -> int:
     return max(int(max_levels), DEFAULT_MAX_ROUNDS)
 
 
+def _min_source_parents(src, dst, valid, wgt, dist_src_ext, dist_dst_ext,
+                        sentinel):
+    """Pass B: each slot's parent from the final distances, once per search.
+
+    ``parent[d] = min{src : dist[src] + w == dist[d]}`` over the valid
+    slots whose source is reached (``dist[src] != INF``: masked before the
+    add, which would wrap in uint32).  ``dist_src_ext`` is indexed by
+    ``src`` (global ids), ``dist_dst_ext`` by ``dst`` (the slots this
+    engine owns); each ends in one ``INF`` pad slot for the padding
+    index.  Returns int32 parents over ``dist_dst_ext``'s slots, the
+    root's and every unreached slot's left at ``sentinel``: no source
+    wins the root, since its distance is 0 and every weight is at least 1.
+    """
+    n = dist_dst_ext.shape[0] - 1
+    ds = dist_src_ext[src]
+    won = valid & (ds != jnp.uint32(_hier.INF_U32)) & (
+        ds + wgt == dist_dst_ext[dst])
+    parent = jnp.full((n + 1,), sentinel, jnp.int32).at[
+        jnp.where(won, dst, n)].min(jnp.where(won, src, sentinel))
+    return parent[:n]
+
+
 # ---------------------------------------------------------------------------
 # Single-device engine.
 # ---------------------------------------------------------------------------
 
 class _SsspState(NamedTuple):
-    parent_ext: jax.Array   # [V+1] int32 — global parent ids, sentinel V
     dist: jax.Array         # [V] uint32 — tentative distances, INF_U32 unreached
     changed_bm: jax.Array   # [W] uint32 — packed changed set (re-entries live)
     n_changed: jax.Array    # [] int32 — popcount(changed_bm)
@@ -152,7 +176,6 @@ def _run_sssp_impl(
         wgt = chunks.weight.reshape(-1)
         ids = jnp.arange(v, dtype=jnp.int32)
 
-        parent_ext = jnp.full((v + 1,), v, jnp.int32).at[root].set(root)
         dist = jnp.full((v,), _hier.INF_U32, jnp.uint32).at[root].set(
             jnp.uint32(0))
         root_bit = jnp.uint32(1) << (root % 32).astype(jnp.uint32)
@@ -184,22 +207,6 @@ def _run_sssp_impl(
             new_dist = new_dist_ext[:v]
             improved = new_dist < s.dist
 
-        # Pass B: parent = min source achieving the post-relax distance.
-        # Distance-improved slots reset to the sentinel first; equality
-        # winners min-merge (they never re-enter the changed set — the
-        # fixpoint parent is a pure function of the final distances).
-        with jax.named_scope("sssp.parent"):
-            pbase = jnp.where(improved, v, s.parent_ext[:v])
-            pext = jnp.concatenate([pbase, jnp.full((1,), v, jnp.int32)])
-            won = active & (cand == new_dist_ext[tgt])
-            new_parent_ext = pext.at[jnp.where(won, dst, v)].min(
-                jnp.where(won, src, v).astype(jnp.int32))
-            if fault is not None and fault.site == "parent":
-                pv = faults.corrupt_parent(
-                    fault, new_parent_ext[:v], improved, ids, jnp.int32(v),
-                    level=s.rnd, root=root)
-                new_parent_ext = jnp.concatenate([pv, new_parent_ext[v:]])
-
         # The next round's changed set: the popped rest plus every
         # improved vertex; then the round's counters.
         with jax.named_scope("sssp.select"):
@@ -219,7 +226,7 @@ def _run_sssp_impl(
             b_i32 = jnp.minimum(b, jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
 
             nxt = _SsspState(
-                new_parent_ext, new_dist, new_changed, n_changed, b,
+                new_dist, new_changed, n_changed, b,
                 s.rnd + 1,
                 s.stats_b.at[s.rnd].set(b_i32),
                 s.stats_fs.at[s.rnd].set(fs),
@@ -231,7 +238,7 @@ def _run_sssp_impl(
 
     with jax.named_scope("sssp.init"):
         init = _SsspState(
-            parent_ext, dist, changed_bm,
+            dist, changed_bm,
             jnp.int32(1), jnp.uint32(0), jnp.int32(0),
             jnp.full((max_rounds,), -1, jnp.int32),
             jnp.zeros((max_rounds,), jnp.int32),
@@ -239,8 +246,19 @@ def _run_sssp_impl(
             jnp.full((max_rounds,), -1, jnp.int32),
         )
     s = jax.lax.while_loop(cond, body, init)
+
+    # Pass B, once per search: parent = min source achieving the final
+    # distance.
+    with jax.named_scope("sssp.parent"):
+        dist_ext = jnp.concatenate([s.dist, jnp.full((1,), inf)])
+        parent = _min_source_parents(
+            src, dst, valid, wgt, dist_ext, dist_ext, v).at[root].set(root)
+        if fault is not None and fault.site == "parent":
+            parent = faults.corrupt_parent(
+                fault, parent, (s.dist != inf) & (ids != root), ids,
+                jnp.int32(v), level=s.rnd - 1, root=root)
     with jax.named_scope("sssp.finish"):
-        parent = jnp.where(s.parent_ext[:v] == v, -1, s.parent_ext[:v])
+        parent = jnp.where(parent == v, -1, parent)
         dist_i = jnp.where(s.dist == inf, -1, s.dist.astype(jnp.int32))
         return BFSResult(
             parent=parent,
@@ -283,7 +301,6 @@ def _run_sssp_batch(chunks, degree, roots, *, delta, max_rounds, fault=None):
 # ---------------------------------------------------------------------------
 
 class _SsspShardState(NamedTuple):
-    parent_loc: jax.Array   # [V_loc+1] int32 — global ids, sentinel V_pad
     dist_full: jax.Array    # [V_pad] uint32 — replicated distance plane
     changed_bm: jax.Array   # [W_pad] uint32 — replicated changed set
     n_changed: jax.Array    # [] int32
@@ -359,13 +376,8 @@ def _run_sssp_sharded(
     # wrongly stripped as "already known".
     delta_wire = "flat" if exchange == "flat" else "hier_or_packed"
 
-    # --- init: root bit set once; owner holds the root parent.
+    # --- init: root bit set once.
     with jax.named_scope("sssp.init"):
-        is_mine, root_slot = to_local(root)
-        parent_loc = jnp.where((slots == root_slot) & is_mine, root,
-                               jnp.int32(v_pad))
-        parent_loc = jnp.concatenate(
-            [parent_loc, jnp.full((1,), v_pad, jnp.int32)])
         dist_full = jnp.full((v_pad,), _hier.INF_U32, jnp.uint32).at[
             root].set(jnp.uint32(0))
         root_bit = jnp.uint32(1) << (root % 32).astype(jnp.uint32)
@@ -405,19 +417,6 @@ def _run_sssp_sharded(
             new_dist_loc_ext = dist_loc_ext.at[tgt].min(cand)
             new_dist_loc = new_dist_loc_ext[:v_loc]
             improved_loc = new_dist_loc < dist_loc
-
-        # Pass B: parent = min source achieving the post-relax distance.
-        with jax.named_scope("sssp.parent"):
-            pbase = jnp.where(improved_loc, v_pad, s.parent_loc[:v_loc])
-            pext = jnp.concatenate([pbase, jnp.full((1,), v_pad, jnp.int32)])
-            won = active & (cand == new_dist_loc_ext[tgt])
-            new_parent = pext.at[jnp.where(won, dst_flat, v_loc)].min(
-                jnp.where(won, src_flat, v_pad).astype(jnp.int32))
-            if fault is not None and fault.site == "parent":
-                pv = faults.corrupt_parent(
-                    fault, new_parent[:v_loc], improved_loc, gslots,
-                    jnp.int32(v_pad), level=s.rnd, device=dev, root=root)
-                new_parent = jnp.concatenate([pv, new_parent[v_loc:]])
 
         with jax.named_scope("sssp.exchange"):
             # Exchange 1 — distance plane: owner slots carry the new
@@ -472,7 +471,7 @@ def _run_sssp_sharded(
             b_i32 = jnp.minimum(b, jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
 
             nxt = _SsspShardState(
-                new_parent, new_dist_full, new_changed, n_changed, b,
+                new_dist_full, new_changed, n_changed, b,
                 s.rnd + 1,
                 s.stats_b.at[s.rnd].set(b_i32),
                 s.stats_fs.at[s.rnd].set(fs),
@@ -484,7 +483,7 @@ def _run_sssp_sharded(
 
     with jax.named_scope("sssp.init"):
         init = _SsspShardState(
-            parent_loc, dist_full, changed_bm,
+            dist_full, changed_bm,
             jnp.int32(1), jnp.uint32(0), jnp.int32(0),
             jnp.full((max_rounds,), -1, jnp.int32),
             jnp.zeros((max_rounds,), jnp.int32),
@@ -492,10 +491,23 @@ def _run_sssp_sharded(
             jnp.full((max_rounds,), -1, jnp.int32),
         )
     s = jax.lax.while_loop(cond, body, init)
-    with jax.named_scope("sssp.finish"):
-        parent = jnp.where(s.parent_loc[:v_loc] == v_pad, -1,
-                           s.parent_loc[:v_loc])
+
+    # Pass B, once per search, over the dst-owned slots: dist[src] from
+    # the replicated plane, dist[dst] from the owned slice.
+    with jax.named_scope("sssp.parent"):
         dist_own = s.dist_full[gslots]
+        is_mine, root_slot = to_local(root)
+        parent = _min_source_parents(
+            jnp.clip(src_flat, 0, v_pad), dst_flat, valid_flat, wgt_flat,
+            jnp.concatenate([s.dist_full, jnp.full((1,), inf)]),
+            jnp.concatenate([dist_own, jnp.full((1,), inf)]), v_pad)
+        parent = jnp.where((slots == root_slot) & is_mine, root, parent)
+        if fault is not None and fault.site == "parent":
+            parent = faults.corrupt_parent(
+                fault, parent, (dist_own != inf) & (gslots != root), gslots,
+                jnp.int32(v_pad), level=s.rnd - 1, device=dev, root=root)
+    with jax.named_scope("sssp.finish"):
+        parent = jnp.where(parent == v_pad, -1, parent)
         dist_i = jnp.where(dist_own == inf, -1,
                            dist_own.astype(jnp.int32))
         return BFSResult(

@@ -13,8 +13,10 @@ SSSP checks (kernel ``"sssp"``, DESIGN.md §16 — same shape, different
 invariants over ``(parent, dist)`` where ``dist`` rides in the result's
 ``level`` plane):
   S1. parent[root] == root, dist[root] == 0.
-  S2. every reached non-root vertex v satisfies
-      dist[v] == dist[parent[v]] + w(parent[v], v)  (tree distances).
+  S2. every non-root vertex reached by either plane (a parent or a
+      distance) has both, and
+      dist[v] == dist[parent[v]] + w(parent[v], v)  (tree distances: a
+      distance no tree edge achieves fails here).
   S3. every tree edge (v, parent[v]) exists in the input graph.
   S4. no edge gives a shorter path than claimed:
       dist[d] <= dist[s] + w(s, d) for every edge with both ends reached
@@ -158,7 +160,7 @@ def validate_sssp(ev: EdgeView, result: BFSResult, root: jax.Array
 
     tree_dist_ok = jnp.all(
         jnp.where(
-            reached & ~is_root,
+            (reached | (dist >= 0)) & ~is_root,
             (parent >= 0)
             & (parent < v)
             & (parent != jnp.arange(v))

@@ -134,6 +134,101 @@ def test_families_are_deterministic_in_seed():
 
 
 # ---------------------------------------------------------------------------
+# Min-source parents on ties: every engine, bitwise, on a disconnected graph
+# ---------------------------------------------------------------------------
+
+TESTS_DIR = os.path.dirname(os.path.abspath(__file__))
+
+
+def tie_heavy_cases():
+    """Two disjoint copies of one Kronecker graph, so a root in the first
+    leaves the second's sources unreached, under two tie-heavy
+    weightings: every weight 1, and symmetric weights in [1, 3].  Roots:
+    the first copy's hub and a leaf, the second copy's hub, and an
+    isolated vertex."""
+    import jax.numpy as jnp
+
+    from repro.core.kronecker import EdgeList
+
+    a = generate_edges(5, 7)
+    n = a.num_vertices
+    g = build_csr(EdgeList(jnp.concatenate([a.src, a.src + n]),
+                           jnp.concatenate([a.dst, a.dst + n]), 2 * n))
+    ev = edge_view(g)
+    degree = np.asarray(g.degree)
+    roots = np.array([0, int(np.flatnonzero(degree[:n] == 1)[0]), n,
+                      int(np.flatnonzero(degree == 0)[0])], np.int32)
+    lo, hi = jnp.minimum(ev.src, ev.dst), jnp.maximum(ev.src, ev.dst)
+    weights = {"unit": jnp.ones_like(ev.src),
+               "one_to_three": 1 + (lo * 7 + hi * 13) % 3}
+    return [(name, g, ev.__class__(ev.src, ev.dst, ev.valid, ev.num_vertices,
+                                   jnp.where(ev.valid, w, 0)
+                                   .astype(jnp.uint32)), roots)
+            for name, w in weights.items()]
+
+
+def check_tie_parity(engine: str) -> int:
+    """Run ``engine`` on every tie-heavy case and hold parents and
+    distances to the oracle bitwise; returns the cases checked."""
+    n_ok = 0
+    for name, g, ev, roots in tie_heavy_cases():
+        V = g.num_vertices
+        o_par, o_dist = oracle_planes(g, ev, roots)
+        # The case is what it claims: many vertices have several
+        # shortest-path parents, and reached roots leave sources with
+        # edges unreached.
+        s, d, w = (np.asarray(x)[np.asarray(ev.valid)].astype(np.int64)
+                   for x in (ev.src, ev.dst, ev.weight))
+        ds, dd = o_dist[0][s], o_dist[0][d]
+        wins = (ds >= 0) & (ds + w == dd)
+        assert np.count_nonzero(np.bincount(d[wins], minlength=V) > 1) \
+            > np.count_nonzero(o_dist[0] >= 0) // 10, name
+        assert np.any(o_dist[0][s] < 0), name
+        if engine == "sharded_2x2":
+            plan = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 2),
+                                 batch_roots=True, kernel="sssp")
+            pg = PreparedGraph(ev=ev, degree=g.degree, core=None)
+        else:
+            plan = TraversalPlan(layout=(), batch_roots=engine == "batched",
+                                 kernel="sssp")
+            pg = PreparedGraph(ev=ev, degree=g.degree, core=None,
+                               chunks=chunk_edge_view(ev))
+        compiled = compile_plan(plan, pg)
+        if engine == "per_root":
+            runs = [compiled.bfs(int(r)) for r in roots]
+            par = np.stack([np.asarray(r.parent)[:V] for r in runs])
+            dist = np.stack([np.asarray(r.level)[:V] for r in runs])
+        else:
+            res = compiled.bfs(roots)
+            par = np.asarray(res.parent)[:, :V]
+            dist = np.asarray(res.level)[:, :V]
+        assert np.array_equal(par, o_par), (engine, name)
+        assert np.array_equal(dist, o_dist), (engine, name)
+        n_ok += 1
+    return n_ok
+
+
+TIE_SHARDED = f"""
+import sys
+sys.path.insert(0, {TESTS_DIR!r})
+from test_sssp import check_tie_parity
+print(f"TIES_OK n={{check_tie_parity('sharded_2x2')}}")
+"""
+
+
+@pytest.mark.parametrize("engine", ["per_root", "batched", "sharded_2x2"])
+def test_tie_heavy_disconnected_parents_match_oracle(engine):
+    """Parents are built once per search from the final distances: on
+    weightings where many sources reach a vertex at equal distance, and
+    with sources the search never reaches, every engine gives the
+    oracle's min-source parents and distances bit for bit."""
+    if engine == "sharded_2x2":
+        assert "TIES_OK n=2" in run_sub(TIE_SHARDED)
+    else:
+        assert check_tie_parity(engine) == 2
+
+
+# ---------------------------------------------------------------------------
 # Graph500 kernel 3 through the normal path: pipeline.build -> compile_plan
 # -> CompiledBFS.bfs, with the spec's weights
 # ---------------------------------------------------------------------------
@@ -251,6 +346,25 @@ def test_plan_kernel_axis_validation_and_shims():
     assert (rp.kernel, rp.exchange, rp.partition) == \
         ("sssp", "hier_min", "word_cyclic")
     assert rekernel_plan(rp, "sssp") is rp
+
+
+def test_validate_sssp_flags_a_distance_without_a_parent():
+    """S2 holds the two planes to one reached set: a vertex that keeps
+    its distance but loses its parent fails ``tree_dist``, as a distance
+    no tree edge achieves."""
+    import jax.numpy as jnp
+
+    from repro.core import BFSResult, validate_sssp
+
+    g, ev = weighted_graph()
+    o_par, o_dist = oracle_planes(g, ev, [0])
+    good = BFSResult(parent=jnp.asarray(o_par[0]),
+                     level=jnp.asarray(o_dist[0]), stats=None)
+    assert bool(validate_sssp(ev, good, 0).ok)
+    far = int(np.argmax(o_dist[0]))
+    bad = good._replace(parent=good.parent.at[far].set(-1))
+    val = validate_sssp(ev, bad, 0)
+    assert not bool(val.tree_dist_ok) and not bool(val.ok)
 
 
 def test_sssp_requires_weight_plane():

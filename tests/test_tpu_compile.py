@@ -108,10 +108,9 @@ def _computations(text) -> dict:
     return comps
 
 
-def _level_loop_ops(text) -> list:
-    """The fusions and custom calls in the level loop's body and in every
-    computation it calls (branches, inner loops, fusions)."""
-    comps = _computations(text)
+def _loop_computations(text, comps) -> set:
+    """Names of the level loop's body and of every computation it calls
+    (branches, inner loops, fusions)."""
     entry = re.search(r"^ENTRY %(\S+) ", text, re.M).group(1)
     loop = [i for i in comps[entry] if " while(" in i]
     assert len(loop) == 1, loop
@@ -125,7 +124,15 @@ def _level_loop_ops(text) -> list:
             for one, many in _CALLEES.findall(inst):
                 todo += [one] if one else [
                     c.strip().lstrip("%") for c in many.split(",")]
-    return [i for name in seen for i in comps[name]
+    return seen
+
+
+def _level_loop_ops(text) -> list:
+    """The fusions and custom calls in the level loop's body and in every
+    computation it calls."""
+    comps = _computations(text)
+    return [i for name in _loop_computations(text, comps)
+            for i in comps[name]
             if re.search(r" (fusion|custom-call)\(", i)]
 
 
@@ -200,8 +207,53 @@ def test_sssp_round_loop_ops_carry_sssp_scopes(sssp_text):
     assert not unscoped, unscoped
     phases = {p for i in traced for p in _op_path(i)
               if p.startswith("sssp.")}
-    assert phases == {"sssp.select", "sssp.relax", "sssp.parent"}, phases
+    assert phases == {"sssp.select", "sssp.relax"}, phases
     assert "tpu_custom_call" not in sssp_text
+
+
+@pytest.fixture(scope="module")
+def sssp_sharded_text(topo):
+    """The compiled text of the 2x2 vertex-sharded δ-stepping program
+    (``hier_min``), lowered from shapes on the described chips."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.plan import vertex_sharded_program
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("group", "member"))
+    n_dev, w_loc, n_chunks, chunk = 4, 8, 4, 1024
+    step = vertex_sharded_program(
+        mesh, w_loc=w_loc, n_dev=n_dev, exchange="hier_min", kernel="sssp",
+        delta=1 << 19, max_rounds=512)
+    rep = NamedSharding(mesh, P())
+    shard = NamedSharding(mesh, P(("group", "member")))
+    slots = (n_dev, n_chunks, chunk)
+    args = [_shape((), jnp.int32, rep)] + [
+        _shape(sh, dt, shard) for sh, dt in (
+            (slots, jnp.int32), (slots, jnp.int32), (slots, jnp.bool_),
+            ((n_dev, n_chunks), jnp.int32), ((n_dev, n_chunks), jnp.int32),
+            (slots, jnp.uint32), ((n_dev, 32 * w_loc), jnp.int32))
+    ] + [_shape((), jnp.int32, rep)]
+    return _compiled_text(step, *args)
+
+
+@pytest.mark.parametrize("engine", ["per_root", "sharded_2x2"])
+def test_sssp_parents_built_once_after_the_round_loop(
+        engine, sssp_text, sssp_sharded_text):
+    """Pass B runs once per search: no instruction of the round loop
+    carries ``sssp.parent``, and the min-source scatter under that scope
+    sits outside the loop."""
+    text = sssp_text if engine == "per_root" else sssp_sharded_text
+    comps = _computations(text)
+    in_loop = _loop_computations(text, comps)
+    looped = [i[:160] for name in in_loop for i in comps[name]
+              if "sssp.parent" in _op_path(i)]
+    assert not looped, looped
+    outside = [i for name, insts in comps.items() if name not in in_loop
+               for i in insts]
+    assert _scatters("\n".join(outside), "sssp.parent")
+    assert any("sssp.relax" in _op_path(i) for name in in_loop
+               for i in comps[name])
 
 
 def test_batched_program_keeps_one_copy_of_the_core(one_chip):
