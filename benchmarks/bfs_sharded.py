@@ -1,15 +1,15 @@
 """Mesh-sharded Graph500 ladder (DESIGN.md §9/§10): BENCH_bfs.json rungs
-per mesh shape, every rung a :class:`repro.core.plan.BFSPlan`.
+per mesh shape, every rung a :class:`repro.core.plan.TraversalPlan`.
 
 Three harness layers over 8 forced host devices (the container is
 XLA:CPU; relative rungs, not absolute GTEPS, are the tracked numbers):
 
-  * root-parallel   — ``BFSPlan(layout=("root",))`` over 1/2/4/8
+  * root-parallel   — ``TraversalPlan(layout=("root",))`` over 1/2/4/8
     devices: the 64 search keys split with zero communication.  Rung "1"
     is the plain single-device batch plan (the PR-1 baseline).  Parents
     are asserted bitwise-identical to the baseline for every shape
     before timing.
-  * vertex-sharded  — ``BFSPlan(layout=("group", "member"))`` over
+  * vertex-sharded  — ``TraversalPlan(layout=("group", "member"))`` over
     meshes 2x1 / 2x2 / 4x2: one giant traversal spans the mesh, the
     per-level delta bitmaps combine through the T3 two-phase bitwise-OR
     collective (``exchange="hier_or"``).  Each mesh runs under BOTH
@@ -17,15 +17,8 @@ XLA:CPU; relative rungs, not absolute GTEPS, are the tracked numbers):
     ``word_cyclic`` (paper eq. (3); ``2x2_cyc``) — and every vertex
     rung records the per-shard edge-count skew (``edge_skew``:
     max / mean / max_over_mean of the dst-owner counts, the padding
-    overhead the block layout pays after the degree sort).  The 4x2
-    shape additionally runs the DESIGN.md §12 wire-codec exchanges —
-    ``hier_or_packed`` (density-adaptive sparse/dense codec on the
-    inter-group leg; rungs ``4x2_pack`` / ``4x2_pack_cyc``) and
-    ``hier_or_sieve`` (visited-sieve then pack; ``4x2_sieve`` /
-    ``4x2_sieve_cyc``) — and every vertex rung records the modeled
-    per-level wire bytes (raw vs post-sieve vs post-codec per exchange
-    leg, ``wire_bytes``) recovered from the first root's level array.
-  * composed        — ``BFSPlan(layout=("root", "group", "member"))``
+    overhead the block layout pays after the degree sort).
+  * composed        — ``TraversalPlan(layout=("root", "group", "member"))``
     over the 2x2x2 mesh: the root batch splits over its own mesh axis
     OUTSIDE the vertex-sharded SPMD program (layer 1 x layer 2).
 
@@ -33,7 +26,7 @@ Because the main benchmark process must keep seeing one device, the
 measurements run in a child process carrying
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``; the child prints
 a JSON payload the parent folds into ``BENCH_bfs.json``.  Each rung's
-payload records its plan (``BFSPlan.to_dict()``).
+payload records its plan (``TraversalPlan.to_dict()``).
 
 Env knobs: ``BENCH_SHARDED_SCALE`` (default 14 — the acceptance scale),
 ``BENCH_SHARDED_ROOTS`` (default 64), ``BENCH_SHARDED_VERTEX_ROOTS``
@@ -83,8 +76,9 @@ def _child() -> dict:
     import jax
 
     from repro.core import (
-        BFSPlan, PreparedGraph, build_csr, build_heavy_core, chunk_edge_view,
-        compile_plan, degree_reorder, edge_view, generate_edges, sample_roots,
+        PreparedGraph, TraversalPlan, build_csr, build_heavy_core,
+        chunk_edge_view, compile_plan, degree_reorder, edge_view,
+        generate_edges, sample_roots,
     )
     from repro.core.reorder import relabel_edges
     from repro.kernels import ops as kops
@@ -133,7 +127,7 @@ def _child() -> dict:
     # traversal on the interpret-mode container), so it runs lazily: only
     # when a selected rung needs a parity check or the rel-vs-single
     # denominator.
-    base_plan = BFSPlan(layout=(), batch_roots=True)
+    base_plan = TraversalPlan(layout=(), batch_roots=True)
     base = compile_plan(base_plan, pg)
     _base_parent: dict = {}
 
@@ -193,7 +187,7 @@ def _child() -> dict:
         if n_dev == 1:
             plan, compiled = base_plan, base
         else:
-            plan = BFSPlan(layout=("root",), mesh_shape=(n_dev,))
+            plan = TraversalPlan(layout=("root",), mesh_shape=(n_dev,))
             compiled = compile_plan(plan, pg)
         res, rung = timed_rung(lambda: compiled.bfs(roots), plan,
                                "root_parallel", name, n_roots,
@@ -218,7 +212,7 @@ def _child() -> dict:
     # all visible devices (member sized to the router group) rides along
     # as its own rung so the eq.-5-derived shape is measured, not assumed.
     from repro.comms.topology import plan_device_mesh
-    from repro.core.distributed_bfs import modeled_wire_bytes, shard_edge_skew
+    from repro.core.distributed_bfs import shard_edge_skew
     planned = plan_device_mesh(len(jax.devices()))
     shapes = list(VERTEX_SHAPES)
     if planned not in shapes:
@@ -226,24 +220,15 @@ def _child() -> dict:
     out["planned_shape"] = f"{planned[0]}x{planned[1]}"
     vroots = roots[:n_vroots]
     # both partitions cover the same shape set — including the planner's
-    # eq.-5 shape, so the block-vs-cyclic skew comparison exists for it;
-    # the §12 wire-codec exchanges (hier_or_packed = density-adaptive
-    # codec on the inter-group leg, hier_or_sieve = visited-sieve then
-    # pack) ride on the 4x2 acceptance shape under both partitions
-    cases = ([(s, "block", "hier_or") for s in shapes]
-             + [(s, "word_cyclic", "hier_or") for s in shapes]
-             + [((4, 2), p, e)
-                for e in ("hier_or_packed", "hier_or_sieve")
-                for p in ("block", "word_cyclic")])
-    suffix = {"hier_or": "", "hier_or_packed": "_pack",
-              "hier_or_sieve": "_sieve"}
-    for shape, partition, exchange in cases:
-        name = (f"{shape[0]}x{shape[1]}" + suffix[exchange]
+    # eq.-5 shape, so the block-vs-cyclic skew comparison exists for it
+    cases = [(s, p) for p in ("block", "word_cyclic") for s in shapes]
+    for shape, partition in cases:
+        name = (f"{shape[0]}x{shape[1]}"
                 + ("_cyc" if partition == "word_cyclic" else ""))
         if not wanted(name):
             continue
-        plan = BFSPlan(layout=("group", "member"), mesh_shape=shape,
-                       exchange=exchange, partition=partition)
+        plan = TraversalPlan(layout=("group", "member"), mesh_shape=shape,
+                             exchange="hier_or", partition=partition)
         compiled = compile_plan(plan, pg)    # shards the graph internally
         skew = shard_edge_skew(compiled.graph.sharded)
         result = compiled.run(vroots, check="post")
@@ -256,15 +241,8 @@ def _child() -> dict:
                 for r, names in sorted(run.check_failures.items()))
             raise RuntimeError(
                 f"vertex-sharded rung {name} (mesh={shape} "
-                f"partition={partition} exchange={exchange}): spec "
+                f"partition={partition} exchange=hier_or): spec "
                 f"validation failed — {detail or 'unknown check'}")
-        # modeled per-level wire bytes (raw / post-sieve / post-codec per
-        # exchange leg, DESIGN.md §12) recovered from the first root's
-        # level array
-        wire = modeled_wire_bytes(
-            result.level[0], n_devices=shape[0] * shape[1],
-            w_loc=compiled.graph.sharded.w_loc,
-            group=shape[0], member=shape[1], partition=partition)
         out["vertex_sharded"][name] = {
             "mesh": f"{shape[0]}x{shape[1]}",
             "layer": "vertex_sharded",
@@ -276,22 +254,18 @@ def _child() -> dict:
             "validated": run.all_valid,
             "check_counts": run.check_counts,
             "edge_skew": skew,
-            "wire_bytes": wire,
         }
-        wt = wire["totals"]
         print(f"# vertex_sharded mesh={name}: "
               f"wall={float(np.sum(run.times_s)):.2f}s "
-              f"skew={skew['max_over_mean']:.2f} "
-              f"wire_inter={wt['inter_raw']}B"
-              f"->codec {wt['inter_post_codec']}B", file=sys.stderr)
+              f"skew={skew['max_over_mean']:.2f}", file=sys.stderr)
 
     # ---- composed 3-axis ladder (layer 1 x layer 2) --------------------
     for shape in COMPOSED_SHAPES:
         name = f"{shape[0]}x{shape[1]}x{shape[2]}"
         if not wanted(name):
             continue
-        plan = BFSPlan(layout=("root", "group", "member"), mesh_shape=shape,
-                       exchange="hier_or")
+        plan = TraversalPlan(layout=("root", "group", "member"),
+                             mesh_shape=shape, exchange="hier_or")
         compiled = compile_plan(plan, pg)
         res, rung = timed_rung(
             lambda: compiled.bfs(vroots), plan, "composed", name,
@@ -460,8 +434,7 @@ def run():
             f"layer=multiprocess;procs={rung['procs']};"
             f"hmean_GTEPS={rung['harmonic_mean_teps'] / 1e9:.5f};"
             f"identical={rung['identical']};"
-            f"exchange_s={exch.get('total_seconds', float('nan')):.4f};"
-            f"wire_inter={rung['wire_bytes']['totals']['inter_raw']}B"))
+            f"exchange_s={exch.get('total_seconds', float('nan')):.4f}"))
     for name, rung in payload["mesh_ladder"].items():
         rows.append(row(
             f"bfs_sharded/scale{payload['scale']}/mesh{name}",

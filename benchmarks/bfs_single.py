@@ -9,8 +9,6 @@ Matrix-2000+ — the *relative ladder* is the reproduction target):
                     vector shape — interpret-mode Pallas on CPU)
   avls            : dense-core Pallas kernel, hand-tuned rows_per_tile (the
                     vector-length-specified mode)
-  legacy_engine   : the seed customized loop — bool frontier, per-level
-                    bitmap round trip, all-edges top-down (the "before")
   bitmap_engine   : the bitmap-resident loop — packed frontier/visited
                     across the whole while_loop, fused frontier_update
                     epilogue, chunked frontier-proportional top-down
@@ -23,8 +21,8 @@ list) to override — the CI smoke run uses that for the scale-14 check.
 
 The module also fills a machine-readable payload (``json_payload()``) that
 ``benchmarks/run.py`` writes to ``BENCH_bfs.json`` at the repo root: engine
-wall-clock + TEPS, per-level breakdown (direction, frontier, scanned edges,
-scanned chunks), and the before/after speedup of the resident loop.
+wall-clock + TEPS and the per-level breakdown (direction, frontier, scanned
+edges, scanned chunks).
 """
 from __future__ import annotations
 
@@ -32,14 +30,13 @@ import os
 import time
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from benchmarks.common import FAST, row, timed
 from repro.core import (
-    BFSPlan, PreparedGraph, build_csr, build_heavy_core, chunk_edge_view,
-    compile_plan, degree_reorder, edge_view, generate_edges, sample_roots,
-    traversed_edges,
+    PreparedGraph, TraversalPlan, build_csr, build_heavy_core,
+    chunk_edge_view, compile_plan, degree_reorder, edge_view, generate_edges,
+    sample_roots, traversed_edges,
 )
 from repro.core.heavy import pack_bitmap
 from repro.core.reference import reference_bfs
@@ -97,13 +94,11 @@ def run():
         pg = PreparedGraph(ev=ev, degree=g.degree, core=core, chunks=chunks)
         pg_nocore = PreparedGraph(ev=ev, degree=g.degree, chunks=chunks)
         plan_xla = compile_plan(
-            BFSPlan(engine="reference", batch_roots=False), pg)
-        plan_leg = compile_plan(
-            BFSPlan(engine="legacy", batch_roots=False), pg)
+            TraversalPlan(engine="reference", batch_roots=False), pg)
         plan_bm = compile_plan(
-            BFSPlan(engine="bitmap", batch_roots=False), pg)
+            TraversalPlan(engine="bitmap", batch_roots=False), pg)
         plan_nocore = compile_plan(
-            BFSPlan(engine="bitmap", batch_roots=False), pg_nocore)
+            TraversalPlan(engine="bitmap", batch_roots=False), pg_nocore)
         res = plan_xla.bfs(root)
         m = int(traversed_edges(g.degree, res))
         engines: dict[str, dict] = {}
@@ -131,27 +126,13 @@ def run():
                 f"bfs_single/scale{scale}/{mode}(rows={rpt})", t_k * 1e6,
                 f"core_bits_per_s={bits / t_k:.3g}"))
 
-        # Before/after pair measured *interleaved* so background load drift
-        # hits both engines equally — their ratio is the tracked number.
-        fn_leg = lambda: plan_leg.bfs(root).parent
-        fn_bm = lambda: plan_bm.bfs(root).parent
-        jax.block_until_ready(fn_leg())
-        jax.block_until_ready(fn_bm())
-        t_legs, t_bms = [], []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn_leg())
-            t_legs.append(time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn_bm())
-            t_bms.append(time.perf_counter() - t0)
         note = ";note=interpret-mode Pallas (CPU) — see DESIGN.md §8"
-        record("legacy_engine", float(np.median(t_legs)), note)
-        record("bitmap_engine", float(np.median(t_bms)), note)
+        record("bitmap_engine", timed(lambda: plan_bm.bfs(root).parent),
+               note)
         record("bitmap_nocore", timed(lambda: plan_nocore.bfs(root).parent))
 
         # --- Graph500-spec batched harness: 64 keys, one jitted program ---
-        # Timed once inside run_graph500_batched (the fused program is too
+        # Timed once by CompiledBFS.run (the fused program is too
         # expensive on interpret-mode CPU for repeat timing), and skipped
         # above BENCH_BATCH_SCALE_MAX: under vmap, chunk skipping becomes
         # masking, so the batch scans all edges for all roots every level
@@ -163,8 +144,9 @@ def run():
         if scale <= batch_scale_max:
             roots = np.asarray(sample_roots(1, edges, 64))
             roots = np.asarray(r.new_from_old)[roots]
-            g500 = compile_plan(BFSPlan(layout=(), batch_roots=True), pg).run(
-                roots, warmup=True, do_validate=False).run
+            g500 = compile_plan(
+                TraversalPlan(layout=(), batch_roots=True), pg).run(
+                    roots, warmup=True, do_validate=False).run
             t_b = float(np.sum(g500.times_s))
             rows.append(row(
                 f"bfs_single/scale{scale}/batch64", t_b * 1e6 / len(roots),
@@ -174,7 +156,7 @@ def run():
                 "n_roots": int(len(roots)),
                 "batch_us": t_b * 1e6,
                 "harmonic_mean_teps": g500.harmonic_mean_teps,
-                "plan": BFSPlan(layout=(), batch_roots=True).to_dict(),
+                "plan": TraversalPlan(layout=(), batch_roots=True).to_dict(),
             }
         else:
             rows.append(row(
@@ -182,16 +164,9 @@ def run():
                 f"SKIPPED:batched-harness-beyond-scale-{batch_scale_max}"
                 "-on-interpret-backend"))
 
-        # --- per-level breakdown + before/after for BENCH_bfs.json -------
+        # --- per-level breakdown for BENCH_bfs.json ----------------------
         res_bm = plan_bm.bfs(root)
         lv = int(res_bm.stats.levels)
-        speedup = (engines["legacy_engine"]["us_per_call"]
-                   / engines["bitmap_engine"]["us_per_call"])
-        rows.append(row(
-            f"bfs_single/scale{scale}/resident_vs_seed_loop", 0.0,
-            f"speedup={speedup:.2f}x;"
-            f"chunks_per_level={np.asarray(res_bm.stats.scanned_chunks)[:lv].tolist()};"
-            f"total_chunks={int(res_bm.stats.total_chunks)}"))
         from repro.kernels import ops as kops
         _PAYLOAD[f"scale{scale}"] = {
             "scale": scale,
@@ -199,8 +174,8 @@ def run():
             # so the doc-level interpret_mode only describes the last run
             "interpret_mode": kops.interpret_mode(),
             "engine": "bitmap",
-            "plan": BFSPlan(engine="bitmap", layout=(),
-                            batch_roots=False).to_dict(),
+            "plan": TraversalPlan(engine="bitmap", layout=(),
+                                  batch_roots=False).to_dict(),
             "heavy_threshold": threshold,
             "traversed_edges": m,
             "engines": engines,
@@ -215,7 +190,6 @@ def run():
                     np.asarray(res_bm.stats.scanned_chunks)[:lv].tolist(),
                 "total_chunks": int(res_bm.stats.total_chunks),
             },
-            "speedup_bitmap_vs_seed_loop": speedup,
             "speedup_bitmap_nocore_vs_reference_engine": (
                 engines["xla"]["us_per_call"]
                 / engines["bitmap_nocore"]["us_per_call"]),

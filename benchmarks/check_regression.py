@@ -12,7 +12,7 @@ rungs in that scale's ``rungs_from_this_run``) — against the committed
 baseline.  A rung pair only gates when its identity matches exactly:
 
   * rung name (module / scale / layer / rung),
-  * the :class:`repro.core.plan.BFSPlan` dict that produced the number,
+  * the :class:`repro.core.plan.TraversalPlan` dict that produced the number,
   * interpret mode (a Mosaic-vs-interpret flip is a backend change, not
     a regression).
 
@@ -29,7 +29,7 @@ First-run serve rungs simply report as unmatched (not gated) until a
 baseline with them is committed.
 
 Plan dicts are compared after **default-filling**: a baseline recorded
-before a :class:`repro.core.plan.BFSPlan` field existed (e.g. the v2
+before a :class:`repro.core.plan.TraversalPlan` field existed (e.g. the v2
 ``partition`` axis) still matches a current rung that carries the
 field at its default value — adding a plan axis must not zero-match
 every committed baseline.  A field present on BOTH sides with
@@ -66,11 +66,11 @@ def _load(path: str) -> dict:
 
 
 def _plan_defaults() -> dict:
-    """The current BFSPlan's field defaults (single source of truth for
-    the default-fill — never a copy hardcoded here)."""
-    from repro.core.plan import BFSPlan
+    """The current TraversalPlan's field defaults (single source of truth
+    for the default-fill — never a copy hardcoded here)."""
+    from repro.core.plan import TraversalPlan
 
-    return BFSPlan().to_dict()
+    return TraversalPlan().to_dict()
 
 
 def normalize_plan(plan: dict, defaults: dict | None = None) -> dict:

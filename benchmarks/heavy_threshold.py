@@ -12,8 +12,9 @@ import numpy as np
 
 from benchmarks.common import FAST, row, timed
 from repro.core import (
-    BFSPlan, PreparedGraph, build_csr, build_heavy_core, chunk_edge_view,
-    compile_plan, degree_reorder, edge_view, generate_edges, traversed_edges,
+    PreparedGraph, TraversalPlan, build_csr, build_heavy_core,
+    chunk_edge_view, compile_plan, degree_reorder, edge_view, generate_edges,
+    traversed_edges,
 )
 from repro.core.reorder import relabel_edges
 
@@ -30,7 +31,7 @@ def run():
     g = build_csr(relabel_edges(edges, r))
     ev = edge_view(g)
     chunks = chunk_edge_view(ev)  # construction, untimed (spec)
-    ref = compile_plan(BFSPlan(engine="reference", batch_roots=False),
+    ref = compile_plan(TraversalPlan(engine="reference", batch_roots=False),
                        PreparedGraph(ev=ev, degree=g.degree))
     res = ref.bfs(0)
     m = int(traversed_edges(g.degree, res))
@@ -41,7 +42,7 @@ def run():
                     f"GTEPS={m / t_none / 1e9:.5f}"))
 
     thresholds = (4, 16, 64) if FAST else (4, 16, 50, 64, 100)
-    plan_bm = BFSPlan(engine="bitmap", batch_roots=False)
+    plan_bm = TraversalPlan(engine="bitmap", batch_roots=False)
     for d_thr in thresholds:
         core = build_heavy_core(g, threshold=d_thr)
         frac_v = float((deg >= d_thr).mean())

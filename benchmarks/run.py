@@ -17,7 +17,7 @@ full set.
 Modules may additionally expose ``json_payload() -> dict``; the collected
 payloads are written to ``BENCH_bfs.json`` at the repo root (plus run
 metadata) so the perf trajectory is tracked in-tree from PR to PR.  Rung
-entries record the :class:`repro.core.plan.BFSPlan` that produced them
+entries record the :class:`repro.core.plan.TraversalPlan` that produced them
 (as a dict) so every number names the engine configuration it measured.
 
 The merge here is module-granularity (a partial run must not clobber the

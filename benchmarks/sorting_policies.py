@@ -13,7 +13,7 @@ import numpy as np
 
 from benchmarks.common import FAST, row, timed
 from repro.core import (
-    BFSPlan, PreparedGraph, build_csr, compile_plan, degree_reorder,
+    PreparedGraph, TraversalPlan, build_csr, compile_plan, degree_reorder,
     edge_view, generate_edges, traversed_edges,
 )
 from repro.core.reorder import relabel_edges, sort_host
@@ -42,7 +42,7 @@ def run():
                         f"keys_per_s={n / max(dt, 1e-9):.3g}"))
 
     # --- Fig. 12/13: BFS TEPS with and without the reordering -------------
-    plan_ref = BFSPlan(engine="reference", batch_roots=False)
+    plan_ref = TraversalPlan(engine="reference", batch_roots=False)
 
     def ref_bfs(ev, degree):
         return compile_plan(plan_ref, PreparedGraph(ev=ev, degree=degree))

@@ -14,7 +14,7 @@ timing — a wrong tree must never post a number):
 
   * ``single``    — single-device batched δ-stepping;
   * ``2x2_min``   — vertex-sharded over the 2x2 mesh, ``hier_min``
-    two-phase hierarchical min exchange (§12 codec on the changed-set
+    two-phase hierarchical min exchange (two-phase OR on the changed-set
     delta leg);
   * ``2x2_flat``  — same mesh, flat one-phase min all-reduce (the
     wiring baseline ``hier_min`` must beat on real wire).

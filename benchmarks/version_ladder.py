@@ -10,7 +10,7 @@ Two views are reported per rung:
 ``BENCH_RUNGS`` (set by ``benchmarks/run.py --rungs``) filters the rung
 list so CI smoke can run one rung; the speedup summary rows appear only
 when both of their rungs ran.  ``json_payload()`` records each rung's
-:class:`repro.core.plan.BFSPlan` next to its TEPS.
+:class:`repro.core.plan.TraversalPlan` next to its TEPS.
 """
 from __future__ import annotations
 
@@ -22,8 +22,7 @@ from benchmarks.common import FAST, row, rung_filter
 from repro.core import Graph500Config, compile_plan
 from repro.core import run as run_g500
 
-RUNGS = ("reference-3.0.0", "th2", "k",
-         "pre-g500-legacy", "pre-g500", "pre-g500-batch")
+RUNGS = ("reference-3.0.0", "th2", "k", "pre-g500", "pre-g500-batch")
 
 _PAYLOAD: dict = {}
 
@@ -111,10 +110,4 @@ def run():
             "ladder/speedup_pre-g500_vs_k", 0.0,
             f"speedup={speedup:.2f}x;paper_reports=3.15x_at_512cn;"
             "note=single-CPU-container — see EXPERIMENTS.md ladder discussion"))
-    if "pre-g500" in teps and "pre-g500-legacy" in teps:
-        rows.append(row(
-            "ladder/speedup_resident_vs_seed_loop", 0.0,
-            f"speedup={teps['pre-g500'] / max(teps['pre-g500-legacy'], 1e-9):.2f}x;"
-            "note=bitmap-resident loop + chunked top-down vs the pre-resident "
-            "customized loop"))
     return rows

@@ -4,9 +4,9 @@
     PYTHONPATH=src python examples/distributed_bfs.py --inject
 
 Demonstrates the spec→plan→runner lifecycle (DESIGN.md §10): one
-scale-12 graph, five vertex-sharded exchange wirings (T3 monitor
-collectives over a (group, member) mesh, including the §12 wire-codec
-variants with a per-level wire-byte trace), and the composed
+scale-12 graph, the three vertex-sharded exchange wirings (T3 monitor
+collectives over a (group, member) mesh), both vertex partitions, and
+the composed
 ("root", "group", "member") 2x2x2 plan — the 8 search keys split over
 the root axis OUTSIDE the vertex-sharded SPMD program.  Every layout's
 parents are asserted bitwise-identical to the single-device bitmap
@@ -27,8 +27,8 @@ import numpy as np
 import jax
 
 from repro.core import (
-    BFSPlan, PreparedGraph, build_csr, build_heavy_core, compile_plan,
-    degree_reorder, edge_view, generate_edges,
+    PreparedGraph, TraversalPlan, build_csr, build_heavy_core,
+    compile_plan, degree_reorder, edge_view, generate_edges,
 )
 from repro.core.reference import reference_bfs
 from repro.core.reorder import relabel_edges
@@ -47,7 +47,7 @@ roots = np.arange(8, dtype=np.int32)
 print(f"graph: {V} vertices, {int(g.nnz)} directed edges")
 
 # single-device oracle: the bitmap-resident engine, all roots one program
-base = compile_plan(BFSPlan(layout=(), batch_roots=True), pg)
+base = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg)
 base_res = base.bfs(roots)
 base_parent = np.asarray(base_res.parent)
 _, l_ref = reference_bfs(np.asarray(g.row_offsets),
@@ -60,7 +60,7 @@ if "--inject" in sys.argv[1:]:
     # byte-identical.
     from repro.core import FaultSpec
 
-    plan = BFSPlan(layout=("group", "member"), mesh_shape=(2, 4))
+    plan = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 4))
 
     # 1. persistent exchange corruption: every per-level delta from every
     #    shard is zeroed from level 1 on — the traversal stalls after the
@@ -101,17 +101,13 @@ if "--inject" in sys.argv[1:]:
     print("INJECT OK")
     sys.exit(0)
 
-# layer 2: vertex-sharded (2, 4) mesh, all five exchange wirings —
-# including the DESIGN.md §12 wire codecs (hier_or_packed = density-
-# adaptive sparse/dense codec on the inter-group leg, hier_or_sieve =
-# visited-sieve then pack)
-for exchange in ("hier_or", "hier_gather", "flat",
-                 "hier_or_packed", "hier_or_sieve"):
-    plan = BFSPlan(layout=("group", "member"), mesh_shape=(2, 4),
-                   exchange=exchange)
+# layer 2: vertex-sharded (2, 4) mesh, all three exchange wirings
+for exchange in ("hier_or", "hier_gather", "flat"):
+    plan = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 4),
+                         exchange=exchange)
     res = compile_plan(plan, pg).bfs(roots)
     ok = np.array_equal(np.asarray(res.parent)[:, :V], base_parent)
-    print(f"vertex-sharded 2x4 exchange={exchange:14s}: "
+    print(f"vertex-sharded 2x4 exchange={exchange:11s}: "
           f"bitwise_identical={ok}")
     assert ok, exchange
 
@@ -123,8 +119,8 @@ for exchange in ("hier_or", "hier_gather", "flat",
 from repro.core.distributed_bfs import shard_edge_skew
 
 for partition in ("block", "word_cyclic"):
-    plan = BFSPlan(layout=("group", "member"), mesh_shape=(2, 4),
-                   partition=partition)
+    plan = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 4),
+                         partition=partition)
     compiled = compile_plan(plan, pg)
     skew = shard_edge_skew(compiled.graph.sharded)
     res = compiled.bfs(roots)
@@ -134,37 +130,9 @@ for partition in ("block", "word_cyclic"):
           f"edge_skew_max_over_mean={skew['max_over_mean']:.2f}")
     assert ok, partition
 
-# sieved + packed exchange with the per-level wire-byte trace: the
-# 4x2 acceptance mesh running hier_or_sieve, then the modeled raw /
-# post-sieve / post-codec bytes per level recovered from the level
-# array (DESIGN.md §12 — the SPMD program keeps static shapes, so the
-# volume win is modeled host-side, never paid on this container)
-from repro.core.distributed_bfs import modeled_wire_bytes
-
-plan = BFSPlan(layout=("group", "member"), mesh_shape=(4, 2),
-               exchange="hier_or_sieve")
-compiled = compile_plan(plan, pg)
-res = compiled.bfs(roots)
-ok = np.array_equal(np.asarray(res.parent)[:, :V], base_parent)
-print(f"vertex-sharded 4x2 exchange=hier_or_sieve: bitwise_identical={ok}")
-assert ok
-wb = modeled_wire_bytes(np.asarray(res.level)[0], n_devices=8,
-                        w_loc=compiled.graph.sharded.w_loc,
-                        group=4, member=2)
-print("per-level inter-group wire bytes (modeled, root 0):")
-print(f"  {'level':>5s} {'frontier':>8s} {'raw':>8s} "
-      f"{'post_sieve':>10s} {'post_codec':>10s}")
-for p in wb["per_level"]:
-    i = p["inter"]
-    print(f"  {p['level']:5d} {p['frontier']:8d} {i['raw']:8d} "
-          f"{i['post_sieve']:10d} {i['post_codec']:10d}")
-t = wb["totals"]
-print(f"  totals: raw={t['inter_raw']} post_codec={t['inter_post_codec']} "
-      f"({t['inter_raw'] / max(t['inter_post_codec'], 1):.1f}x smaller), "
-      f"intra raw={t['intra_raw']}")
-
 # layer 1 x layer 2 composed: 2x2x2 — roots split over their own axis
-plan = BFSPlan(layout=("root", "group", "member"), mesh_shape=(2, 2, 2))
+plan = TraversalPlan(layout=("root", "group", "member"),
+                     mesh_shape=(2, 2, 2))
 compiled = compile_plan(plan, pg)
 result = compiled.run(roots)
 ok = np.array_equal(result.parent, base_parent)

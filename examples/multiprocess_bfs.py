@@ -11,7 +11,7 @@ localhost TCP, and the inter-group leg of the T3 monitor collective
 crosses a process boundary.  Rank 0's payload carries the
 :class:`~repro.core.teps.Graph500Run` bookkeeping, the bitwise-parity
 verdict vs the single-device oracle, and the measured per-level
-exchange seconds next to the DESIGN.md §12 modeled wire bytes.
+exchange seconds.
 
 The same topology is also reachable through the pipeline config::
 
@@ -29,7 +29,7 @@ SCALE = 10
 print(f"launching 2 processes x 2 devices, scale {SCALE} "
       f"(rendezvous over localhost TCP)...")
 payload = launch(2, 2, scale=SCALE, n_roots=4, seed=1, reps=2,
-                 exchanges="hier_or,hier_or_packed", partitions="block")
+                 exchanges="hier_or,hier_gather", partitions="block")
 
 assert payload["parents_bitwise_identical"] is True
 print(f"workers: {payload['procs']} procs x {payload['devices_per_proc']} "
@@ -39,19 +39,13 @@ print(f"workers: {payload['procs']} procs x {payload['devices_per_proc']} "
 for name, rung in sorted(payload["rungs"].items()):
     assert rung["identical"], name
     assert rung["parent_sha256"] == payload["oracle_sha256"], name
-    wire = rung["wire_bytes"]["totals"]
     exch = rung["exchange_seconds"]
     print(f"\n{name}: parents bitwise-identical to the single-device "
           f"oracle, hmean {rung['harmonic_mean_teps']:.3g} TEPS")
-    print(f"  modeled inter-group wire: raw {wire['inter_raw']}B, "
-          f"post-codec {wire['inter_post_codec']}B")
     print(f"  measured exchange wall-clock over {exch['levels']} levels: "
           f"{exch['total_seconds']*1e3:.1f} ms")
     for lv in exch["per_level"]:
-        model = rung["wire_bytes"]["per_level"][lv["level"] - 1]
         print(f"    level {lv['level']}: frontier {lv['frontier']:>5} "
-              f"modeled {model['inter']['raw']:>7}B raw "
-              f"/ {model['inter']['post_codec']:>6}B codec "
               f"measured {lv['seconds']*1e3:7.2f} ms")
 
 print("\nOK: cross-process exchange measured, parity held on every rung")

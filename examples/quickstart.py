@@ -4,7 +4,9 @@
 """
 import jax.numpy as jnp
 
-from repro.core import BFSPlan, Graph500Config, compile_plan, run, validate
+from repro.core import (
+    Graph500Config, TraversalPlan, compile_plan, run, validate,
+)
 
 # 1. Reference configuration (no customizations) ---------------------------
 base = Graph500Config.ladder("reference-3.0.0", scale=10, n_roots=4)
@@ -25,7 +27,7 @@ print(f"heavy core      : K={built_p.core.k} vertices, "
       f"{int(built_p.core.core_nnz)} edges in the dense corner")
 
 # 3. Inspect one BFS run (the spec→plan→runner API, DESIGN.md §10) ---------
-plan = BFSPlan(engine="bitmap", layout=(), batch_roots=False)
+plan = TraversalPlan(engine="bitmap", layout=(), batch_roots=False)
 res = compile_plan(plan, built_p).bfs(0)
 lv = int(res.stats.levels)
 print(f"BFS from root 0 : {lv} levels, directions "

@@ -35,7 +35,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import faults
-from repro.kernels.ref import popcount_u32
 
 
 # ---------------------------------------------------------------------------
@@ -276,142 +275,6 @@ def hierarchical_pmin(x, group_axis: str, member_axis: str, *,
     shard = _min_reduce_scatter(x, member_axis)
     shard = _min_all_reduce(shard, group_axis, fault=fault, level=level,
                             device=device, root=root)
-    return lax.all_gather(shard, member_axis, axis=0, tiled=True)
-
-
-# ---------------------------------------------------------------------------
-# Density-adaptive wire codec for bitmap payloads (DESIGN.md §12).
-#
-# Lv et al.'s "Compression and Sieve" (arXiv:1208.5542) sends each level's
-# delta either as a raw bitmap or as a set-bit index list, whichever is
-# smaller for the level's density, after sieving out bits the destination
-# already knows.  Under jit every payload keeps its static shape (a
-# fixed-capacity int32 buffer the size of the raw words), so the byte
-# saving is *modeled* host-side (`core.distributed_bfs.modeled_wire_bytes`)
-# — but the sparse/dense decision genuinely runs per level per shard
-# inside the traversal loop via ``lax.cond``, mirroring the α/β switch.
-# ---------------------------------------------------------------------------
-
-def encode_delta(words: jax.Array, *, threshold=None):
-    """Density-adaptive encode of uint32 delta words: ``(mode, payload,
-    count)``.
-
-    ``mode`` is 1 (sparse) when ``popcount(words) <= threshold`` — the
-    payload's first ``count`` int32 slots then hold the set-bit indices
-    (``word*32 + bit``, strictly increasing) — else 0 (dense) with the
-    payload a bitcast of the raw words.  Capacity is ``len(words)``
-    slots, so ``threshold`` is clamped there and the sparse branch never
-    truncates: the codec is lossless for every threshold.  ``threshold
-    = None`` means full capacity (sparse whenever it fits).
-    """
-    if words.dtype != jnp.uint32:
-        raise TypeError(
-            f"encode_delta is for uint32 bitmap words, got {words.dtype}")
-    w = words.shape[0]
-    thr = w if threshold is None else min(int(threshold), w)
-    count = jnp.sum(popcount_u32(words)).astype(jnp.int32)
-
-    def enc_sparse(_):
-        bits = ((words[:, None] >> jnp.arange(32, dtype=jnp.uint32)[None, :])
-                & jnp.uint32(1)).reshape(-1).astype(bool)
-        slot = jnp.cumsum(bits.astype(jnp.int32)) - 1
-        target = jnp.where(bits, slot, w)   # count <= thr <= w: never drops
-        return jnp.zeros((w,), jnp.int32).at[target].set(
-            jnp.arange(w * 32, dtype=jnp.int32), mode="drop")
-
-    def enc_dense(_):
-        return lax.bitcast_convert_type(words, jnp.int32)
-
-    sparse = count <= jnp.int32(thr)
-    payload = lax.cond(sparse, enc_sparse, enc_dense, None)
-    return jnp.where(sparse, 1, 0).astype(jnp.int32), payload, count
-
-
-def decode_delta(mode: jax.Array, payload: jax.Array, count: jax.Array):
-    """Inverse of :func:`encode_delta` — exact round trip for well-formed
-    payloads (the sparse index list holds ``count`` distinct indices, so
-    the scatter-add of single bits IS the bitwise OR)."""
-    w = payload.shape[0]
-
-    def dec_sparse(_):
-        valid = jnp.arange(w, dtype=jnp.int32) < count
-        word_i = jnp.where(valid, payload // 32, w)
-        bit = jnp.where(valid,
-                        jnp.uint32(1) << (payload % 32).astype(jnp.uint32),
-                        jnp.uint32(0))
-        return jnp.zeros((w,), jnp.uint32).at[word_i].add(bit, mode="drop")
-
-    def dec_dense(_):
-        return lax.bitcast_convert_type(payload, jnp.uint32)
-
-    return lax.cond(mode == 1, dec_sparse, dec_dense, None)
-
-
-def _encoded_or_all_reduce(x, axis_name, *, threshold=None, fault=None,
-                           level=None, device=None, root=None):
-    """Bitwise-OR all-reduce whose per-device contribution round-trips
-    through the density-adaptive codec — the wire representation of the
-    inter-group leg.  Bit-exact vs :func:`_or_all_reduce` (the codec is
-    lossless); the modeled bytes are what shrink.
-
-    Fault sites (§13): ``codec`` corrupts this shard's outgoing
-    ``(mode, payload, count)`` wire triple *between* encode and decode —
-    a flipped payload slot, a truncated sparse count, or the wrong mode
-    header; ``inter_group`` drops every contribution but index 0's after
-    the decode fold (the dropped-forward mode, replicated).
-    """
-    n = lax.axis_size(axis_name)
-    mode, payload, count = encode_delta(x, threshold=threshold)
-    mode, payload, count = faults.corrupt_encoded(
-        fault, mode, payload, count, level=level, device=device, root=root)
-    hdr = jnp.stack([mode, count])
-    hdrs = lax.all_gather(hdr, axis_name, axis=0, tiled=False)
-    payloads = lax.all_gather(payload, axis_name, axis=0, tiled=False)
-    first = decode_delta(hdrs[0, 0], payloads[0], hdrs[0, 1])
-    out = first
-    for i in range(1, n):
-        out = out | decode_delta(hdrs[i, 0], payloads[i], hdrs[i, 1])
-    return faults.drop_peers(fault, out, first, level=level, device=device,
-                             root=root) if fault is not None else out
-
-
-def compressed_hierarchical_por(x, group_axis: str, member_axis: str, *,
-                                known=None, threshold=None, fault=None,
-                                level=None, device=None, root=None):
-    """:func:`hierarchical_por` with the visited sieve and the
-    density-adaptive codec on the *inter-group* leg — the lossless-integer
-    sibling of :func:`compressed_hierarchical_psum`'s bfloat16 cast
-    (bitmap words must never round-trip through a float dtype, so their
-    compression is the index-list codec instead).
-
-    ``known`` (optional, replicated, same width as ``x``) is the
-    destination's last-known visited words: the outgoing delta is ANDed
-    against ``~known`` before anything hits the wire, so
-    already-discovered vertices are sieved out (arXiv:1208.5542).  The
-    result equals ``hierarchical_por(x, ...) & ~known`` — identical to
-    the unsieved reduction whenever the payload is a true delta (disjoint
-    from ``known``), which the dst-owned BFS engine guarantees.  Applying
-    the sieve before the member reduce-scatter is equivalent to applying
-    it at the inter-group leg (AND distributes over OR and ``known`` is
-    replicated) and also thins the intra-group legs.
-    """
-    if not (jnp.issubdtype(x.dtype, jnp.integer) or x.dtype == jnp.bool_):
-        raise TypeError(f"compressed_hierarchical_por is for integer/bool "
-                        f"payloads, got {x.dtype}")
-    if known is not None:
-        x = x & ~known
-    m = lax.axis_size(member_axis)
-    if x.shape[0] % m != 0:
-        # fall back: OR within group first, then the encoded exchange
-        # across groups (still two-phase, still codec'd on the wire leg)
-        x = _or_all_reduce(x, member_axis)
-        return _encoded_or_all_reduce(x, group_axis, threshold=threshold,
-                                      fault=fault, level=level,
-                                      device=device, root=root)
-    shard = _or_reduce_scatter(x, member_axis)
-    shard = _encoded_or_all_reduce(shard, group_axis, threshold=threshold,
-                                   fault=fault, level=level, device=device,
-                                   root=root)
     return lax.all_gather(shard, member_axis, axis=0, tiled=True)
 
 

@@ -12,17 +12,13 @@ from repro.core.bfs_steps import (
     ChunkedEdgeView, EdgeView, chunk_edge_view, edge_view, with_edge_weights,
 )
 from repro.core.graph_build import WEIGHT_UNIT, edge_weights
-from repro.core.hybrid_bfs import (
-    BFSResult, bfs_batch, bfs_batch_sharded, hybrid_bfs,
-)
+from repro.core.hybrid_bfs import BFSResult
 from repro.core.faults import FAULT_CLASSES, FaultSpec
 from repro.core.validate import (
     CHECK_NAMES, SSSP_CHECK_NAMES, validate, validate_batch, validate_sssp,
     validate_sssp_batch,
 )
-from repro.core.teps import (
-    run_graph500, run_graph500_batched, run_graph500_sharded, traversed_edges,
-)
+from repro.core.teps import run_graph500, traversed_edges
 from repro.core.kernels import (
     KERNELS, KernelSpec, kernel_spec, rekernel_plan,
 )
@@ -30,8 +26,7 @@ from repro.core.sssp_steps import (
     SSSP_EXCHANGES, bucket_width, sssp_max_rounds, sssp_oracle,
 )
 from repro.core.plan import (
-    BFSPlan, CompiledBFS, Graph500Result, PreparedGraph, TraversalPlan,
-    compile_plan,
+    CompiledBFS, Graph500Result, PreparedGraph, TraversalPlan, compile_plan,
 )
 from repro.core.pipeline import Graph500Config, build, run
 
@@ -56,15 +51,14 @@ __all__ = [
     "unpack_bitmap",
     "ChunkedEdgeView", "EdgeView", "chunk_edge_view", "edge_view",
     "with_edge_weights", "WEIGHT_UNIT", "edge_weights",
-    "BFSResult", "bfs_batch", "bfs_batch_sharded", "hybrid_bfs",
+    "BFSResult",
     "FAULT_CLASSES", "FaultSpec",
     "CHECK_NAMES", "SSSP_CHECK_NAMES", "validate", "validate_batch",
     "validate_sssp", "validate_sssp_batch",
-    "run_graph500", "run_graph500_batched",
-    "run_graph500_sharded", "traversed_edges",
+    "run_graph500", "traversed_edges",
     "KERNELS", "KernelSpec", "kernel_spec", "rekernel_plan",
     "SSSP_EXCHANGES", "bucket_width", "sssp_max_rounds", "sssp_oracle",
-    "BFSPlan", "TraversalPlan", "CompiledBFS", "Graph500Result",
+    "TraversalPlan", "CompiledBFS", "Graph500Result",
     "PreparedGraph", "compile_plan",
     "TuneReport", "TuneResult", "enumerate_plans", "load_table",
     "save_tuned", "sweep", "tuned_plan",

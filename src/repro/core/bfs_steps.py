@@ -101,25 +101,6 @@ def relax_step(
     return new_parent, next_frontier
 
 
-def masked_relax_step(
-    ev: EdgeView,
-    parent: jax.Array,
-    frontier: jax.Array,
-    visited: jax.Array,
-    edge_mask: jax.Array,  # [E_pad] bool — restrict relaxation (tail edges)
-) -> tuple[jax.Array, jax.Array]:
-    """Relax only edges with ``edge_mask`` set (used to exclude the dense core)."""
-    v = ev.num_vertices
-    f_ext = jnp.concatenate([frontier, jnp.zeros((1,), bool)])
-    vis_ext = jnp.concatenate([visited, jnp.ones((1,), bool)])
-    active = ev.valid & edge_mask & f_ext[ev.src] & ~vis_ext[ev.dst]
-    cand = jnp.where(active, ev.src, v).astype(jnp.int32)
-    tgt = jnp.where(active, ev.dst, v)
-    new_parent = parent.at[tgt].min(cand)
-    next_frontier = (new_parent[:v] != v) & ~visited
-    return new_parent, next_frontier
-
-
 def frontier_edge_count(degree: jax.Array, frontier: jax.Array) -> jax.Array:
     """Edges incident to the frontier — the m_f quantity in the direction switch."""
     return jnp.sum(jnp.where(frontier, degree, 0))

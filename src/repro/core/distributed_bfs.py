@@ -39,21 +39,17 @@ Exercised three ways:
     the 256/512-chip production meshes (core/plan.py's
     ``vertex_sharded_program`` is the shared shard_map wiring).
 
-``make_dist_bfs`` is a deprecation shim over the plan API
-(``BFSPlan(layout=("group", "member"))`` — DESIGN.md §10); this module
-keeps the host-side partitioner (``shard_graph``) and result helpers.
+The engine runs through the plan API (``TraversalPlan(layout=("group",
+"member"))`` — DESIGN.md §10); this module keeps the host-side
+partitioner (``shard_graph``) and its owner-map helpers.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
-
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
 
 from repro.core.bfs_steps import DEFAULT_CHUNKS
-from repro.core.heavy import HeavyCore, padded_bitmap_words
-from repro.core.hybrid_bfs import MAX_LEVELS
+from repro.core.heavy import padded_bitmap_words
 from repro.util import pytree_dataclass
 
 PARTITIONS = ("block", "word_cyclic")
@@ -144,92 +140,6 @@ def shard_edge_skew(sg: ShardedGraph) -> dict:
     }
 
 
-def modeled_wire_bytes(level, *, n_devices: int, w_loc: int, group: int,
-                       member: int, partition: str = "block") -> dict:
-    """Host-side per-level wire-byte model of the ``hier_or``-family delta
-    exchange (DESIGN.md §12) from a completed traversal's ``level`` array.
-
-    The SPMD program never exports per-level payloads (static shapes —
-    the exchange cost is modeled, never paid on this container), but the
-    level array recovers them exactly: the delta exchanged at loop
-    iteration ``t`` is the set of vertices with ``level == t`` (the root's
-    level-0 bit is set at init and never exchanged).  Per level, per
-    (group, member-block) shard of the two-phase collective, three wire
-    tiers of the inter-group leg are modeled (bytes a device ships to
-    each of its G−1 peer groups, summed over all devices):
-
-      * ``raw``        — what ``hier_or`` ships: ``4·S_w`` per peer, with
-        ``S_w`` the member reduce-scatter block width (``W/M``, or the
-        full ``W`` on the non-dividing fallback path).
-      * ``post_sieve`` — nonzero words survive the visited sieve as
-        (index, value) pairs + a count header:
-        ``min(raw, 8·nnz_words + 4)``.
-      * ``post_codec`` — the density-adaptive index list of
-        ``comms.hierarchical.encode_delta``: ``min(raw, 4·popcount + 4)``.
-
-    ``intra.raw`` models the intra-group legs (member reduce-scatter +
-    delivery all-gather), which always ship raw words.  Returns a
-    JSON-ready dict: ``{"per_level": [...], "totals": {...}, ...}``.
-    """
-    import numpy as np
-
-    if partition not in PARTITIONS:
-        raise ValueError(
-            f"unknown partition {partition!r}; expected one of {PARTITIONS}")
-    g, m = int(group), int(member)
-    if g * m != n_devices:
-        raise ValueError(f"group*member = {g}*{m} != n_devices {n_devices}")
-    level = np.asarray(level).reshape(-1)
-    w_pad = n_devices * w_loc
-    word_ids = np.arange(w_pad)
-    owner = (word_ids % n_devices if partition == "word_cyclic"
-             else word_ids // w_loc)
-    owner_group = owner // m
-    # member reduce-scatter block width; the non-dividing fallback ships
-    # the full width from every member (comms.hierarchical fallback path)
-    divides = w_pad % m == 0
-    sw = w_pad // m if divides else w_pad
-    depth = int(level.max()) if level.size else 0
-    per_level = []
-    totals = {"inter_raw": 0, "inter_post_sieve": 0, "inter_post_codec": 0,
-              "intra_raw": 0}
-    for t in range(1, depth + 1):
-        verts = np.flatnonzero(level == t)
-        words = np.zeros(w_pad, np.uint32)
-        np.bitwise_or.at(words, verts // 32,
-                         np.uint32(1) << (verts % 32).astype(np.uint32))
-        raw_blk = 4 * sw
-        inter = {"raw": 0, "post_sieve": 0, "post_codec": 0}
-        for gi in range(g):
-            gwords = np.where(owner_group == gi, words, np.uint32(0))
-            for b in range(m):
-                blk = gwords[b * sw:(b + 1) * sw] if divides else gwords
-                nnz_words = int(np.count_nonzero(blk))
-                pop = int(np.unpackbits(blk.view(np.uint8)).sum())
-                inter["raw"] += (g - 1) * raw_blk
-                inter["post_sieve"] += (g - 1) * min(raw_blk,
-                                                     8 * nnz_words + 4)
-                inter["post_codec"] += (g - 1) * min(raw_blk, 4 * pop + 4)
-        # intra-group legs (raw words, summed over all G*M devices):
-        # reduce-scatter sends (m-1) blocks, delivery all-gather sends the
-        # owned block to (m-1) members; the fallback is one member
-        # all-reduce of the full width (no delivery leg).
-        intra_dev = (2 * 4 * sw * (m - 1) if divides
-                     else 4 * w_pad * (m - 1))
-        intra = {"raw": g * m * intra_dev}
-        per_level.append({"level": t, "frontier": int(verts.size),
-                          "inter": inter, "intra": intra})
-        totals["inter_raw"] += inter["raw"]
-        totals["inter_post_sieve"] += inter["post_sieve"]
-        totals["inter_post_codec"] += inter["post_codec"]
-        totals["intra_raw"] += intra["raw"]
-    return {
-        "partition": partition, "group": g, "member": m, "w_loc": w_loc,
-        "scatter_words": sw, "levels": depth,
-        "per_level": per_level, "totals": totals,
-    }
-
-
 def shard_graph(src, dst, valid, num_vertices: int, n_devices: int,
                 n_chunks: int = DEFAULT_CHUNKS,
                 partition: str = "block", weight=None) -> ShardedGraph:
@@ -301,73 +211,3 @@ def shard_graph(src, dst, valid, num_vertices: int, n_devices: int,
         partition=partition,
         weight=None if wt is None else jnp.asarray(wt),
     )
-
-
-class DistBFSResult(NamedTuple):
-    parent: jax.Array      # [V_pad] int32 global parent id (-1 unvisited)
-    level: jax.Array       # [V_pad] int32 (-1 unvisited)
-    levels_run: jax.Array  # [] int32
-
-
-def make_dist_bfs(
-    mesh: Mesh,
-    g: ShardedGraph,
-    *,
-    group_axis: str = "group",
-    member_axis: str = "member",
-    hierarchical: bool = True,
-    exchange: str | None = None,
-    core: HeavyCore | None = None,
-    alpha: float = 14.0,
-    beta: float = 24.0,
-    max_levels: int = MAX_LEVELS,
-    batched: bool = False,
-):
-    """DEPRECATED: vertex-sharded BFS driver — shim over the plan API.
-
-    Equivalent plan: ``BFSPlan(layout=("group", "member"),
-    exchange=exchange, batch_roots=batched)`` compiled against ``mesh``
-    with ``built.sharded = g`` (the shard_map wiring now lives in
-    ``core/plan.py:vertex_sharded_program`` — the one copy shared with
-    the dry-run cost cells).  Returns ``fn(root) -> DistBFSResult`` (or
-    ``fn(roots[R])`` with a leading roots axis when ``batched=True``),
-    bitwise-identical to the plan run.
-
-    ``exchange`` selects the delta-combination wiring
-    (``hier_or`` | ``hier_gather`` | ``flat``); when None it follows the
-    ``hierarchical`` flag (kept for the ablation benchmark and API
-    compatibility with the retired engine).
-    """
-    from repro.core import plan as plan_api
-
-    plan_api.warn_deprecated(
-        "make_dist_bfs",
-        'BFSPlan(layout=("group", "member"), exchange=..., '
-        'batch_roots=...)')
-    if exchange is None:
-        exchange = "hier_or" if hierarchical else "flat"
-    p = plan_api.BFSPlan(engine="bitmap", layout=("group", "member"),
-                         exchange=exchange, alpha=alpha, beta=beta,
-                         max_levels=max_levels, batch_roots=batched,
-                         partition=g.partition)
-    compiled = plan_api.compile_plan(
-        p, plan_api.PreparedGraph(core=core, sharded=g),
-        mesh=mesh, axis_names=(group_axis, member_axis))
-
-    def run(root: jax.Array) -> DistBFSResult:
-        res = compiled.bfs(root)
-        return DistBFSResult(res.parent, res.level, jnp.max(res.levels))
-
-    return run
-
-
-def gather_result(res: DistBFSResult, g: ShardedGraph):
-    """Global (parent, level) in vertex order.
-
-    A no-op for BOTH partitions: the plan runner already applies
-    :func:`partition_permutation` at traversal exit (word-cyclic), and
-    block shard outputs concatenate directly into global vertex order.
-    """
-    import numpy as np
-
-    return np.asarray(res.parent, np.int64), np.asarray(res.level, np.int64)

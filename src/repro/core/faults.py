@@ -1,8 +1,8 @@
 """Deterministic fault injection for the Graph500 engines (DESIGN.md §13).
 
 At 512 nodes the paper's two-phase monitor exchange is exactly where
-silent corruption — a dropped inter-group forward, a mangled codec
-payload, a stale sieve mask — would go undetected: the traversal
+silent corruption — a dropped inter-group forward, a flipped delta
+word — would go undetected: the traversal
 finishes, the TEPS number looks plausible, and only spec validation
 (step 4) can tell the tree is wrong.  This module makes those failure
 modes *injectable on purpose*, deterministically, inside the real jitted
@@ -29,24 +29,12 @@ values inside the loop:
                         the round loop).  Kinds: ``self`` (newly-found
                         vertices become their own parent), ``offset``
                         (parent ids bumped +1 mod V).
-  site ``codec``      — the encoded wire representation between
-                        ``comms.hierarchical.encode_delta`` and
-                        ``decode_delta`` on the inter-group leg
-                        (``hier_or_packed`` / ``hier_or_sieve`` only).
-                        Kinds: ``payload_flip`` (XOR a seed-derived mask
-                        into one payload slot), ``trunc_count`` (halve
-                        the sparse count header), ``wrong_mode`` (flip
-                        the sparse/dense mode header).
-  site ``inter_group`` — the inter-group OR leg of ``hierarchical_por``
-                        / ``compressed_hierarchical_por``: every
-                        receiver keeps only group 0's contribution (the
-                        other groups' monitor forwards are dropped on
-                        the floor — replicated, so the SPMD loop stays
+  site ``inter_group`` — the inter-group leg of ``hierarchical_por``
+                        (and of the SSSP min exchanges): every receiver
+                        keeps only group 0's contribution (the other
+                        groups' monitor forwards are dropped on the
+                        floor — replicated, so the SPMD loop stays
                         uniform).  Kind: ``drop``.
-  site ``sieve``      — the ``known_bm`` mask of ``hier_or_sieve``
-                        marked all-ones (a maximally stale sieve: every
-                        outgoing delta bit is wrongly "already known"
-                        and sieved off the wire).  Kind: ``stale``.
 
 All helpers below are no-ops returning their input unchanged when the
 fault is ``None`` or targets a different site — the hooks cost nothing
@@ -59,14 +47,12 @@ from dataclasses import dataclass
 
 import jax.numpy as jnp
 
-FAULT_SITES = ("exchange", "parent", "codec", "inter_group", "sieve")
+FAULT_SITES = ("exchange", "parent", "inter_group")
 
 FAULT_KINDS = {
     "exchange": ("zero", "flip"),
     "parent": ("self", "offset"),
-    "codec": ("payload_flip", "trunc_count", "wrong_mode"),
     "inter_group": ("drop",),
-    "sieve": ("stale",),
 }
 
 #: The fault classes of the detection matrix (DESIGN.md §13): one
@@ -83,7 +69,7 @@ class FaultSpec:
     values (``-1`` matches everything); ``persistent=True`` widens the
     level predicate from ``lvl == level`` to ``lvl >= level`` (a fault
     that keeps firing — the quarantine-path demonstrator).  ``word`` /
-    ``bit`` / ``seed`` parameterize the corruption payload.
+    ``bit`` parameterize the ``exchange``/``flip`` corruption.
     """
 
     site: str
@@ -92,9 +78,8 @@ class FaultSpec:
     persistent: bool = False  # fire at every level >= `level`
     device: int = -1       # flat shard index (-1 = every device)
     root: int = -1         # global root id (-1 = every root)
-    word: int = 0          # target word / payload slot
+    word: int = 0          # target word of the delta
     bit: int = 0           # target bit within the word
-    seed: int = 0          # mixed into the payload_flip mask
 
     def __post_init__(self):
         if self.site not in FAULT_SITES:
@@ -129,12 +114,6 @@ def fires(fault, site: str, *, level=None, device=None, root=None):
     if root is not None and fault.root >= 0:
         act = act & (jnp.asarray(root, jnp.int32) == fault.root)
     return act
-
-
-def _flip_mask(fault) -> jnp.ndarray:
-    """Seed-derived 32-bit corruption mask (never zero)."""
-    m = ((fault.seed * 0x9E3779B1) ^ 0x5A5A5A5A) & 0xFFFFFFFF
-    return jnp.uint32(m or 0x5A5A5A5A)
 
 
 def corrupt_delta(fault, words, *, level, device=None, root=None):
@@ -177,24 +156,6 @@ def corrupt_parent(fault, parent, newly, self_ids, sentinel, *, level,
     return jnp.where(act & newly, wrong, parent)
 
 
-def corrupt_encoded(fault, mode, payload, count, *, level,
-                    device=None, root=None):
-    """Site ``codec``: corrupt one shard's (mode, payload, count) wire
-    triple between encode and decode."""
-    act = fires(fault, "codec", level=level, device=device, root=root)
-    if act is None:
-        return mode, payload, count
-    if fault.kind == "payload_flip":
-        w = fault.word % payload.shape[0]
-        bad = payload.at[w].set(payload[w]
-                                ^ _flip_mask(fault).astype(jnp.int32))
-        return mode, jnp.where(act, bad, payload), count
-    if fault.kind == "trunc_count":
-        return mode, payload, jnp.where(act, count // 2, count)
-    # wrong_mode: sparse <-> dense
-    return jnp.where(act, 1 - mode, mode), payload, count
-
-
 def drop_peers(fault, combined, first_leg, *, level, device=None, root=None):
     """Site ``inter_group``: the OR-combined inter-group result loses
     every contribution but group 0's (``first_leg`` — identical on every
@@ -203,13 +164,3 @@ def drop_peers(fault, combined, first_leg, *, level, device=None, root=None):
     if act is None:
         return combined
     return jnp.where(act, first_leg, combined)
-
-
-def corrupt_known(fault, known, *, level, device=None, root=None):
-    """Site ``sieve``: a maximally stale ``known_bm`` (all bits claimed
-    already-visited, so the sieve wrongly strips the whole delta)."""
-    act = fires(fault, "sieve", level=level, device=device, root=root)
-    if act is None:
-        return known
-    return jnp.where(act, jnp.full_like(known, jnp.uint32(0xFFFFFFFF)),
-                     known)

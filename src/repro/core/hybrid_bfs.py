@@ -1,4 +1,5 @@
-"""Direction-optimizing hybrid BFS (paper §2.1) with selectable engines.
+"""Direction-optimizing hybrid BFS (paper §2.1): the reference and bitmap
+engines.
 
 Switching policy — paper eq. (1)/(2), Fig. 1::
 
@@ -11,12 +12,8 @@ Switching policy — paper eq. (1)/(2), Fig. 1::
 Engines (the Fig. 18 ladder, DESIGN.md §3):
 
   * ``reference`` — pure-jnp edge-parallel relaxation both directions
-    over boolean frontier/visited arrays (the reference-3.0.0 rung).
-  * ``legacy``    — the first customized port: bottom-up levels run the
-    dense heavy-core Pallas kernel, but the frontier lives as ``bool [V]``
-    and is re-packed into a bitmap every bottom-up level, and top-down
-    scans all padded edges regardless of frontier size.  Kept as the
-    measured "before" rung for BENCH_bfs.json.
+    over boolean frontier/visited arrays (the reference-3.0.0 rung and
+    the test oracle).
   * ``bitmap``    — the bitmap-resident Pre-G500 engine (T1 + T2):
     ``frontier`` and ``visited`` live as packed ``uint32 [W]`` across the
     whole ``lax.while_loop`` (bits set once at init, never re-packed
@@ -33,9 +30,9 @@ Engines (the Fig. 18 ladder, DESIGN.md §3):
 
 Everything is a single ``lax.while_loop`` under jit; per-level statistics
 (direction, frontier size, scanned edges, scanned chunks) land in
-fixed-size arrays (``BFSStats``).  ``bfs_batch`` vmaps the bitmap engine
+fixed-size arrays (``BFSStats``).  ``_run_batch`` vmaps the bitmap engine
 over the 64 Graph500 search keys so the whole benchmark is one jitted
-program (see ``core/teps.py``).
+program (a ``batch_roots`` plan, ``core/plan.py``).
 
 The bitmap engines name their phases with ``jax.named_scope``, so a
 device trace charges every op to one: ``bfs.init`` (state set-up),
@@ -59,21 +56,14 @@ import jax.numpy as jnp
 
 from repro.core import faults
 from repro.core.bfs_steps import (
-    DEFAULT_CHUNKS,
     ChunkedEdgeView,
     EdgeView,
     chunk_frontier_mask,
     chunk_range_mask,
     frontier_edge_count,
-    masked_relax_step,
     relax_step,
 )
-from repro.core.heavy import (
-    HeavyCore,
-    pack_bitmap,
-    padded_bitmap_words,
-    testbit,
-)
+from repro.core.heavy import HeavyCore, padded_bitmap_words, testbit
 from repro.kernels import ops as kops
 from repro.kernels.bitmap_ops import WORDS_PER_TILE
 from repro.kernels.ref import BIG, core_spmv_ref, popcount_u32
@@ -81,7 +71,7 @@ from repro.kernels.ref import BIG, core_spmv_ref, popcount_u32
 MAX_LEVELS = 64
 TOP_DOWN, BOTTOM_UP = jnp.int32(0), jnp.int32(1)
 
-ENGINES = ("reference", "legacy", "bitmap")
+ENGINES = ("reference", "bitmap")
 
 #: All in-loop sentinel bits passing (BFSStats.sentinel, DESIGN.md §13).
 SENTINEL_OK = 7
@@ -91,8 +81,9 @@ def _switch_direction(direction, in_count, vis_count, n_active,
                       alpha: float, beta: float):
     """Paper eq. (1)/(2) hybrid switch — the ONE copy of the formula.
 
-    Shared by the legacy, bitmap-resident and vertex-sharded level loops
-    so the engines stay bitwise-locked if the heuristic is ever tuned.
+    Shared by the reference, bitmap-resident and vertex-sharded level
+    loops so the engines stay bitwise-locked if the heuristic is ever
+    tuned.
     """
     thrv1 = ((n_active - vis_count).astype(jnp.float32)
              / alpha).astype(jnp.int32)
@@ -119,8 +110,7 @@ class BFSStats(NamedTuple):
     # unused levels, else bit0 = exchange conservation (next-frontier
     # popcount == Σ shard delta popcounts), bit1 = frontier ∩ visited = ∅,
     # bit2 = level within bound — a healthy level reads 7.  None for the
-    # legacy engines (trailing default keeps their positional
-    # constructions valid).
+    # reference engine, which carries no sentinels.
     sentinel: jax.Array | None = None
 
 
@@ -131,11 +121,10 @@ class BFSResult(NamedTuple):
 
 
 # ---------------------------------------------------------------------------
-# Legacy engines: boolean frontier state (reference + the pre-resident
-# customized loop, kept as the measured baseline).
+# Reference engine: boolean frontier state, every edge every level.
 # ---------------------------------------------------------------------------
 
-class _State(NamedTuple):
+class _RefState(NamedTuple):
     parent_ext: jax.Array
     frontier: jax.Array
     visited: jax.Array
@@ -147,94 +136,53 @@ class _State(NamedTuple):
     stats_se: jax.Array
 
 
-def _core_bottom_up_legacy(core: HeavyCore, frontier, visited, parent_ext, v):
-    """Dense-core kernel step with the per-level bool->bitmap round trip."""
-    k = core.k
-    if k > v:  # tiny graph: core padding exceeds |V|
-        frontier_k = jnp.pad(frontier, (0, k - v))
-        visited_k = jnp.pad(visited, (0, k - v), constant_values=True)
-    else:
-        frontier_k, visited_k = frontier[:k], visited[:k]
-    f_bm = pack_bitmap(frontier_k, k // 32)
-    cand = kops.core_spmv(core.a_core, f_bm)          # int32 [K]
-    rows = jnp.arange(k, dtype=jnp.int32)
-    won = (cand < BIG) & ~visited_k
-    tgt = jnp.where(won, rows, v)
-    return parent_ext.at[tgt].min(jnp.where(won, cand, v).astype(jnp.int32))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("engine", "alpha", "beta", "use_core", "max_levels"),
-)
-def _run_legacy(
+@functools.partial(jax.jit, static_argnames=("alpha", "beta", "max_levels"))
+def _run_reference(
     ev: EdgeView,
     degree: jax.Array,
     n_active: jax.Array,
     root: jax.Array,
-    core: HeavyCore | None,
     *,
-    engine: str,
     alpha: float,
     beta: float,
-    use_core: bool,
     max_levels: int,
 ) -> BFSResult:
+    """The reference-3.0.0 rung: one edge-parallel relax over every edge
+    per level.  The direction switch only labels the level in the stats
+    (and picks the work estimate it reports); both directions relax the
+    same edges."""
     v = ev.num_vertices
     parent_ext = jnp.full((v + 1,), v, jnp.int32).at[root].set(root)
     frontier = jnp.zeros((v,), bool).at[root].set(True)
-    visited = frontier
     level = jnp.full((v,), -1, jnp.int32).at[root].set(0)
 
-    if use_core:
-        core_edge = (ev.src < core.k) & (ev.dst < core.k)
-        tail_mask = ~core_edge
-    else:
-        tail_mask = None
-
-    def cond(s: _State):
+    def cond(s: _RefState):
         return jnp.any(s.frontier) & (s.lvl < max_levels)
 
-    def body(s: _State):
+    def body(s: _RefState):
         in_count = jnp.sum(s.frontier).astype(jnp.int32)
         vis_count = jnp.sum(s.visited).astype(jnp.int32)
         direction = _switch_direction(
             s.direction, in_count, vis_count, n_active, alpha, beta)
-
-        if engine == "reference" or not use_core:
-            new_parent, nxt = relax_step(ev, s.parent_ext, s.frontier, s.visited)
-        else:
-            def bu(_):
-                p1 = _core_bottom_up_legacy(core, s.frontier, s.visited, s.parent_ext, v)
-                p2, _ = masked_relax_step(ev, p1, s.frontier, s.visited, tail_mask)
-                return p2
-
-            def td(_):
-                p, _ = relax_step(ev, s.parent_ext, s.frontier, s.visited)
-                return p
-
-            new_parent = jax.lax.cond(direction == BOTTOM_UP, bu, td, None)
-            nxt = (new_parent[:v] != v) & ~s.visited
+        new_parent, nxt = relax_step(ev, s.parent_ext, s.frontier, s.visited)
 
         # scanned-edge estimate: TD scans frontier adjacency; BU scans
-        # unvisited adjacency (vectorized engines scan all, we report the
+        # unvisited adjacency (the relax scans all; we report the
         # algorithmic work the direction choice implies — paper Fig. 17).
         m_f = frontier_edge_count(degree, s.frontier)
         m_u = jnp.sum(jnp.where(s.visited, 0, degree))
         scanned = jnp.where(direction == TOP_DOWN, m_f, m_u).astype(jnp.int32)
 
-        visited = s.visited | nxt
-        new_level = jnp.where(nxt, s.lvl + 1, s.level)
-        stats_dir = s.stats_dir.at[s.lvl].set(direction)
-        stats_fs = s.stats_fs.at[s.lvl].set(in_count)
-        stats_se = s.stats_se.at[s.lvl].set(scanned)
-        return _State(
-            new_parent, nxt, visited, new_level, s.lvl + 1, direction,
-            stats_dir, stats_fs, stats_se,
+        return _RefState(
+            new_parent, nxt, s.visited | nxt,
+            jnp.where(nxt, s.lvl + 1, s.level), s.lvl + 1, direction,
+            s.stats_dir.at[s.lvl].set(direction),
+            s.stats_fs.at[s.lvl].set(in_count),
+            s.stats_se.at[s.lvl].set(scanned),
         )
 
-    init = _State(
-        parent_ext, frontier, visited, level,
+    init = _RefState(
+        parent_ext, frontier, frontier, level,
         jnp.int32(0), TOP_DOWN,
         jnp.full((max_levels,), -1, jnp.int32),
         jnp.zeros((max_levels,), jnp.int32),
@@ -658,116 +606,11 @@ def _run_batch(chunks, degree, n_active, roots, core, *,
     )(roots)
 
 
-def hybrid_bfs(
-    ev: EdgeView,
-    degree: jax.Array,
-    root: int | jax.Array,
-    *,
-    core: HeavyCore | None = None,
-    engine: str = "reference",
-    alpha: float = 14.0,
-    beta: float = 24.0,
-    max_levels: int = MAX_LEVELS,
-    chunks: ChunkedEdgeView | None = None,
-    n_chunks: int = DEFAULT_CHUNKS,
-) -> BFSResult:
-    """DEPRECATED: one hybrid BFS from ``root`` — shim over the plan API.
-
-    Equivalent plan: ``BFSPlan(engine=engine, layout=(),
-    batch_roots=False)``; results are bitwise-identical (the shim routes
-    through :func:`repro.core.plan.compile_plan`, which runs the same
-    jitted engine).  See DESIGN.md §10 for the migration table.
-    """
-    from repro.core import plan as plan_api
-
-    plan_api.warn_deprecated(
-        "hybrid_bfs", "BFSPlan(engine=..., layout=(), batch_roots=False)")
-    p = plan_api.BFSPlan(engine=engine, layout=(), batch_roots=False,
-                         alpha=alpha, beta=beta, max_levels=max_levels,
-                         n_chunks=n_chunks)
-    compiled = plan_api.compile_plan(
-        p, plan_api.PreparedGraph(ev=ev, degree=degree, core=core,
-                                  chunks=chunks))
-    return compiled.bfs(root)
-
-
-def bfs_batch(
-    ev: EdgeView,
-    degree: jax.Array,
-    roots,
-    *,
-    core: HeavyCore | None = None,
-    alpha: float = 14.0,
-    beta: float = 24.0,
-    max_levels: int = MAX_LEVELS,
-    chunks: ChunkedEdgeView | None = None,
-    n_chunks: int = DEFAULT_CHUNKS,
-) -> BFSResult:
-    """DEPRECATED: batched bitmap-engine BFS — shim over the plan API.
-
-    Equivalent plan: ``BFSPlan(layout=(), batch_roots=True)`` (one jitted
-    program for all roots; under vmap ``lax.cond`` lowers to ``select``
-    so per-root chunk skipping becomes masking — see DESIGN.md §8).
-    Returns a :class:`BFSResult` whose leaves carry a leading roots axis,
-    bitwise-identical to the plan run.
-    """
-    from repro.core import plan as plan_api
-
-    plan_api.warn_deprecated(
-        "bfs_batch", "BFSPlan(layout=(), batch_roots=True)")
-    p = plan_api.BFSPlan(engine="bitmap", layout=(), batch_roots=True,
-                         alpha=alpha, beta=beta, max_levels=max_levels,
-                         n_chunks=n_chunks)
-    compiled = plan_api.compile_plan(
-        p, plan_api.PreparedGraph(ev=ev, degree=degree, core=core,
-                                  chunks=chunks))
-    return compiled.bfs(roots)
-
-
 # ---------------------------------------------------------------------------
-# Layer 1 — root-parallel mesh sharding (DESIGN.md §9).
-#
-# The shard_map wiring lives in core/plan.py (`_root_parallel_fn`) — the
-# plan compiler owns the one copy of every mesh program.  The entry point
-# below is the legacy shim.
+# Layer 1 — root-parallel mesh sharding (DESIGN.md §9) has no engine code
+# of its own: core/plan.py (`_root_parallel_fn`) shard_maps the batched
+# bitmap engine over a ("root",) mesh.
 # ---------------------------------------------------------------------------
-
-def bfs_batch_sharded(
-    ev: EdgeView,
-    degree: jax.Array,
-    roots,
-    *,
-    mesh,
-    root_axis: str = "root",
-    core: HeavyCore | None = None,
-    alpha: float = 14.0,
-    beta: float = 24.0,
-    max_levels: int = MAX_LEVELS,
-    chunks: ChunkedEdgeView | None = None,
-    n_chunks: int = DEFAULT_CHUNKS,
-) -> BFSResult:
-    """DEPRECATED: root-parallel batch — shim over the plan API.
-
-    Equivalent plan: ``BFSPlan(layout=("root",))`` compiled against
-    ``mesh``.  Splits ``roots`` across ``mesh``'s ``root_axis`` with the
-    graph replicated — per-root outputs are bitwise-identical to the
-    single-device batch (no collective appears anywhere in the lowering);
-    ``roots`` is padded with ``roots[0]`` up to a multiple of the axis
-    size and the padding is sliced off the result.
-    """
-    from repro.core import plan as plan_api
-
-    plan_api.warn_deprecated(
-        "bfs_batch_sharded", 'BFSPlan(layout=("root",))')
-    p = plan_api.BFSPlan(engine="bitmap", layout=("root",),
-                         batch_roots=True, alpha=alpha, beta=beta,
-                         max_levels=max_levels, n_chunks=n_chunks)
-    compiled = plan_api.compile_plan(
-        p, plan_api.PreparedGraph(ev=ev, degree=degree, core=core,
-                                  chunks=chunks),
-        mesh=mesh, axis_names=(root_axis,))
-    return compiled.bfs(roots)
-
 
 # ---------------------------------------------------------------------------
 # Layer 2 — vertex-sharded resident bitmaps (DESIGN.md §9, paper T3).
@@ -792,8 +635,7 @@ def bfs_batch_sharded(
 # V/8 bytes per level per device, like the paper's bitmap exchange.
 # ---------------------------------------------------------------------------
 
-SHARD_EXCHANGES = ("hier_or", "hier_gather", "flat", "hier_or_packed",
-                   "hier_or_sieve")
+SHARD_EXCHANGES = ("hier_or", "hier_gather", "flat")
 
 
 def _axis_names_tuple(name) -> tuple:
@@ -816,7 +658,7 @@ def _shard_index(group_axis, member_axis):
 
 def _exchange_delta(delta_loc, dev, w_loc, n_dev, *, exchange,
                     group_axis, member_axis, partition="block",
-                    known_bm=None, fault=None, level=None, root=None):
+                    fault=None, level=None, root=None):
     """Combine per-shard delta words into the full next-frontier bitmap.
 
     Delta bits live only in the owner's words (dst-owned edges find owned
@@ -826,7 +668,7 @@ def _exchange_delta(delta_loc, dev, w_loc, n_dev, *, exchange,
     ``j`` is global word ``d*W_loc + j`` — exactly the device-major block
     order the gather collectives emit; under ``word_cyclic`` it is global
     word ``d + j*P``, so the OR-scatter is strided and the gathered
-    device-major blocks transpose into word order.  Five wirings, all
+    device-major blocks transpose into word order.  Three wirings, all
     bit-identical:
 
       * ``hier_or``     — scatter the owned words into a zero full-width
@@ -837,32 +679,19 @@ def _exchange_delta(delta_loc, dev, w_loc, n_dev, *, exchange,
       * ``hier_gather`` — two-phase hierarchical all-gather of the blocks
         (1/M inter-group bytes; exploits disjointness).
       * ``flat``        — single-phase all-gather (the ablation baseline).
-      * ``hier_or_packed`` — ``hier_or`` with the density-adaptive wire
-        codec on the inter-group leg (DESIGN.md §12): each level each
-        shard ships a sparse set-bit index list when the delta popcount
-        is below threshold, raw words otherwise, selected in-loop by
-        ``lax.cond``.
-      * ``hier_or_sieve``  — sieve-then-pack: the outgoing delta is ANDed
-        against ``known_bm`` (the destination's last-known visited words,
-        replicated — arXiv:1208.5542's visited sieve) before the codec'd
-        inter-group leg.  Dst-owned deltas are already disjoint from the
-        visited set, so the sieve removes nothing here — it is carried
-        for the paper-structure and stays correct (and starts paying)
-        if a future edge partition produces overlapping deltas.
     """
     from repro.comms.hierarchical import (
-        compressed_hierarchical_por,
         hierarchical_all_gather,
         hierarchical_por,
     )
 
     # Fault site "exchange" (§13): the outgoing per-level delta words —
-    # shared by every wiring, upstream of scatter/gather/codec.
+    # shared by every wiring, upstream of scatter/gather.
     delta_loc = faults.corrupt_delta(fault, delta_loc, level=level,
                                      device=dev, root=root)
 
     axes = _axis_names_tuple(group_axis) + _axis_names_tuple(member_axis)
-    if exchange in ("hier_or", "hier_or_packed", "hier_or_sieve"):
+    if exchange == "hier_or":
         if partition == "word_cyclic":
             # global word j*P + d <-> matrix slot [j, d]: placing the
             # owned words in column `dev` of a [W_loc, P] zero matrix is
@@ -875,20 +704,9 @@ def _exchange_delta(delta_loc, dev, w_loc, n_dev, *, exchange,
             full = jnp.zeros((n_dev * w_loc,), jnp.uint32)
             full = jax.lax.dynamic_update_slice(full, delta_loc,
                                                 (dev * w_loc,))
-        if exchange == "hier_or":
-            return hierarchical_por(full, group_axis, member_axis,
-                                    fault=fault, level=level, device=dev,
-                                    root=root)
-        known = known_bm if exchange == "hier_or_sieve" else None
-        if known is not None:
-            # Fault site "sieve": a stale known_bm wrongly strips delta
-            # bits off the wire before the codec'd inter-group leg.
-            known = faults.corrupt_known(fault, known, level=level,
-                                         device=dev, root=root)
-        return compressed_hierarchical_por(full, group_axis, member_axis,
-                                           known=known, fault=fault,
-                                           level=level, device=dev,
-                                           root=root)
+        return hierarchical_por(full, group_axis, member_axis,
+                                fault=fault, level=level, device=dev,
+                                root=root)
     if exchange == "hier_gather":
         out = hierarchical_all_gather(delta_loc, group_axis, member_axis)
     elif exchange == "flat":
@@ -908,10 +726,6 @@ class _ShardState(NamedTuple):
     level_loc: jax.Array     # [V_loc] int32
     frontier_bm: jax.Array   # [W] uint32 — full width, replicated value
     visited_loc: jax.Array   # [W_loc] uint32 — resident, owned words only
-    known_bm: jax.Array      # [W] uint32 — full-width visited-so-far union
-                             # (the sieve mask of the hier_or_sieve
-                             # exchange: every shard's last-known view of
-                             # the global visited words)
     in_count: jax.Array      # [] int32 — global popcount(frontier)
     vis_count: jax.Array     # [] int32 — global
     m_f: jax.Array           # [] int32 — global frontier degree sum
@@ -1131,8 +945,7 @@ def _run_bitmap_sharded(
             next_bm = _exchange_delta(
                 delta_loc, dev, w_loc, n_dev, exchange=exchange,
                 group_axis=group_axis, member_axis=member_axis,
-                partition=partition, known_bm=s.known_bm,
-                fault=fault, level=s.lvl, root=root)
+                partition=partition, fault=fault, level=s.lvl, root=root)
         with jax.named_scope("bfs.epilogue"):
             in_count = jnp.sum(popcount_u32(next_bm)).astype(jnp.int32)
 
@@ -1140,8 +953,8 @@ def _run_bitmap_sharded(
             # next frontier must carry exactly the bits the shards packed —
             # owner words are disjoint, so popcounts add), frontier ∩ visited
             # = ∅ over the owned slice, level bound.  A corrupted exchange
-            # (dropped leg, mangled codec, stale sieve, flipped word) breaks
-            # one of the first two the moment it fires.
+            # (dropped leg, flipped word) breaks one of the first two the
+            # moment it fires.
             delta_sum = jax.lax.psum(
                 jnp.sum(popcount_u32(delta_loc)).astype(jnp.int32), axes)
             if cyclic:
@@ -1176,7 +989,6 @@ def _run_bitmap_sharded(
 
             nxt = _ShardState(
                 new_parent, new_level, next_bm, new_visited_loc,
-                s.known_bm | next_bm,
                 in_count, s.vis_count + in_count,
                 m_next, s.deg_vis + m_next,
                 s.lvl + 1, direction,
@@ -1190,7 +1002,7 @@ def _run_bitmap_sharded(
                 lambda new, old: jnp.where(alive, new, old), nxt, s)
 
     init = _ShardState(
-        parent_loc, level_loc, frontier_bm, visited_loc, frontier_bm,
+        parent_loc, level_loc, frontier_bm, visited_loc,
         jnp.int32(1), jnp.int32(1), deg_root, deg_root,
         jnp.int32(0), TOP_DOWN,
         jnp.full((max_levels,), -1, jnp.int32),

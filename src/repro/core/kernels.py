@@ -70,8 +70,8 @@ def rekernel_plan(plan, kernel: str):
     The kernel axis rides on top of a tuned/explicit plan: layout,
     mesh_shape, partition and α/β carry over unchanged, but an exchange
     outside the target kernel's family falls back to that kernel's
-    default wiring (a BFS-tuned ``hier_or_sieve`` has no min-combine
-    analogue — the sieve would strip SSSP's re-entered vertices).
+    default wiring (a BFS-tuned ``hier_gather`` has no min-combine
+    analogue).
     """
     if kernel == plan.kernel:
         return plan

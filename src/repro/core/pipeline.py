@@ -9,15 +9,12 @@ The four rungs of Fig. 18, as config knobs:
                      Pallas engine (T1) [+ monitor comm (T3) in the
                      distributed runner]
 
-Extra rungs beyond the paper's figure:
+Extra rung beyond the paper's figure:
 
-  pre-g500-legacy  : the pre-resident customized loop (per-level bitmap
-                     round trip, all-edges top-down) — the measured
-                     "before" for BENCH_bfs.json;
   pre-g500-batch   : the resident engine with all search keys vmapped
                      into ONE jitted program (``batched=True``).
 
-Every rung is executed by constructing a :class:`repro.core.plan.BFSPlan`
+Every rung is executed by constructing a :class:`repro.core.plan.TraversalPlan`
 (:meth:`Graph500Config.to_plan`) and running it through
 :func:`repro.core.plan.compile_plan` — the mesh rungs are just layouts:
 
@@ -49,7 +46,7 @@ from repro.core import kronecker
 from repro.core.bfs_steps import EdgeView, edge_view, with_edge_weights
 from repro.core.graph_build import build_csr
 from repro.core.heavy import HeavyCore, build_heavy_core
-from repro.core.plan import BFSPlan, compile_plan
+from repro.core.plan import TraversalPlan, compile_plan
 from repro.core.reorder import Reordering, degree_reorder, relabel_edges
 from repro.core.teps import Graph500Run
 
@@ -62,7 +59,7 @@ class Graph500Config:
     n_roots: int = 8
     degree_sort: bool = True
     heavy_threshold: Optional[int] = 100   # None disables the dense core
-    engine: str = "bitmap"                 # "reference" | "legacy" | "bitmap"
+    engine: str = "bitmap"                 # "reference" | "bitmap"
     alpha: float = 14.0
     beta: float = 24.0
     batched: bool = False                  # one jitted program for all roots
@@ -71,7 +68,7 @@ class Graph500Config:
     # 0 means "all visible devices".
     root_devices: Optional[int] = None
     # Explicit plan layout/mesh (DESIGN.md §10) — overrides root_devices.
-    # None keeps the legacy-knob derivation; () forces single device.
+    # None derives them from root_devices; () forces single device.
     layout: Optional[tuple] = None
     mesh_shape: Optional[tuple] = None
     exchange: str = "hier_or"
@@ -118,8 +115,6 @@ class Graph500Config:
                         engine="reference"),
             "k": dict(degree_sort=True, heavy_threshold=None,
                       engine="reference", alpha=8.0, beta=64.0),
-            "pre-g500-legacy": dict(degree_sort=True, heavy_threshold=100,
-                                    engine="legacy"),
             "pre-g500": dict(degree_sort=True, heavy_threshold=100,
                              engine="bitmap"),
             "pre-g500-batch": dict(degree_sort=True, heavy_threshold=100,
@@ -143,7 +138,7 @@ class Graph500Config:
         }
         return Graph500Config(**{**presets[rung], **kw})
 
-    def to_plan(self) -> BFSPlan:
+    def to_plan(self) -> TraversalPlan:
         """Lower the config knobs onto the declarative plan axes.
 
         With ``tuned=True`` the plan starts from the TUNED_PLANS.json
@@ -178,7 +173,7 @@ class Graph500Config:
                           if self.root_devices else None)
         else:
             layout, mesh_shape = (), None
-        return BFSPlan(
+        return TraversalPlan(
             engine=self.engine, layout=layout, mesh_shape=mesh_shape,
             exchange=self.exchange, partition=self.partition,
             alpha=self.alpha, beta=self.beta,

@@ -6,10 +6,9 @@ group-based monitor communication (T3) — and Buluç–Madduri
 (arXiv:1104.4518) shows the partitionings are points in one design space
 selected per run.  This module makes that the API:
 
-  1. **spec** — :class:`TraversalPlan` (née ``BFSPlan``; the old name
-     survives as an alias), a frozen dataclass naming the *kernel*
-     (``"bfs"`` / ``"sssp"`` — the traversal-lifecycle contract of
-     DESIGN.md §16 and ``core.kernels``), the engine, the mesh *layout*
+  1. **spec** — :class:`TraversalPlan`, a frozen dataclass naming the
+     *kernel* (``"bfs"`` / ``"sssp"`` — the traversal-lifecycle contract
+     of DESIGN.md §16 and ``core.kernels``), the engine, the mesh *layout*
      (which of the three axes ``root`` / ``group`` / ``member`` exist
      and their sizes), the delta-exchange strategy, the direction-switch
      α/β and the chunking knobs.  Kernel, sharding layout, exchange
@@ -41,11 +40,6 @@ Layouts (all bitwise-locked to the single-device bitmap engine):
                                   OUTSIDE the vertex-sharded SPMD program
                                   — each root-slice of devices runs the
                                   full layer-2 traversal for its roots.
-
-The six pre-plan entry points (``hybrid_bfs``, ``bfs_batch``,
-``bfs_batch_sharded``, ``make_dist_bfs``, ``run_graph500_batched``,
-``run_graph500_sharded``) survive as thin deprecation shims over this
-module; see DESIGN.md §10 for the migration table.
 """
 from __future__ import annotations
 
@@ -53,7 +47,6 @@ import dataclasses
 import functools
 import math
 import time
-import warnings
 from dataclasses import dataclass
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -85,7 +78,7 @@ from repro.core.hybrid_bfs import (
     _run_bitmap,
     _run_bitmap_impl,
     _run_bitmap_sharded,
-    _run_legacy,
+    _run_reference,
 )
 from repro.core.hybrid_bfs import SENTINEL_OK
 from repro.core.kernels import kernel_spec, validate_result_batch
@@ -126,17 +119,13 @@ class TraversalPlan:
                       kernel picks the state carrier / relax rule /
                       exchange combine / validation contract from
                       ``core.kernels``; every other axis is shared.
-      ``engine``      Fig. 18 ladder rung (reference / legacy / bitmap-T1)
+      ``engine``      Fig. 18 ladder rung (reference / bitmap-T1)
       ``layout``      which mesh axes exist — §4.2 partitioning choice
       ``mesh_shape``  per-axis sizes; ``None`` infers from the visible
                       devices (the (group, member) split comes from the
                       eq.-5 interconnect model via ``plan_device_mesh``)
       ``exchange``    §4.3 monitor wiring of the per-level delta combine:
-                      ``hier_or`` / ``hier_gather`` / ``flat``, plus the
-                      DESIGN.md §12 wire-codec variants ``hier_or_packed``
-                      (density-adaptive index-list codec on the
-                      inter-group leg) and ``hier_or_sieve``
-                      (visited-sieve then pack)
+                      ``hier_or`` / ``hier_gather`` / ``flat``
       ``partition``   vertex-ownership map of the sharded engine:
                       ``block`` (contiguous word blocks) vs
                       ``word_cyclic`` (eq. (3) cyclic ownership at
@@ -191,14 +180,10 @@ class TraversalPlan:
         fields = {f.name for f in dataclasses.fields(TraversalPlan)}
         unknown = set(d) - fields
         if unknown:
-            raise ValueError(f"unknown BFSPlan fields {sorted(unknown)}; "
+            raise ValueError(f"unknown TraversalPlan fields "
+                             f"{sorted(unknown)}; "
                              f"expected a subset of {sorted(fields)}")
         return TraversalPlan(**d)
-
-
-#: Migration shim (DESIGN.md §16): the spec predates the second kernel
-#: and every existing call site constructs a ``BFSPlan``.
-BFSPlan = TraversalPlan
 
 
 @dataclass
@@ -258,14 +243,6 @@ class Graph500Result:
     mesh_axes: Optional[dict]   # {axis: size} of the resolved mesh
 
 
-def warn_deprecated(old: str, replacement: str) -> None:
-    """Deprecation notice shared by the six legacy entrypoint shims."""
-    warnings.warn(
-        f"{old} is deprecated; construct a BFSPlan and compile_plan it "
-        f"instead ({replacement}) — see DESIGN.md §10",
-        DeprecationWarning, stacklevel=3)
-
-
 # ---------------------------------------------------------------------------
 # 2. Validation + mesh resolution
 # ---------------------------------------------------------------------------
@@ -313,7 +290,7 @@ def validate_plan(plan: TraversalPlan) -> None:
     if plan.layout and plan.engine != "bitmap":
         raise ValueError(
             f"mesh layout {plan.layout} requires engine='bitmap' "
-            f"(got {plan.engine!r}); the legacy engines are single-device")
+            f"(got {plan.engine!r}); the reference engine is single-device")
     if "root" in plan.layout and not plan.batch_roots:
         raise ValueError(
             "layout with a 'root' axis requires batch_roots=True "
@@ -345,7 +322,7 @@ def validate_plan(plan: TraversalPlan) -> None:
         raise ValueError(f"n_chunks must be >= 1, got {plan.n_chunks}")
 
 
-def _resolve_mesh(plan: BFSPlan, mesh, axis_names):
+def _resolve_mesh(plan: TraversalPlan, mesh, axis_names):
     """Return (mesh, names) for the plan — names[i] is the concrete mesh
     axis (str, or tuple of axes for a factored role) playing layout role
     ``plan.layout[i]``.
@@ -709,8 +686,8 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
     ``built`` is a :class:`PreparedGraph` or anything exposing
     ``ev``/``degree``/``core`` (``pipeline.BuiltGraph``).  ``mesh`` lets
     callers supply a prebuilt device mesh (its axes must cover the plan
-    layout; the legacy shims use this — plan-level strictness like the
-    power-of-two member check is skipped for caller-supplied meshes).
+    layout; plan-level strictness like the power-of-two member check is
+    skipped for caller-supplied meshes).
     ``axis_names`` renames layout roles onto concrete mesh axes (entries
     may be tuples for factored roles).
 
@@ -718,14 +695,14 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
     :class:`repro.core.faults.FaultSpec` compiled into the bitmap
     engines' injection hooks — deterministic corruption for exercising
     the checked execution mode and the recovery policy.  ``None`` (the
-    default) compiles the clean program; the legacy engines have no
-    injection sites and reject a fault.
+    default) compiles the clean program; the reference engine has no
+    injection sites and rejects a fault.
     """
     validate_plan(plan)
     if fault is not None and plan.engine != "bitmap":
         raise ValueError(
             f"fault injection requires engine='bitmap' (got "
-            f"{plan.engine!r}); the legacy engines have no hooks")
+            f"{plan.engine!r}); the reference engine has no hooks")
     mesh, names = _resolve_mesh(plan, mesh, axis_names)
     role = dict(zip(plan.layout, names))
     vertexy = "member" in plan.layout
@@ -773,12 +750,10 @@ def compile_plan(plan: TraversalPlan, built, *, mesh=None,
                                (chunks, degree, n_active, core_arg), 3,
                                bitmap_kw)
         else:
-            legacy_core = use_core and plan.engine == "legacy"
             program = _Program(
-                _run_legacy,
-                (pg.ev, degree, n_active, pg.core if legacy_core else None), 3,
-                dict(engine=plan.engine, alpha=plan.alpha, beta=plan.beta,
-                     use_core=legacy_core, max_levels=plan.max_levels))
+                _run_reference, (pg.ev, degree, n_active), 3,
+                dict(alpha=plan.alpha, beta=plan.beta,
+                     max_levels=plan.max_levels))
         v_orig = pg.ev.num_vertices
     elif plan.layout == ("root",):
         n_active = jnp.sum(pg.degree > 0).astype(jnp.int32)
@@ -915,7 +890,7 @@ class CompiledBFS:
 
     def _sentinel_of(self, res):
         """The per-level in-loop check-mask trace of one raw result, or
-        ``None`` for engines without one (legacy)."""
+        ``None`` for the reference engine, which has none."""
         if self._vertexy:
             return res.sentinel
         stats = getattr(res, "stats", None)

@@ -25,11 +25,8 @@ BFS one with three substitutions (the kernel interface of §16):
   * **exchange combine** — distances combine across shards with the
     min-reduction family (``comms.hierarchical.hierarchical_pmin``, the
     T3 two-phase monitor shape), while the changed-set *delta* bitmap
-    rides the existing OR family with the §12 density-adaptive codec on
-    the inter-group leg (``hier_or_packed`` wiring; the sieve variant is
-    deliberately NOT used — SSSP vertices re-enter the changed set after
-    being visited, so sieving against "known" bits would drop live
-    work).
+    rides the existing OR family (the ``hier_or`` wiring, or ``flat``
+    under the ``flat`` plan).
 
 Bucket loop (label-correcting δ-stepping): each round pops the entire
 minimum bucket ``b = min(dist // δ)`` over the changed set as the
@@ -79,7 +76,7 @@ from repro.core.hybrid_bfs import (
 from repro.kernels.ref import popcount_u32
 
 #: Exchange wirings of the SSSP kernel: ``hier_min`` is the T3 two-phase
-#: min-reduction for distances + codec'd hierarchical OR for the changed
+#: min-reduction for distances + hierarchical OR for the changed
 #: delta; ``flat`` is the single-phase ablation baseline for both legs.
 SSSP_EXCHANGES = ("hier_min", "flat")
 
@@ -369,12 +366,11 @@ def _run_sssp_sharded(
     gslots = to_global(slots)
     ids_full = jnp.arange(v_pad, dtype=jnp.int32)
 
-    # The changed-set delta rides the OR exchange family: the two-phase
-    # wiring takes the §12 density-adaptive codec on its inter-group leg
-    # (sparse index lists when the delta is thin — SSSP rounds usually
-    # are).  NEVER the sieve variant: changed-set re-entries would be
-    # wrongly stripped as "already known".
-    delta_wire = "flat" if exchange == "flat" else "hier_or_packed"
+    # The changed-set delta rides the OR exchange family over the same
+    # hop structure as the distances: two-phase under ``hier_min``,
+    # single-phase under ``flat``.  Owner deltas are disjoint, so the OR
+    # is exact whichever wiring carries it.
+    delta_wire = "flat" if exchange == "flat" else "hier_or"
 
     # --- init: root bit set once.
     with jax.named_scope("sssp.init"):
@@ -434,13 +430,12 @@ def _run_sssp_sharded(
                     contrib, group_axis, member_axis, fault=fault,
                     level=s.rnd, device=dev, root=root)
 
-            # Exchange 2 — changed-set delta bitmap (OR family + codec).
+            # Exchange 2 — changed-set delta bitmap (OR family).
             delta_bm_loc = _pack_delta_words(improved_loc, w_loc)
             changed_delta_full = _exchange_delta(
                 delta_bm_loc, dev, w_loc, n_dev, exchange=delta_wire,
                 group_axis=group_axis, member_axis=member_axis,
-                partition=partition, known_bm=None,
-                fault=fault, level=s.rnd, root=root)
+                partition=partition, fault=fault, level=s.rnd, root=root)
 
         with jax.named_scope("sssp.select"):
             new_changed = popped_bm | changed_delta_full

@@ -7,16 +7,12 @@ noted in DESIGN.md §8 — multiplicities are generator noise, not traversal
 work).
 
 Per the spec the headline figure is the **harmonic mean** TEPS across the
-64 search keys.  Two harnesses:
-
-  * :func:`run_graph500` — one jitted BFS per root, each timed separately
-    (closest to the reference driver loop).
-  * :func:`run_graph500_batched` — all roots under ONE jitted program via
-    ``bfs_batch`` (vmap over search keys).  The spec times each search;
-    with a fused batch the per-search time is the batch wall-clock divided
-    by the number of roots (noted in DESIGN.md §8) — the harmonic-mean
-    TEPS then measures exactly what the list measures: total traversal
-    throughput over the 64 searches.
+64 search keys.  :func:`run_graph500` times one jitted BFS per root
+(closest to the reference code's search loop); a ``batch_roots`` plan's
+``CompiledBFS.run`` times all roots under ONE jitted program, and the
+per-search time is the batch wall-clock divided by the number of roots
+(noted in DESIGN.md §8) — the harmonic-mean TEPS then measures exactly
+what the list measures: total traversal throughput over the 64 searches.
 """
 from __future__ import annotations
 
@@ -99,14 +95,14 @@ def run_graph500(
 ) -> Graph500Run:
     """Timed BFS over the given roots (Graph500 step 3 + 4), one at a time.
 
-    A per-root plan run: ``BFSPlan(engine=engine, layout=(),
+    A per-root plan run: ``TraversalPlan(engine=engine, layout=(),
     batch_roots=False)`` — the chunked edge view is built once at compile
     time (graph construction is untimed per spec) and each search is
     timed separately, closest to the reference driver loop.
     """
-    from repro.core.plan import BFSPlan, PreparedGraph, compile_plan
+    from repro.core.plan import PreparedGraph, TraversalPlan, compile_plan
 
-    p = BFSPlan(engine=engine, layout=(), batch_roots=False,
+    p = TraversalPlan(engine=engine, layout=(), batch_roots=False,
                 alpha=alpha, beta=beta, n_chunks=n_chunks)
     compiled = compile_plan(
         p, PreparedGraph(ev=ev, degree=degree, core=core))
@@ -114,99 +110,3 @@ def run_graph500(
     if not do_validate:
         run.validated = [True] * len(run.teps)
     return run
-
-
-def run_graph500_batched(
-    ev: EdgeView,
-    degree: jax.Array,
-    roots,
-    *,
-    core=None,
-    alpha: float = 14.0,
-    beta: float = 24.0,
-    do_validate: bool = True,
-    warmup: bool = True,
-    n_chunks: int = DEFAULT_CHUNKS,
-    mesh=None,
-    root_axis: str = "root",
-) -> Graph500Run:
-    """DEPRECATED: fused-batch Graph500 harness — shim over the plan API.
-
-    Equivalent plan: ``BFSPlan(layout=(), batch_roots=True)``, or
-    ``BFSPlan(layout=("root",))`` when ``mesh`` is given (root-parallel
-    layer-1 sharding, zero communication, per-root outputs
-    bitwise-identical to the single-device batch).  All searches share
-    one compilation and one device dispatch; per-search time is the
-    batch wall-clock / n_roots (see module docstring).
-    """
-    from repro.core.plan import (
-        BFSPlan, PreparedGraph, compile_plan, warn_deprecated,
-    )
-
-    warn_deprecated(
-        "run_graph500_batched",
-        "BFSPlan(layout=() or ('root',), batch_roots=True) + "
-        "CompiledBFS.run")
-    run = Graph500Run(batched=True)
-    roots = np.asarray(roots, dtype=np.int32)
-    n = len(roots)
-    if n == 0:
-        return run
-    layout = ("root",) if mesh is not None else ()
-    p = BFSPlan(engine="bitmap", layout=layout, batch_roots=True,
-                alpha=alpha, beta=beta, n_chunks=n_chunks)
-    compiled = compile_plan(
-        p, PreparedGraph(ev=ev, degree=degree, core=core),
-        mesh=mesh, axis_names=(root_axis,) if mesh is not None else None)
-    run = compiled.run(roots, warmup=warmup, do_validate=do_validate).run
-    if not do_validate:
-        run.validated = [True] * len(run.teps)
-    return run
-
-
-def run_graph500_sharded(
-    mesh,
-    sharded_graph,
-    degree,
-    roots,
-    *,
-    core=None,
-    exchange: str = "hier_or",
-    alpha: float = 14.0,
-    beta: float = 24.0,
-    warmup: bool = True,
-    ev: EdgeView | None = None,
-    do_validate: bool = True,
-) -> Graph500Run:
-    """DEPRECATED: vertex-sharded Graph500 harness — shim over the plan API.
-
-    Equivalent plan: ``BFSPlan(layout=("group", "member"),
-    exchange=exchange)`` compiled against ``mesh`` with
-    ``built.sharded = sharded_graph``.  All search keys run batched
-    inside ONE SPMD program spanning the (group, member) mesh:
-    per-search time is batch wall-clock / n_roots, exactly as in
-    :func:`run_graph500_batched`.  ``degree`` is the global (unsharded)
-    degree vector used for the TEPS edge count.  Spec validation
-    (step 4) runs per root when ``ev`` (the unsharded edge view) is
-    provided and ``do_validate`` is on; without ``ev`` the checks cannot
-    run, so ``validated`` stays empty and ``all_valid`` reports False
-    rather than vacuously True.
-    """
-    from repro.core.plan import (
-        BFSPlan, PreparedGraph, compile_plan, warn_deprecated,
-    )
-
-    warn_deprecated(
-        "run_graph500_sharded",
-        'BFSPlan(layout=("group", "member"), exchange=...) + '
-        "CompiledBFS.run")
-    roots = np.asarray(roots, dtype=np.int32)
-    if len(roots) == 0:
-        return Graph500Run(batched=True)
-    p = BFSPlan(engine="bitmap", layout=("group", "member"),
-                exchange=exchange, alpha=alpha, beta=beta, batch_roots=True)
-    compiled = compile_plan(
-        p, PreparedGraph(ev=ev, degree=degree, core=core,
-                         sharded=sharded_graph),
-        mesh=mesh)
-    return compiled.run(roots, warmup=warmup, do_validate=do_validate).run

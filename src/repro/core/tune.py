@@ -4,8 +4,8 @@ per-scale winners (DESIGN.md §11).
 The paper tunes its hybrid-BFS knobs — direction-switch α/β, chunking,
 monitor exchange wiring, mesh layout — by hand per machine scale
 (§4.2/§4.3), and Buluç–Madduri (arXiv:1104.4518) show the winning
-layout/partition flips with scale and machine shape.  PR 3's frozen
-:class:`~repro.core.plan.BFSPlan` turned exactly those knobs into
+layout/partition flips with scale and machine shape.  The frozen
+:class:`~repro.core.plan.TraversalPlan` turned exactly those knobs into
 orthogonal declarative axes, so tuning is a loop over
 :func:`~repro.core.plan.compile_plan`:
 
@@ -60,9 +60,9 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from repro.core.plan import BFSPlan, PreparedGraph, compile_plan
+from repro.core.plan import PreparedGraph, TraversalPlan, compile_plan
 
-# v2: BFSPlan grew the `partition` axis (block vs word_cyclic vertex
+# v2: the plan grew the `partition` axis (block vs word_cyclic vertex
 # ownership of the sharded engine); v1 winners predate it and must be
 # re-swept, not silently reinterpreted.
 SCHEMA_VERSION = 2
@@ -100,20 +100,15 @@ class TuneBudget:
 
 
 BUDGETS = {
-    # the CI smoke sweeps the wire-codec variants (DESIGN.md §12) next to
-    # the raw hier_or so their bitwise-parity acceptance runs on every PR
-    "small": TuneBudget(
-        "small", exchanges=("hier_or", "hier_or_packed", "hier_or_sieve")),
+    "small": TuneBudget("small"),
     "medium": TuneBudget(
         "medium",
-        exchanges=("hier_or", "hier_gather", "hier_or_packed",
-                   "hier_or_sieve"),
+        exchanges=("hier_or", "hier_gather"),
         alpha_beta=((8.0, 64.0), (14.0, 24.0)), n_chunks=(16, 64),
         all_factorizations=True, n_roots=8, reps=2),
     "full": TuneBudget(
         "full",
-        exchanges=("hier_or", "hier_gather", "flat", "hier_or_packed",
-                   "hier_or_sieve"),
+        exchanges=("hier_or", "hier_gather", "flat"),
         alpha_beta=((8.0, 24.0), (8.0, 64.0), (14.0, 24.0), (14.0, 64.0)),
         n_chunks=(16, 64, 256), all_factorizations=True, n_roots=16, reps=3),
 }
@@ -179,9 +174,10 @@ def enumerate_plans(n_devices: int, budget: TuneBudget) -> list:
         partitions = budget.partitions if vertexy else ("block",)
         for exchange, partition, (alpha, beta), n_chunks in itertools.product(
                 exchanges, partitions, budget.alpha_beta, budget.n_chunks):
-            p = BFSPlan(layout=layout, mesh_shape=shape, exchange=exchange,
-                        partition=partition, alpha=alpha, beta=beta,
-                        n_chunks=n_chunks, batch_roots=True)
+            p = TraversalPlan(layout=layout, mesh_shape=shape,
+                              exchange=exchange, partition=partition,
+                              alpha=alpha, beta=beta, n_chunks=n_chunks,
+                              batch_roots=True)
             plans[p] = None
     return list(plans)
 
@@ -198,7 +194,7 @@ class TuneResult:
     measurement itself raised — recorded with the exception string so
     one crashing candidate never kills the sweep)."""
 
-    plan: BFSPlan
+    plan: TraversalPlan
     status: str
     reason: str = ""
     wall_s: float = math.inf
@@ -264,7 +260,7 @@ class TuneReport:
         return "\n".join(lines)
 
 
-def _plan_sort_key(plan: BFSPlan) -> str:
+def _plan_sort_key(plan: TraversalPlan) -> str:
     return json.dumps(plan.to_dict(), sort_keys=True)
 
 
@@ -351,7 +347,7 @@ def sweep(
     # inputs.  One oracle covers every candidate because the scatter-min
     # parent convention makes the tree direction-invariant (DESIGN.md §3)
     # — α/β only move the switch level, never the winning parent.
-    oracle = compile_plan(BFSPlan(layout=(), batch_roots=True), pg)
+    oracle = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg)
     oracle_parent = np.asarray(oracle.bfs(roots).parent)
 
     for plan in plans:
@@ -410,7 +406,7 @@ def load_table(path: Optional[str] = None) -> Optional[dict]:
         return None
     got = doc.get("schema_version")
     if got != SCHEMA_VERSION:
-        hint = ("its plans predate the BFSPlan `partition` axis (v2) and "
+        hint = ("its plans predate the plan's `partition` axis (v2) and "
                 "the sweep must re-rank both partitions"
                 if isinstance(got, int) and got < SCHEMA_VERSION
                 else "it was written by a newer plan schema")
@@ -466,7 +462,7 @@ def tuned_plan(
     path: Optional[str] = None,
     overrides: Optional[dict] = None,
     kernel: Optional[str] = None,
-) -> Optional[BFSPlan]:
+) -> Optional[TraversalPlan]:
     """Look up the persisted winner for ``(scale, n_devices, backend)``.
 
     ``n_devices``/``backend`` default to this process's JAX view.  Returns
@@ -488,7 +484,7 @@ def tuned_plan(
     entry = doc["entries"].get(_entry_key(scale, n_devices, backend))
     if entry is None:
         return None
-    plan = BFSPlan.from_dict(entry["plan"])
+    plan = TraversalPlan.from_dict(entry["plan"])
     if overrides:
         plan = dataclasses.replace(plan, **overrides)
     if kernel is not None:
@@ -549,7 +545,7 @@ def _respawn_with_devices(n: int, args) -> int:
 
 def main(argv: Optional[list] = None) -> int:
     ap = argparse.ArgumentParser(
-        description="BFSPlan auto-tuner (DESIGN.md §11)")
+        description="TraversalPlan auto-tuner (DESIGN.md §11)")
     ap.add_argument("--scale", type=int, default=12)
     ap.add_argument("--budget", choices=sorted(BUDGETS), default="small")
     ap.add_argument("--seed", type=int, default=1)

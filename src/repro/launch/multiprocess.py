@@ -1,11 +1,10 @@
 """Multi-process distributed runtime: real cross-process exchange on
 any CI box (DESIGN.md §15).
 
-Every number this repo committed before PR 9 ran ONE process faking 8
-host devices, so the per-level frontier exchange — the whole point of
-the paper's group-based monitor communication (T3, Fig. 16) — was a
-memcpy: modeled ``wire_bytes`` (§12) existed, measured transfer seconds
-did not.  This launcher makes the exchange real without TPUs:
+One process faking 8 host devices turns the per-level frontier
+exchange — the whole point of the paper's group-based monitor
+communication (T3, Fig. 16) — into a memcpy.  This launcher makes the
+exchange real without TPUs:
 
   * **parent** — picks a localhost rendezvous port, spawns one JAX
     process per "node" (``--procs N``, each seeing ``--devices-per-proc
@@ -22,11 +21,9 @@ did not.  This launcher makes the exchange real without TPUs:
     two-phase collectives is exactly the leg that crosses processes.
   * **rank 0** — collects the :class:`~repro.core.teps.Graph500Run`
     bookkeeping, the bitwise-parity verdict against the in-process
-    single-device oracle, the modeled per-level ``wire_bytes`` AND the
-    measured per-level exchange-leg wall-clock
-    (:func:`time_exchange_per_level`), and prints one JSON payload the
-    parent returns — the §12 byte model finally sits next to measured
-    transfer seconds.
+    single-device oracle and the measured per-level exchange-leg
+    wall-clock (:func:`time_exchange_per_level`), and prints one JSON
+    payload the parent returns.
 
 Acceptance is bitwise: parents from an N-proc × D-device run must equal
 the single-process fake-device run and the single-device oracle for
@@ -39,10 +36,10 @@ CLI (the CI multiprocess smoke)::
     PYTHONPATH=src python -m repro.launch.multiprocess \\
         --procs 2 --devices-per-proc 4 --scale 12 --roots 8
 
-    # both partitions + the §12 codec, fault injection, bench payload
+    # both partitions, two exchanges, fault injection, bench payload
     PYTHONPATH=src python -m repro.launch.multiprocess \\
         --procs 4 --devices-per-proc 2 --scale 12 --roots 8 \\
-        --exchanges hier_or,hier_or_packed --partitions block,word_cyclic
+        --exchanges hier_or,hier_gather --partitions block,word_cyclic
     PYTHONPATH=src python -m repro.launch.multiprocess \\
         --procs 2 --devices-per-proc 2 --scale 10 \\
         --inject exchange/zero/1/persistent --check full
@@ -65,8 +62,7 @@ _SRC_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 #: rung-name suffix per exchange wiring (matches benchmarks/bfs_sharded)
-EXCHANGE_SUFFIX = {"hier_or": "", "hier_or_packed": "_pack",
-                   "hier_or_sieve": "_sieve", "hier_gather": "_gather",
+EXCHANGE_SUFFIX = {"hier_or": "", "hier_gather": "_gather",
                    "hier_min": "_min", "flat": "_flat"}
 
 
@@ -120,15 +116,13 @@ def parse_inject(spec: Optional[str]):
 # ---------------------------------------------------------------------------
 
 def time_exchange_per_level(compiled, level_row, *, reps: int = 3) -> dict:
-    """Measured wall-clock of the per-level delta-exchange leg, next to
-    the §12 byte model.
+    """Measured wall-clock of the per-level delta-exchange leg.
 
     The SPMD traversal runs its whole level loop inside one jitted call,
     so the exchange cost cannot be clocked in situ — but the completed
     ``level`` array recovers each level's delta bitmap exactly (the
     delta exchanged at loop step ``t`` is the set of vertices with
-    ``level == t``, the same reconstruction ``modeled_wire_bytes``
-    uses).  This replays each level's REAL payload through the real
+    ``level == t``).  This replays each level's REAL payload through the real
     exchange program (:func:`repro.core.hybrid_bfs._exchange_delta` in a
     ``shard_map`` over the compiled plan's mesh — cross-process wire
     under the multiprocess runtime) and reports min-over-``reps``
@@ -152,18 +146,17 @@ def time_exchange_per_level(compiled, level_row, *, reps: int = 3) -> dict:
     mesh = compiled.mesh
     role = dict(zip(plan.layout, compiled._axis_names))
     group_axis, member_axis = role["group"], role["member"]
-    sieve = plan.exchange == "hier_or_sieve"
 
-    def local(delta, known):
+    def local(delta):
         dev = _shard_index(group_axis, member_axis)
         return _exchange_delta(
             delta[0], dev, w_loc, n_dev, exchange=plan.exchange,
             group_axis=group_axis, member_axis=member_axis,
-            partition=plan.partition, known_bm=known[0] if sieve else None)
+            partition=plan.partition)
 
     va = (group_axis, member_axis)
     prog = jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=(P(va), P(None)), out_specs=P(),
+        local, mesh=mesh, in_specs=P(va), out_specs=P(),
         check_vma=False))
 
     level_row = np.asarray(level_row).reshape(-1)
@@ -187,18 +180,14 @@ def time_exchange_per_level(compiled, level_row, *, reps: int = 3) -> dict:
     warm = None
     for t in range(1, depth + 1):
         verts = np.flatnonzero(level_row == t)
-        delta = shard_view(words_of(verts))
-        known = words_of(np.flatnonzero((level_row >= 0)
-                                        & (level_row < t)))[None, :]
-        delta = jnp.asarray(delta)
-        known = jnp.asarray(known)
+        delta = jnp.asarray(shard_view(words_of(verts)))
         if warm is None:
-            jax.block_until_ready(prog(delta, known))   # compile once
+            jax.block_until_ready(prog(delta))   # compile once
             warm = True
         best = float("inf")
         for _ in range(max(1, reps)):
             t0 = time.perf_counter()
-            jax.block_until_ready(prog(delta, known))
+            jax.block_until_ready(prog(delta))
             best = min(best, time.perf_counter() - t0)
         per_level.append({"level": t, "frontier": int(verts.size),
                           "seconds": best})
@@ -274,8 +263,7 @@ def _worker(args) -> int:
     log(f"initialized: {jax.process_count()} processes x {dpp} devices "
         f"= {jax.device_count()} global")
 
-    from repro.core.distributed_bfs import modeled_wire_bytes
-    from repro.core.plan import BFSPlan, compile_plan, mesh_process_count
+    from repro.core.plan import TraversalPlan, compile_plan, mesh_process_count
     from repro.core.tune import _build_inputs
     from repro.kernels import ops as kops
 
@@ -290,7 +278,7 @@ def _worker(args) -> int:
     # asserts against it — the acceptance bar is bitwise.  For SSSP the
     # level plane carries distances, so parity covers both arrays.
     oracle = compile_plan(
-        BFSPlan(layout=(), batch_roots=True, kernel=kernel), pg)
+        TraversalPlan(layout=(), batch_roots=True, kernel=kernel), pg)
     oracle_res = oracle.bfs(roots)
     oracle_parent = np.asarray(oracle_res.parent)[:, :v]
     oracle_level = np.asarray(oracle_res.level)[:, :v]
@@ -307,9 +295,9 @@ def _worker(args) -> int:
     for partition in partitions:
         for exchange in exchanges:
             name = rung_name(args.procs, dpp, exchange, partition, kernel)
-            plan = BFSPlan(layout=("group", "member"), mesh_shape=shape,
-                           exchange=exchange, partition=partition,
-                           kernel=kernel)
+            plan = TraversalPlan(layout=("group", "member"),
+                                 mesh_shape=shape, exchange=exchange,
+                                 partition=partition, kernel=kernel)
             compiled = compile_plan(plan, pg, fault=fault)
             assert mesh_process_count(compiled.mesh) == args.procs, \
                 "mesh does not span the worker processes"
@@ -331,14 +319,9 @@ def _worker(args) -> int:
                 raise AssertionError(
                     f"{name}: fault injected but no check ran — use "
                     f"--check post|full")
-            # The §12 byte model and the exchange-leg replay reconstruct
-            # per-level BFS deltas from the level array; SSSP rounds pop
-            # δ-buckets, not levels, so neither applies to that kernel.
-            wire = (modeled_wire_bytes(
-                        result.level[0], n_devices=total,
-                        w_loc=compiled.graph.sharded.w_loc,
-                        group=args.procs, member=dpp, partition=partition)
-                    if kernel == "bfs" else None)
+            # The exchange-leg replay reconstructs per-level BFS deltas
+            # from the level array; SSSP rounds pop δ-buckets, not
+            # levels, so it does not apply to that kernel.
             exch_s = (time_exchange_per_level(compiled, result.level[0],
                                               reps=args.reps)
                       if fault is None and kernel == "bfs" else None)
@@ -357,12 +340,10 @@ def _worker(args) -> int:
                 "parent_sha256": parent_digest(result.parent[:, :v]),
                 "validated": run.all_valid,
                 "check_counts": run.check_counts,
-                "wire_bytes": wire,
                 "exchange_seconds": exch_s,
                 "g500": _serialize_run(run),
             }
-            it = (f"inter_raw={wire['totals']['inter_raw']}B "
-                  f"exch_s={exch_s['total_seconds']:.4f}" if exch_s
+            it = (f"exch_s={exch_s['total_seconds']:.4f}" if exch_s
                   else f"check_counts={run.check_counts}")
             log(f"{name}: identical={identical} "
                 f"hmean={run.harmonic_mean_teps:.3g} {it}")
@@ -584,12 +565,9 @@ def main(argv: Optional[list] = None) -> int:
         extra = (f"exchange_total={exch['total_seconds']:.4f}s "
                  f"levels={exch['levels']}" if exch
                  else f"check_counts={rung['check_counts']}")
-        wire = rung.get("wire_bytes")
-        raw = (f"inter_raw={wire['totals']['inter_raw']}B "
-               if wire else "")
         print(f"# {name}: identical={rung['identical']} "
               f"hmean_TEPS={rung['harmonic_mean_teps']:.3g} "
-              f"{raw}{extra}", file=sys.stderr)
+              f"{extra}", file=sys.stderr)
     print(_MARK + json.dumps(payload), flush=True)
     if args.inject is None and not payload["parents_bitwise_identical"]:
         print("# FAIL: parents not bitwise-identical to the oracle",

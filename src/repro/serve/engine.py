@@ -6,9 +6,10 @@ distance plane rides the ``level`` rows) — through the same coalescer,
 hot-root cache, and checked-batch requeue machinery.
 
 ``Engine`` is the product-shaped wrapper around the whole existing
-stack: it loads a graph ONCE, resolves a :class:`~repro.core.plan.BFSPlan`
-(TUNED_PLANS.json winner when a scale is given, explicit overrides win),
-compiles it ONCE, and then serves an arbitrary stream of root queries
+stack: it loads a graph ONCE, resolves a
+:class:`~repro.core.plan.TraversalPlan` (TUNED_PLANS.json winner when a
+scale is given, explicit overrides win), compiles it ONCE, and then
+serves an arbitrary stream of root queries
 against the resident :class:`~repro.core.plan.CompiledBFS` — exactly the
 amortization the paper's resident bitmaps and the serve_decode example
 demonstrate, promoted to a subsystem.
@@ -28,7 +29,7 @@ from typing import Optional
 
 import numpy as np
 
-from repro.core.plan import BFSPlan, compile_plan
+from repro.core.plan import TraversalPlan, compile_plan
 from repro.serve.cache import ParentCache
 from repro.serve.coalescer import BatchOutcome, CoalescePolicy, replay
 from repro.serve.metrics import ServeReport
@@ -63,7 +64,7 @@ class ServeConfig:
 
 def resolve_serve_plan(scale: Optional[int] = None,
                        overrides: Optional[dict] = None,
-                       *, batch_size: int = 8) -> BFSPlan:
+                       *, batch_size: int = 8) -> TraversalPlan:
     """The serving plan: TUNED_PLANS.json winner for ``scale`` on this
     process's devices when available, the single-device batched bitmap
     plan otherwise; ``overrides`` always win (explicit > tuned >
@@ -74,7 +75,7 @@ def resolve_serve_plan(scale: Optional[int] = None,
         from repro.core.tune import tuned_plan
         plan = tuned_plan(scale, overrides=overrides)
     if plan is None:
-        plan = BFSPlan(engine="bitmap", layout=(), batch_roots=True)
+        plan = TraversalPlan(engine="bitmap", layout=(), batch_roots=True)
         if overrides:
             plan = dataclasses.replace(plan, **overrides)
     if not plan.batch_roots:
@@ -87,13 +88,13 @@ class Engine:
 
     ``built`` is a :class:`~repro.core.pipeline.BuiltGraph` (or any
     ``PreparedGraph``-compatible object); ``plan`` an explicit
-    :class:`BFSPlan`, else resolved via :func:`resolve_serve_plan` from
-    ``scale``/``plan_overrides``.  ``fault`` compiles a static
+    :class:`TraversalPlan`, else resolved via :func:`resolve_serve_plan`
+    from ``scale``/``plan_overrides``.  ``fault`` compiles a static
     :class:`~repro.core.faults.FaultSpec` into the engines' injection
     hooks, for exercising the checked-serving path.
     """
 
-    def __init__(self, built, plan: Optional[BFSPlan] = None, *,
+    def __init__(self, built, plan: Optional[TraversalPlan] = None, *,
                  config: Optional[ServeConfig] = None,
                  scale: Optional[int] = None,
                  plan_overrides: Optional[dict] = None,

@@ -60,7 +60,7 @@ print("OK")
 
 def test_distributed_bfs_matches_host_reference():
     out = run_sub(PREAMBLE + """
-from repro.core import (BFSPlan, PreparedGraph, compile_plan,
+from repro.core import (PreparedGraph, TraversalPlan, compile_plan,
                         generate_edges, build_csr, degree_reorder)
 from repro.core.reorder import relabel_edges
 from repro.core.graph_build import csr_to_edge_arrays
@@ -74,8 +74,8 @@ src, dst, valid = (np.asarray(t) for t in csr_to_edge_arrays(g))
 sg = shard_graph(src, dst, valid, g.num_vertices, 8)
 ro, ci = np.asarray(g.row_offsets), np.asarray(g.col_indices)
 for exchange in ("hier_or", "flat"):
-    plan = BFSPlan(layout=("group", "member"), exchange=exchange,
-                   batch_roots=False)
+    plan = TraversalPlan(layout=("group", "member"), exchange=exchange,
+                         batch_roots=False)
     compiled = compile_plan(plan, PreparedGraph(sharded=sg, degree=g.degree),
                             mesh=mesh)
     for root in (0, 5):

@@ -2,7 +2,7 @@
 
 The detection matrix: every fault class from
 :data:`repro.core.faults.FAULT_CLASSES`, injected into the real code
-paths under the five exchange wirings and both vertex partitions, must
+paths under the three exchange wirings and both vertex partitions, must
 be CAUGHT by ``check="full"`` and attributed to the expected named
 check — and combinations where the fault's site is not wired on that
 exchange (plus fully clean runs) must report ZERO failures (no false
@@ -22,7 +22,7 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.core import BFSPlan, PreparedGraph, compile_plan
+from repro.core import PreparedGraph, TraversalPlan, compile_plan
 from repro.core.faults import FAULT_CLASSES, FAULT_KINDS, FAULT_SITES, FaultSpec
 
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -69,23 +69,24 @@ def test_faultspec_validates_site_and_kind():
     assert "level>=2" in f.describe()
     assert hash(f) == hash(FaultSpec(site="parent", kind="self", level=2,
                                      persistent=True))
-    # one class per (site, kind) pair, >= 6 distinct fault classes
-    assert len(FAULT_CLASSES) >= 6
+    # one class per (site, kind) pair: 5 distinct fault classes
+    assert FAULT_SITES == ("exchange", "parent", "inter_group")
+    assert len(FAULT_CLASSES) == 5
     assert FAULT_CLASSES == tuple(
         (s, k) for s in FAULT_SITES for k in FAULT_KINDS[s])
 
 
-def test_fault_rejects_legacy_engines():
+def test_fault_rejects_reference_engine():
     pg = small_graph()
     with pytest.raises(ValueError, match="engine='bitmap'"):
-        compile_plan(BFSPlan(engine="reference", layout=(),
-                             batch_roots=False), pg,
+        compile_plan(TraversalPlan(engine="reference", layout=(),
+                                   batch_roots=False), pg,
                      fault=FaultSpec(site="parent", kind="self"))
 
 
 def test_run_rejects_unknown_check_mode():
     pg = small_graph()
-    c = compile_plan(BFSPlan(layout=(), batch_roots=True), pg)
+    c = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg)
     with pytest.raises(ValueError, match="check must be"):
         c.run(np.arange(2, dtype=np.int32), check="bogus")
 
@@ -100,7 +101,7 @@ def test_validate_batch_matches_per_root_validate():
 
     pg = small_graph()
     roots = np.arange(6, dtype=np.int32)
-    c = compile_plan(BFSPlan(layout=(), batch_roots=True), pg)
+    c = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg)
     res = c.bfs(roots)
     val = validate_batch(pg.ev, res.parent, res.level, roots)
     assert val.ok.shape == (6,)
@@ -119,7 +120,7 @@ def test_failure_report_counts_and_attribution():
 
     pg = small_graph()
     roots = np.arange(4, dtype=np.int32)
-    c = compile_plan(BFSPlan(layout=(), batch_roots=True), pg)
+    c = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg)
     res = c.run(roots, check="post")
     counts = res.run.check_counts
     assert set(CHECK_NAMES) <= set(counts)
@@ -136,7 +137,7 @@ def test_single_device_parent_fault_detected_and_quarantined():
     pg = small_graph()
     roots = np.arange(4, dtype=np.int32)
     f = FaultSpec(site="parent", kind="self", level=1, persistent=True)
-    c = compile_plan(BFSPlan(layout=(), batch_roots=True), pg, fault=f)
+    c = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg, fault=f)
     res = c.run(roots, check="post")
     run = res.run
     assert run.check_counts["depth"] == 4
@@ -157,7 +158,7 @@ def test_single_device_level_scoped_fault_spares_other_roots():
     roots = np.arange(4, dtype=np.int32)
     f = FaultSpec(site="parent", kind="offset", level=1, persistent=True,
                   root=2)
-    c = compile_plan(BFSPlan(layout=(), batch_roots=True), pg, fault=f)
+    c = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg, fault=f)
     run = c.run(roots, check="post").run
     assert set(run.check_failures) == {2}
     assert set(run.check_failures[2]) & {"depth", "tree_edge"}
@@ -170,7 +171,7 @@ def test_clean_full_check_has_zero_false_positives():
     pg = small_graph()
     roots = np.arange(4, dtype=np.int32)
     for batched in (True, False):
-        c = compile_plan(BFSPlan(layout=(), batch_roots=batched), pg)
+        c = compile_plan(TraversalPlan(layout=(), batch_roots=batched), pg)
         run = c.run(roots, check="full").run
         assert run.all_valid
         assert run.check_counts["sentinel"] == 0
@@ -180,7 +181,7 @@ def test_clean_full_check_has_zero_false_positives():
 
 def test_check_off_preserves_legacy_semantics():
     pg = small_graph()
-    c = compile_plan(BFSPlan(layout=(), batch_roots=True), pg)
+    c = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg)
     run = c.run(np.arange(2, dtype=np.int32), check="off").run
     assert run.validated == [] and not run.all_valid
     assert run.check_counts == {} and run.check_failures == {}
@@ -196,7 +197,7 @@ def test_sweep_survives_raising_measurement():
     def boom(compiled, roots, reps):
         raise RuntimeError("injected measurement crash")
 
-    report = tune.sweep(8, plans=[BFSPlan(layout=(), batch_roots=True)],
+    report = tune.sweep(8, plans=[TraversalPlan(layout=(), batch_roots=True)],
                         measure=boom, log=lambda s: None)
     assert report.results == []
     assert len(report.skipped) == 1
@@ -214,9 +215,9 @@ def test_sweep_survives_raising_measurement():
 MATRIX = """
 import os
 import numpy as np
-from repro.core import (BFSPlan, PreparedGraph, build_csr, build_heavy_core,
-                        compile_plan, degree_reorder, edge_view,
-                        generate_edges)
+from repro.core import (PreparedGraph, TraversalPlan, build_csr,
+                        build_heavy_core, compile_plan, degree_reorder,
+                        edge_view, generate_edges)
 from repro.core.faults import FaultSpec
 from repro.core.reorder import relabel_edges
 
@@ -232,17 +233,14 @@ pg = PreparedGraph(ev=ev, degree=g.degree,
                    core=build_heavy_core(g, threshold=32))
 roots = np.array([0], dtype=np.int32)
 
-EXCHANGES = ("hier_or", "hier_gather", "flat", "hier_or_packed",
-             "hier_or_sieve")
+EXCHANGES = ("hier_or", "hier_gather", "flat")
 PARTITIONS = ("block", "word_cyclic")
 
 # which exchanges actually wire each injection site
 ACTIVE = {
     "exchange": set(EXCHANGES),
     "parent": set(EXCHANGES),
-    "codec": {"hier_or_packed", "hier_or_sieve"},
-    "inter_group": {"hier_or", "hier_or_packed", "hier_or_sieve"},
-    "sieve": {"hier_or_sieve"},
+    "inter_group": {"hier_or"},
 }
 
 SPECS = {
@@ -254,16 +252,8 @@ SPECS = {
                                   level=1, persistent=True),
     ("parent", "offset"): FaultSpec(site="parent", kind="offset",
                                     level=1, persistent=True),
-    ("codec", "payload_flip"): FaultSpec(site="codec", kind="payload_flip",
-                                         level=1, persistent=True, seed=3),
-    ("codec", "trunc_count"): FaultSpec(site="codec", kind="trunc_count",
-                                        level=1, persistent=True),
-    ("codec", "wrong_mode"): FaultSpec(site="codec", kind="wrong_mode",
-                                       level=1, persistent=True),
     ("inter_group", "drop"): FaultSpec(site="inter_group", kind="drop",
                                        level=1, persistent=True),
-    ("sieve", "stale"): FaultSpec(site="sieve", kind="stale",
-                                  level=1, persistent=True),
 }
 
 # expected attribution: ("subset", S) = S must be among the failed
@@ -273,31 +263,19 @@ EXPECT = {
     ("exchange", "flip"): ("exact", {"sentinel"}),
     ("parent", "self"): ("subset", {"depth"}),
     ("parent", "offset"): ("any", {"depth", "tree_edge"}),
-    ("codec", "payload_flip"): ("any", None),
-    ("codec", "trunc_count"): ("any", None),
-    ("codec", "wrong_mode"): ("any", None),
     ("inter_group", "drop"): ("any", None),
-    ("sieve", "stale"): ("subset", {"component", "sentinel"}),
 }
 
 
 def harmless_allowed(cls, ex, part):
-    # Content-dependent combos where the injected corruption can be
+    # The content-dependent combo where the injected corruption can be
     # PROVABLY consequence-free (asserted below: zero failures AND
     # parents bitwise equal to the clean run) rather than detected:
-    #   * inter_group/drop under the block partition — w_loc is padded
-    #     to the kernel tile, so at matrix scales device 0 owns every
-    #     real vertex and the dropped non-first-group legs carry only
-    #     padding words;
-    #   * exchange/flip under hier_or_sieve — the flip targets the
-    #     root's bit, and the visited sieve strips already-known bits
-    #     off the wire before the codec leg (masking IS the sieve's
-    #     job).
-    if cls == ("inter_group", "drop") and part == "block":
-        return True
-    if cls == ("exchange", "flip") and ex == "hier_or_sieve":
-        return True
-    return False
+    # inter_group/drop under the block partition — w_loc is padded to
+    # the kernel tile, so at matrix scales device 0 owns every real
+    # vertex and the dropped non-first-group legs carry only padding
+    # words.
+    return cls == ("inter_group", "drop") and part == "block"
 
 if full:
     cases = [(cls, ex, part) for cls in SPECS
@@ -313,13 +291,9 @@ else:
         (("exchange", "flip"), "hier_or", "word_cyclic"),
         (("parent", "self"), "flat", "block"),
         (("parent", "offset"), "hier_gather", "word_cyclic"),
-        (("codec", "payload_flip"), "hier_or_packed", "block"),
-        (("codec", "trunc_count"), "hier_or_sieve", "word_cyclic"),
-        (("codec", "wrong_mode"), "hier_or_packed", "word_cyclic"),
         (("inter_group", "drop"), "hier_or", "word_cyclic"),
-        (("sieve", "stale"), "hier_or_sieve", "block"),
-        (("codec", "payload_flip"), "flat", "block"),      # inactive
-        (("sieve", "stale"), "hier_or", "word_cyclic"),    # inactive
+        (("inter_group", "drop"), "flat", "block"),              # inactive
+        (("inter_group", "drop"), "hier_gather", "word_cyclic"),  # inactive
     ]
     clean_cases = [("hier_or", "block")]
 
@@ -329,8 +303,8 @@ n_detected = n_clean = n_harmless = 0
 # harmless-combo escape below compares against
 clean_parent = {}
 for (ex, part) in clean_cases:
-    plan = BFSPlan(layout=("group", "member"), mesh_shape=(2, 2),
-                   exchange=ex, partition=part, batch_roots=True)
+    plan = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 2),
+                         exchange=ex, partition=part, batch_roots=True)
     c = compile_plan(plan, pg)
     for mode in ("post", "full"):
         res = c.run(roots, check=mode, warmup=False)
@@ -342,8 +316,8 @@ for (ex, part) in clean_cases:
     print(f"CLEAN    none x {ex} x {part}")
 
 for (cls, ex, part) in cases:
-    plan = BFSPlan(layout=("group", "member"), mesh_shape=(2, 2),
-                   exchange=ex, partition=part, batch_roots=True)
+    plan = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 2),
+                         exchange=ex, partition=part, batch_roots=True)
     c = compile_plan(plan, pg, fault=SPECS[cls])
     res = c.run(roots, check="full", warmup=False)
     run = res.run
@@ -392,7 +366,7 @@ def test_sharded_detection_matrix():
     # the reduced matrix detects every fault class once (no harmless
     # escapes: its combos are pinned to deterministically-detecting
     # wirings), plus 3 clean legs
-    assert "detected=9 clean=3 harmless=0" in out
+    assert "detected=5 clean=3 harmless=0" in out
 
 
 # ---------------------------------------------------------------------------
@@ -401,7 +375,7 @@ def test_sharded_detection_matrix():
 
 RECOVERY = """
 import numpy as np
-from repro.core import (BFSPlan, PreparedGraph, build_csr, compile_plan,
+from repro.core import (PreparedGraph, TraversalPlan, build_csr, compile_plan,
                         degree_reorder, edge_view, generate_edges)
 from repro.core.faults import FaultSpec
 from repro.core.reorder import relabel_edges
@@ -413,10 +387,10 @@ g = build_csr(relabel_edges(edges, r))
 ev = edge_view(g)
 pg = PreparedGraph(ev=ev, degree=g.degree, core=None)
 roots = np.arange(4, dtype=np.int32)
-plan = BFSPlan(layout=("group", "member"), mesh_shape=(2, 2),
-               batch_roots=True)
+plan = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 2),
+                     batch_roots=True)
 
-oracle = compile_plan(BFSPlan(layout=(), batch_roots=True), pg)
+oracle = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg)
 base = oracle.run(roots, check="post")
 assert base.run.all_valid
 

@@ -6,10 +6,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import (
-    BFSPlan, Graph500Config, PreparedGraph, build, build_csr,
-    build_heavy_core, chunk_edge_view, compile_plan, degree_reorder,
-    edge_view, generate_edges, pack_bitmap, run, sample_roots,
-    unpack_bitmap, validate,
+    BFSResult, Graph500Config, PreparedGraph, TraversalPlan, build,
+    build_csr, build_heavy_core, chunk_edge_view, compile_plan,
+    degree_reorder, edge_view, generate_edges, pack_bitmap, run,
+    sample_roots, unpack_bitmap, validate,
 )
 from repro.core.graph_build import csr_to_edge_arrays
 from repro.core.heavy import heavy_count
@@ -26,22 +26,20 @@ def small_graph():
     return edges, g
 
 
-# hybrid_bfs / bfs_batch-shaped conveniences routed through the plan API
-# (the deprecated shims themselves are exercised in tests/test_plan.py;
-# DeprecationWarnings from repro.* are errors under this suite's
-# filterwarnings config).
+# Per-root and batched conveniences over the plan API.
 
 def plan_bfs(ev, degree, root, *, core=None, engine="reference",
              alpha=14.0, beta=24.0, max_levels=64, chunks=None,
              n_chunks=64):
-    p = BFSPlan(engine=engine, layout=(), batch_roots=False, alpha=alpha,
-                beta=beta, max_levels=max_levels, n_chunks=n_chunks)
+    p = TraversalPlan(engine=engine, layout=(), batch_roots=False,
+                      alpha=alpha, beta=beta, max_levels=max_levels,
+                      n_chunks=n_chunks)
     return compile_plan(p, PreparedGraph(
         ev=ev, degree=degree, core=core, chunks=chunks)).bfs(root)
 
 
 def plan_batch(ev, degree, roots, *, core=None, chunks=None):
-    p = BFSPlan(layout=(), batch_roots=True)
+    p = TraversalPlan(layout=(), batch_roots=True)
     return compile_plan(p, PreparedGraph(
         ev=ev, degree=degree, core=core, chunks=chunks)).bfs(roots)
 
@@ -159,7 +157,7 @@ def test_bitmap_pack_unpack_roundtrip():
 
 
 @pytest.mark.parametrize("engine,threshold", [
-    ("reference", None), ("legacy", 8), ("bitmap", 8), ("bitmap", 4)])
+    ("reference", None), ("bitmap", 8), ("bitmap", 4)])
 @pytest.mark.parametrize("scale", [8, 10])
 def test_hybrid_bfs_matches_host_oracle(engine, threshold, scale):
     edges = generate_edges(11, scale)
@@ -175,6 +173,46 @@ def test_hybrid_bfs_matches_host_oracle(engine, threshold, scale):
         np.testing.assert_array_equal(np.asarray(res.level), l_ref,
                                       err_msg=f"root={root}")
         val = validate(ev, res, jnp.int32(root))
+        assert bool(val.ok), {k: bool(getattr(val, k)) for k in val._fields}
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["per_root", "batch"])
+@pytest.mark.parametrize("family", ["grid", "erdos_renyi"])
+def test_bitmap_engine_matches_host_oracle_on_family(family, batched):
+    """Graphs that break the Kronecker assumptions: the 2-D grid is
+    high-diameter (long top-down stretches, tiny frontiers), the
+    Erdős–Rényi graph has no heavy tail.  Both stay under the heavy
+    threshold, so the dense core is bypassed as ``pipeline.build`` does
+    for them; levels must equal the host BFS and every tree validate."""
+    from repro.data.graphs import erdos_renyi_graph, grid_graph
+
+    el = (grid_graph(20, seed=5) if family == "grid"
+          else erdos_renyi_graph(400, avg_degree=6, seed=7))
+    g0 = build_csr(el)
+    g = build_csr(relabel_edges(el, degree_reorder(g0.degree)))
+    assert int(heavy_count(g.degree, 100)) == 0
+    ev = edge_view(g)
+    ro, ci = np.asarray(g.row_offsets), np.asarray(g.col_indices)
+    roots = np.array([0, 3, 11], np.int32)
+    if batched:
+        res = plan_batch(ev, g.degree, roots)
+        rows = [(res.parent[i], res.level[i]) for i in range(len(roots))]
+    else:
+        rows = []
+        for root in roots:
+            r1 = plan_bfs(ev, g.degree, int(root), engine="bitmap")
+            rows.append((r1.parent, r1.level))
+            if family == "grid" and root == 0:
+                lv = int(r1.stats.levels)
+                dirs = np.asarray(r1.stats.direction)[:lv]
+                assert lv > 20, lv
+                assert (dirs[:8] == 0).all(), dirs.tolist()
+    for root, (parent, level) in zip(roots, rows):
+        _, l_ref = reference_bfs(ro, ci, int(root))
+        np.testing.assert_array_equal(np.asarray(level), l_ref,
+                                      err_msg=f"root={root}")
+        val = validate(ev, BFSResult(parent=parent, level=level, stats=None),
+                       jnp.int32(root))
         assert bool(val.ok), {k: bool(getattr(val, k)) for k in val._fields}
 
 
@@ -334,27 +372,36 @@ def test_bottom_up_pull_matches_push(case, visited):
 def test_bitmap_engine_never_packs_inside_loop(monkeypatch):
     """Zero pack_bitmap calls in the bitmap engine's traced program: the
     resident frontier/visited state never round-trips through bool (the
-    epilogue packs only the per-level delta — DESIGN.md §3 I3).  The
-    legacy engine, by contrast, packs the frontier every BU level."""
-    import importlib
-    # repro.core re-exports the hybrid_bfs *function*, shadowing the
-    # submodule attribute — resolve the module itself.
-    hb = importlib.import_module("repro.core.hybrid_bfs")
+    epilogue packs only the per-level delta — DESIGN.md §3 I3).  Every
+    loaded ``repro`` module's binding of the symbol is instrumented, so
+    a ``from ... import pack_bitmap`` anywhere in the library is caught;
+    a traced program that does pack is the liveness control."""
+    import sys
+
+    from repro.core import heavy
+
     g, ev, core, chunks = _sorted_graph(9)
     calls = []
-    real = hb.pack_bitmap
+    real = heavy.pack_bitmap
 
     def counting(*a, **k):
         calls.append(1)
         return real(*a, **k)
 
-    monkeypatch.setattr(hb, "pack_bitmap", counting)
+    bound = [m for name, m in list(sys.modules.items())
+             if name.startswith("repro")
+             and getattr(m, "pack_bitmap", None) is real]
+    assert heavy in bound
+    for m in bound:
+        monkeypatch.setattr(m, "pack_bitmap", counting)
     # unusual max_levels forces a fresh trace while the counter is active
     res = plan_bfs(ev, g.degree, 0, core=core, engine="bitmap",
                      chunks=chunks, max_levels=61)
     assert bool(validate(ev, res, jnp.int32(0)).ok)
     assert len(calls) == 0, "bitmap engine packed inside the loop"
-    plan_bfs(ev, g.degree, 0, core=core, engine="legacy", max_levels=61)
+    w = heavy.padded_bitmap_words(g.num_vertices)
+    jax.jit(lambda m: heavy.pack_bitmap(m, w))(
+        jnp.zeros((g.num_vertices,), bool))
     assert len(calls) > 0, "instrumentation dead — counter never fired"
 
 
@@ -413,7 +460,7 @@ def test_batched_runner_reports_harmonic_mean():
     ev = edge_view(g)
     roots = np.asarray(r.new_from_old)[np.asarray(sample_roots(3, edges, 8))]
     g500 = compile_plan(
-        BFSPlan(layout=(), batch_roots=True),
+        TraversalPlan(layout=(), batch_roots=True),
         PreparedGraph(ev=ev, degree=g.degree, core=core)).run(roots).run
     assert g500.batched
     assert len(g500.teps) == len(roots)

@@ -2,7 +2,7 @@
 
 The gate matches rungs on name + plan dict + interpret mode.  Plan
 dicts are compared after default-filling missing fields with the
-current BFSPlan defaults, so growing the plan schema (the v2
+current TraversalPlan defaults, so growing the plan schema (the v2
 ``partition`` axis) does not zero-match every committed baseline —
 while a field present on both sides with different values still
 mismatches (a partition flip IS a plan change).
@@ -20,7 +20,7 @@ from benchmarks.check_regression import (  # noqa: E402
     compare,
     normalize_plan,
 )
-from repro.core.plan import BFSPlan  # noqa: E402
+from repro.core.plan import TraversalPlan  # noqa: E402
 
 
 def _doc(plan_dict, teps=1000.0):
@@ -51,8 +51,8 @@ def test_normalize_plan_fills_current_defaults():
     filled = normalize_plan({"layout": ["group", "member"]})
     assert filled["partition"] == "block"
     assert filled["engine"] == "bitmap"
-    # every BFSPlan field is present after the fill
-    assert set(filled) >= set(BFSPlan().to_dict())
+    # every TraversalPlan field is present after the fill
+    assert set(filled) >= set(TraversalPlan().to_dict())
     # an explicit value survives the fill
     assert normalize_plan({"partition": "word_cyclic"})["partition"] == \
         "word_cyclic"
@@ -61,9 +61,11 @@ def test_normalize_plan_fills_current_defaults():
 def test_pre_partition_baseline_still_matches():
     """A baseline recorded before the partition field existed gates
     against a current rung carrying partition='block'."""
-    old_plan = BFSPlan(layout=("group", "member"), mesh_shape=(4, 2)).to_dict()
+    old_plan = TraversalPlan(layout=("group", "member"),
+                             mesh_shape=(4, 2)).to_dict()
     old_plan.pop("partition")
-    new_plan = BFSPlan(layout=("group", "member"), mesh_shape=(4, 2)).to_dict()
+    new_plan = TraversalPlan(layout=("group", "member"),
+                             mesh_shape=(4, 2)).to_dict()
     base = collect_rungs(_doc(old_plan, teps=1000.0))
     cur = collect_rungs(_doc(new_plan, teps=990.0), only_fresh=True)
     regressions, matched, unmatched = compare(base, cur, 0.25)
@@ -77,9 +79,10 @@ def test_pre_partition_baseline_still_matches():
 def test_partition_flip_is_a_plan_change_not_a_match():
     """Fields present on BOTH sides with different values must not be
     papered over by the default fill."""
-    block = BFSPlan(layout=("group", "member"), mesh_shape=(4, 2)).to_dict()
-    cyc = BFSPlan(layout=("group", "member"), mesh_shape=(4, 2),
-                  partition="word_cyclic").to_dict()
+    block = TraversalPlan(layout=("group", "member"),
+                          mesh_shape=(4, 2)).to_dict()
+    cyc = TraversalPlan(layout=("group", "member"), mesh_shape=(4, 2),
+                        partition="word_cyclic").to_dict()
     base = collect_rungs(_doc(block))
     cur = collect_rungs(_doc(cyc), only_fresh=True)
     regressions, matched, unmatched = compare(base, cur, 0.25)
@@ -95,10 +98,9 @@ def test_unknown_exchange_rejected_with_valid_values():
     from repro.core.hybrid_bfs import SHARD_EXCHANGES
     from repro.core.plan import validate_plan
 
-    assert "hier_or_packed" in SHARD_EXCHANGES
-    assert "hier_or_sieve" in SHARD_EXCHANGES
-    plan = BFSPlan(layout=("group", "member"), mesh_shape=(4, 2),
-                   exchange="hier_or_zstd")
+    assert SHARD_EXCHANGES == ("hier_or", "hier_gather", "flat")
+    plan = TraversalPlan(layout=("group", "member"), mesh_shape=(4, 2),
+                         exchange="hier_or_zstd")
     with pytest.raises(ValueError) as e:
         validate_plan(plan)
     msg = str(e.value)
@@ -112,16 +114,17 @@ def test_pre_codec_baseline_default_fills_and_new_rung_not_gated():
     default-fills and gates its hier_or rung, while a NEW-exchange rung
     absent from the baseline reports as unmatched (not gated), never as
     a regression."""
-    old_plan = BFSPlan(layout=("group", "member"), mesh_shape=(4, 2)).to_dict()
+    old_plan = TraversalPlan(layout=("group", "member"),
+                             mesh_shape=(4, 2)).to_dict()
     old_plan.pop("partition")          # pre-v2 baseline shape
     base = collect_rungs(_doc(old_plan, teps=1000.0))
 
     # current run carries the old rung plus a fresh 4x2_sieve rung
-    doc = _doc(BFSPlan(layout=("group", "member"), mesh_shape=(4, 2))
+    doc = _doc(TraversalPlan(layout=("group", "member"), mesh_shape=(4, 2))
                .to_dict(), teps=990.0)
     scale = doc["modules"]["bfs_sharded"]["by_scale"]["12"]
-    sieve_plan = BFSPlan(layout=("group", "member"), mesh_shape=(4, 2),
-                         exchange="hier_or_sieve").to_dict()
+    sieve_plan = TraversalPlan(layout=("group", "member"), mesh_shape=(4, 2),
+                               exchange="hier_or_sieve").to_dict()
     scale["vertex_sharded"]["4x2_sieve"] = {
         "plan": sieve_plan, "harmonic_mean_teps": 10.0}
     scale["rungs_from_this_run"] = ["4x2", "4x2_sieve"]
@@ -136,7 +139,7 @@ def test_pre_codec_baseline_default_fills_and_new_rung_not_gated():
 def test_old_baseline_vs_old_current_unaffected():
     """Two pre-partition docs (the committed trajectory before this PR)
     still compare exactly as before the default fill existed."""
-    plan = BFSPlan(layout=("root",), mesh_shape=(4,)).to_dict()
+    plan = TraversalPlan(layout=("root",), mesh_shape=(4,)).to_dict()
     plan.pop("partition")
     base = collect_rungs(_doc(plan, teps=500.0))
     cur = collect_rungs(_doc(copy.deepcopy(plan), teps=500.0),
@@ -173,7 +176,7 @@ def test_latency_rung_gates_lower_is_better():
     INVERTED — a p99 increase past the latency threshold fails, a
     decrease never does (it would be a 'regression' under the TEPS
     rule)."""
-    plan = BFSPlan(layout=(), batch_roots=True).to_dict()
+    plan = TraversalPlan(layout=(), batch_roots=True).to_dict()
     base = collect_rungs(_serve_doc(plan, p99=1.0))
     assert base == {"bfs_serve/scale12/serve_steady/p99": {
         "plan": plan, "interpret_mode": True,
@@ -199,7 +202,7 @@ def test_first_run_serve_rung_unmatched_not_gated():
     """Satellite: a serve rung absent from the committed baseline (the
     first run after this subsystem lands) reports as unmatched — it
     must neither fail nor count toward the vacuity check."""
-    plan = BFSPlan(layout=(), batch_roots=True).to_dict()
+    plan = TraversalPlan(layout=(), batch_roots=True).to_dict()
     base = collect_rungs(_doc(plan, teps=1000.0))     # sharded-only baseline
     cur = collect_rungs(_serve_doc(plan), only_fresh=True)
     regressions, matched, unmatched = compare(base, cur, 0.25, 0.5)
@@ -211,9 +214,9 @@ def test_first_run_serve_rung_unmatched_not_gated():
 def test_serve_rung_default_fills_plan_like_teps_rungs():
     """The default-fill plan matching applies to latency rungs too: a
     baseline recorded before a plan field existed still gates."""
-    old_plan = BFSPlan(layout=(), batch_roots=True).to_dict()
+    old_plan = TraversalPlan(layout=(), batch_roots=True).to_dict()
     old_plan.pop("partition")
-    new_plan = BFSPlan(layout=(), batch_roots=True).to_dict()
+    new_plan = TraversalPlan(layout=(), batch_roots=True).to_dict()
     base = collect_rungs(_serve_doc(old_plan, p99=1.0))
     cur = collect_rungs(_serve_doc(new_plan, p99=1.1), only_fresh=True)
     regressions, matched, unmatched = compare(base, cur, 0.25, 0.5)
@@ -249,10 +252,12 @@ def test_pre_kernel_baseline_default_fills_and_gates():
     ``kernel`` plan field existed still matches a current BFS rung that
     carries ``kernel="bfs"`` — adding the kernel axis must not
     zero-match every committed BFS baseline."""
-    old_plan = BFSPlan(layout=("group", "member"), mesh_shape=(4, 2)).to_dict()
+    old_plan = TraversalPlan(layout=("group", "member"),
+                             mesh_shape=(4, 2)).to_dict()
     assert old_plan["kernel"] == "bfs"
     old_plan.pop("kernel")             # pre-§16 baseline shape
-    new_plan = BFSPlan(layout=("group", "member"), mesh_shape=(4, 2)).to_dict()
+    new_plan = TraversalPlan(layout=("group", "member"),
+                             mesh_shape=(4, 2)).to_dict()
     base = collect_rungs(_doc(old_plan, teps=1000.0))
     cur = collect_rungs(_doc(new_plan, teps=990.0), only_fresh=True)
     regressions, matched, unmatched = compare(base, cur, 0.25)
@@ -264,13 +269,13 @@ def test_sssp_rungs_collect_and_gate_separately():
     ``sssp/`` names and gate against sssp baselines only — on first run
     they report unmatched (not gated), and a kernel flip on an
     identically-named rung is a plan change, never a match."""
-    sssp_plan = BFSPlan(layout=("group", "member"), mesh_shape=(2, 2),
-                        exchange="hier_min", kernel="sssp").to_dict()
+    sssp_plan = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 2),
+                              exchange="hier_min", kernel="sssp").to_dict()
     cur = collect_rungs(_sssp_doc(sssp_plan, teps=500.0), only_fresh=True)
     assert set(cur) == {"sssp/scale12/2x2_min"}
 
     # first run: no sssp baseline -> unmatched, vacuity-neutral
-    bfs_base = collect_rungs(_doc(BFSPlan(
+    bfs_base = collect_rungs(_doc(TraversalPlan(
         layout=("group", "member"), mesh_shape=(4, 2)).to_dict()))
     regressions, matched, unmatched = compare(bfs_base, cur, 0.25)
     assert not regressions and not matched

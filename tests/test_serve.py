@@ -331,7 +331,7 @@ def test_engine_serve_matches_offline_run_single_device():
 
 
 def test_serve_batch_primitive_matches_run():
-    from repro.core import (BFSPlan, PreparedGraph, build_csr,
+    from repro.core import (PreparedGraph, TraversalPlan, build_csr,
                             build_heavy_core, chunk_edge_view, compile_plan,
                             degree_reorder, edge_view, generate_edges)
     from repro.core.reorder import relabel_edges
@@ -344,7 +344,7 @@ def test_serve_batch_primitive_matches_run():
     pg = PreparedGraph(ev=ev, degree=g.degree,
                        core=build_heavy_core(g, threshold=8),
                        chunks=chunk_edge_view(ev))
-    compiled = compile_plan(BFSPlan(layout=(), batch_roots=True), pg)
+    compiled = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg)
     roots = np.asarray([1, 5, 1, 9], np.int32)
     sb = compiled.serve_batch(roots, check="post")
     res = compiled.run(roots, warmup=False, check="post")
@@ -359,23 +359,23 @@ def test_serve_batch_primitive_matches_run():
 
 
 def test_resolve_serve_plan_forces_batching_and_overrides_win():
-    from repro.core.plan import BFSPlan
+    from repro.core.plan import TraversalPlan
     from repro.serve.engine import resolve_serve_plan
 
     p = resolve_serve_plan()            # no scale -> untuned default
     assert p.batch_roots and p.engine == "bitmap" and p.layout == ()
     p = resolve_serve_plan(overrides={"alpha": 7.0, "batch_roots": False})
     assert p.alpha == 7.0 and p.batch_roots  # batching always forced
-    assert BFSPlan(**p.to_dict()) == p
+    assert TraversalPlan(**p.to_dict()) == p
 
 
 # --------------------------------------- engine (8-device subprocess)
 
 SUB_PREAMBLE = """
 import numpy as np
-from repro.core import (BFSPlan, PreparedGraph, build_csr, build_heavy_core,
-                        chunk_edge_view, compile_plan, degree_reorder,
-                        edge_view, generate_edges)
+from repro.core import (PreparedGraph, TraversalPlan, build_csr,
+                        build_heavy_core, chunk_edge_view, compile_plan,
+                        degree_reorder, edge_view, generate_edges)
 from repro.core.reorder import relabel_edges
 from repro.data.query_trace import synth_trace
 from repro.serve.engine import Engine, ServeConfig
@@ -400,8 +400,8 @@ def test_engine_serve_bitwise_parity_meshes_and_partitions():
     run_sub(SUB_PREAMBLE + """
 for shape in ((2, 2), (4, 2)):
     for partition in ("block", "word_cyclic"):
-        plan = BFSPlan(layout=("group", "member"), mesh_shape=shape,
-                       partition=partition)
+        plan = TraversalPlan(layout=("group", "member"), mesh_shape=shape,
+                             partition=partition)
         engine = Engine(pg, plan=plan, config=ServeConfig(
             batch_size=4, max_wait_s=0.01, cache_capacity=16))
         report = engine.serve(trace)
@@ -433,7 +433,7 @@ def test_faulted_engine_requeues_and_recovers_via_fallback():
 from repro.core.faults import FaultSpec
 
 fault = FaultSpec(site="exchange", kind="zero", level=1, persistent=True)
-plan = BFSPlan(layout=("group", "member"), mesh_shape=(2, 2))
+plan = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 2))
 engine = Engine(pg, plan=plan, config=ServeConfig(
     batch_size=4, max_wait_s=0.01, cache_capacity=16,
     max_requeues=2, fallback_on_requeue=True), fault=fault)
@@ -448,7 +448,7 @@ s = report.summary()
 assert sum(s["check_counts"].values()) > 0  # detections were recorded
 
 # the recovered answers match the clean single-device oracle
-clean = compile_plan(BFSPlan(layout=(), batch_roots=True), pg)
+clean = compile_plan(TraversalPlan(layout=(), batch_roots=True), pg)
 uniq = sorted({a.root for a in report.answers})
 res = clean.run(np.asarray(uniq, np.int32), warmup=False, check="post")
 idx = {r: i for i, r in enumerate(uniq)}

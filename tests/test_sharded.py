@@ -30,9 +30,9 @@ def run_sub(code: str, extra_env: dict | None = None) -> str:
 PREAMBLE = """
 import numpy as np
 import jax, jax.numpy as jnp
-from repro.core import (BFSPlan, PreparedGraph, build_csr, build_heavy_core,
-                        chunk_edge_view, compile_plan, degree_reorder,
-                        edge_view, generate_edges)
+from repro.core import (PreparedGraph, TraversalPlan, build_csr,
+                        build_heavy_core, chunk_edge_view, compile_plan,
+                        degree_reorder, edge_view, generate_edges)
 from repro.core.graph_build import csr_to_edge_arrays
 from repro.core.reorder import relabel_edges
 from repro.util import make_mesh
@@ -46,23 +46,22 @@ def sorted_graph(scale, seed=11, threshold=32):
     ev = edge_view(g)
     return g, ev, core, chunk_edge_view(ev)
 
-# plan-API conveniences (the deprecated shims these tests used to route
-# through are exercised in tests/test_plan.py)
+# plan-API conveniences
 
 def plan_bfs(ev, degree, root, *, core=None, chunks=None):
-    p = BFSPlan(engine="bitmap", layout=(), batch_roots=False)
+    p = TraversalPlan(engine="bitmap", layout=(), batch_roots=False)
     return compile_plan(p, PreparedGraph(ev=ev, degree=degree, core=core,
                                          chunks=chunks)).bfs(root)
 
 def plan_batch(ev, degree, roots, *, core=None, chunks=None):
-    p = BFSPlan(layout=(), batch_roots=True)
+    p = TraversalPlan(layout=(), batch_roots=True)
     return compile_plan(p, PreparedGraph(ev=ev, degree=degree, core=core,
                                          chunks=chunks)).bfs(roots)
 
 def vertex_plan(mesh, sg, *, core=None, degree=None, ev=None,
                 exchange="hier_or", batched=False):
-    p = BFSPlan(layout=("group", "member"), exchange=exchange,
-                batch_roots=batched)
+    p = TraversalPlan(layout=("group", "member"), exchange=exchange,
+                      batch_roots=batched)
     return compile_plan(p, PreparedGraph(ev=ev, degree=degree, core=core,
                                          sharded=sg), mesh=mesh)
 """
@@ -77,13 +76,13 @@ roots = np.arange(64, dtype=np.int32)
 base = plan_batch(ev, g.degree, roots, core=core, chunks=chunks)
 pg = PreparedGraph(ev=ev, degree=g.degree, core=core, chunks=chunks)
 mesh = make_mesh((4,), ("root",))
-res = compile_plan(BFSPlan(layout=("root",)), pg, mesh=mesh).bfs(roots)
+res = compile_plan(TraversalPlan(layout=("root",)), pg, mesh=mesh).bfs(roots)
 assert np.array_equal(np.asarray(res.parent), np.asarray(base.parent))
 assert np.array_equal(np.asarray(res.level), np.asarray(base.level))
 assert np.array_equal(np.asarray(res.stats.levels),
                       np.asarray(base.stats.levels))
 # root count not a multiple of the axis: padded and sliced
-res10 = compile_plan(BFSPlan(layout=("root",)), pg,
+res10 = compile_plan(TraversalPlan(layout=("root",)), pg,
                      mesh=make_mesh((8,), ("root",))).bfs(roots[:10])
 assert res10.parent.shape[0] == 10
 assert np.array_equal(np.asarray(res10.parent),
@@ -129,8 +128,8 @@ g, ev, core, chunks = sorted_graph(12, seed=11, threshold=32)
 pg = PreparedGraph(ev=ev, degree=g.degree, core=core, chunks=chunks)
 V = g.num_vertices
 for shape in ((2, 1), (2, 2), (4, 2)):
-    plan = BFSPlan(layout=("group", "member"), mesh_shape=shape,
-                   partition="word_cyclic", batch_roots=False)
+    plan = TraversalPlan(layout=("group", "member"), mesh_shape=shape,
+                         partition="word_cyclic", batch_roots=False)
     compiled = compile_plan(plan, pg)
     for root in (0, 17):
         res = compiled.bfs(root)
@@ -278,8 +277,8 @@ for shape in ((3, 1), (1, 5)):
     assert sg.num_vertices > g.num_vertices  # padded tail exists
     # non-pow2 members are allowed through a caller-supplied mesh=
     mesh = make_mesh(shape, ("group", "member"))
-    plan = BFSPlan(layout=("group", "member"), partition=part,
-                   batch_roots=False)
+    plan = TraversalPlan(layout=("group", "member"), partition=part,
+                         batch_roots=False)
     res = compile_plan(plan, PreparedGraph(core=core, sharded=sg,
                                            degree=g.degree),
                        mesh=mesh).bfs(0)
@@ -295,26 +294,21 @@ print("OK")
 
 
 def test_exchange_wirings_bit_identical():
-    """hier_or (two-phase OR reduction), hier_gather (monitor all-gather),
-    flat all-gather, and the §12 wire-codec variants hier_or_packed
-    (density-adaptive codec) and hier_or_sieve (visited-sieve then pack)
-    must produce the same traversal — under BOTH vertex partitions (the
-    cyclic owner map makes the hier_or scatter strided and transposes
-    the gathered device-major blocks)."""
+    """hier_or (two-phase OR reduction), hier_gather (monitor all-gather)
+    and the flat all-gather must produce the same traversal — under
+    BOTH vertex partitions (the cyclic owner map makes the hier_or
+    scatter strided and transposes the gathered device-major blocks)."""
     out = run_sub(PREAMBLE + """
-import warnings
-from repro.core.distributed_bfs import shard_graph, make_dist_bfs, gather_result
+from repro.core.distributed_bfs import shard_graph
 g, ev, core, chunks = sorted_graph(10, seed=3, threshold=8)
 src, dst, valid = (np.asarray(t) for t in csr_to_edge_arrays(g))
-sg = shard_graph(src, dst, valid, g.num_vertices, 8)
 mesh = make_mesh((2, 4), ("group", "member"))
 results = {}
 for part in ("block", "word_cyclic"):
     sg_p = shard_graph(src, dst, valid, g.num_vertices, 8, partition=part)
-    for exch in ("hier_or", "hier_gather", "flat",
-                 "hier_or_packed", "hier_or_sieve"):
-        plan = BFSPlan(layout=("group", "member"), exchange=exch,
-                       partition=part, batch_roots=False)
+    for exch in ("hier_or", "hier_gather", "flat"):
+        plan = TraversalPlan(layout=("group", "member"), exchange=exch,
+                             partition=part, batch_roots=False)
         res = compile_plan(plan, PreparedGraph(core=core, sharded=sg_p,
                                                degree=g.degree),
                            mesh=mesh).bfs(5)
@@ -324,32 +318,28 @@ ref_p, ref_l = results[("block", "hier_or")]
 for key, (p, l) in results.items():
     assert np.array_equal(p, ref_p), key
     assert np.array_equal(l, ref_l), key
-# legacy-compat flag still routes: hierarchical=False -> flat (the one
-# intentional shim call here; its DeprecationWarning is acknowledged)
-with warnings.catch_warnings():
-    warnings.simplefilter("ignore", DeprecationWarning)
-    bfs = make_dist_bfs(mesh, sg, hierarchical=False, core=core)
-p, l = gather_result(bfs(jnp.int32(5)), sg)
-assert np.array_equal(p, ref_p)
 print("OK")
 """)
     assert "OK" in out
 
 
-def test_codec_exchanges_bit_identical_across_meshes():
-    """Tentpole acceptance: hier_or_packed and hier_or_sieve are
-    bitwise-identical to the single-device bitmap engine across meshes
-    2x1 / 2x2 / 4x2 under BOTH vertex partitions."""
-    out = run_sub(PREAMBLE + """
+@pytest.mark.parametrize("exchange", ["hier_or", "hier_gather", "flat"])
+def test_exchanges_bit_identical_across_meshes(exchange):
+    """Every BFS exchange is bitwise-identical to the single-device
+    bitmap engine across meshes 2x1 / 2x2 / 4x2 under BOTH vertex
+    partitions."""
+    out = run_sub(PREAMBLE + f"""
+exch = {exchange!r}
+""" + """
 g, ev, core, chunks = sorted_graph(10, seed=3, threshold=8)
 pg = PreparedGraph(ev=ev, degree=g.degree, core=core, chunks=chunks)
 V = g.num_vertices
 single = plan_bfs(ev, g.degree, 5, core=core, chunks=chunks)
 for shape in ((2, 1), (2, 2), (4, 2)):
-  for part in ("block", "word_cyclic"):
-    for exch in ("hier_or_packed", "hier_or_sieve"):
-        plan = BFSPlan(layout=("group", "member"), mesh_shape=shape,
-                       exchange=exch, partition=part, batch_roots=False)
+    for part in ("block", "word_cyclic"):
+        plan = TraversalPlan(layout=("group", "member"), mesh_shape=shape,
+                             exchange=exch, partition=part,
+                             batch_roots=False)
         res = compile_plan(plan, pg).bfs(5)
         parent, level = np.asarray(res.parent), np.asarray(res.level)
         key = (shape, part, exch)
@@ -361,12 +351,15 @@ print("OK")
     assert "OK" in out
 
 
-def test_codec_exchanges_nondividing_and_composed():
-    """Tentpole acceptance: the wire-codec exchanges survive word counts
-    that do NOT divide the device count ((3,1) and (1,5) meshes take the
-    non-dividing member fallback) and the composed 3-axis
-    (root, group, member) 2x2x2 layout."""
-    out = run_sub(PREAMBLE + """
+@pytest.mark.parametrize("exchange", ["hier_or", "hier_gather", "flat"])
+def test_exchanges_nondividing_and_composed(exchange):
+    """Every BFS exchange survives word counts that do NOT divide the
+    device count ((3,1) and (1,5) meshes take the non-dividing member
+    fallback) and the composed 3-axis (root, group, member) 2x2x2
+    layout, bitwise-identical to the single-device bitmap engine."""
+    out = run_sub(PREAMBLE + f"""
+exch = {exchange!r}
+""" + """
 from repro.core.distributed_bfs import shard_graph
 from repro.core.heavy import padded_bitmap_words
 g, ev, core, chunks = sorted_graph(12, seed=11, threshold=32)
@@ -374,79 +367,32 @@ src, dst, valid = (np.asarray(t) for t in csr_to_edge_arrays(g))
 V = g.num_vertices
 single = plan_bfs(ev, g.degree, 0, core=core, chunks=chunks)
 w_base = padded_bitmap_words(V)
-for shape, part, exch in (((3, 1), "block", "hier_or_sieve"),
-                          ((1, 5), "word_cyclic", "hier_or_packed")):
+for shape, part in (((3, 1), "block"), ((1, 5), "word_cyclic")):
     p = shape[0] * shape[1]
     assert w_base % p != 0, (w_base, p)   # the case under test
     sg = shard_graph(src, dst, valid, V, p, partition=part)
     mesh = make_mesh(shape, ("group", "member"))
-    plan = BFSPlan(layout=("group", "member"), partition=part,
-                   exchange=exch, batch_roots=False)
+    plan = TraversalPlan(layout=("group", "member"), partition=part,
+                         exchange=exch, batch_roots=False)
     res = compile_plan(plan, PreparedGraph(core=core, sharded=sg,
                                            degree=g.degree),
                        mesh=mesh).bfs(0)
     parent, level = np.asarray(res.parent), np.asarray(res.level)
-    assert np.array_equal(parent[:V], np.asarray(single.parent)), (shape, exch)
-    assert np.array_equal(level[:V], np.asarray(single.level)), (shape, exch)
+    assert np.array_equal(parent[:V], np.asarray(single.parent)), shape
+    assert np.array_equal(level[:V], np.asarray(single.level)), shape
 
 # composed 3-axis layout: root batch outside the vertex-sharded program
 roots = np.asarray([0, 17], np.int32)
 base = plan_batch(ev, g.degree, roots, core=core, chunks=chunks)
 pg = PreparedGraph(ev=ev, degree=g.degree, core=core, chunks=chunks)
-for exch in ("hier_or_packed", "hier_or_sieve"):
-    plan = BFSPlan(layout=("root", "group", "member"), mesh_shape=(2, 2, 2),
-                   exchange=exch)
-    res = compile_plan(plan, pg).bfs(roots)
-    assert np.array_equal(np.asarray(res.parent)[:, :V],
-                          np.asarray(base.parent)), exch
-    assert np.array_equal(np.asarray(res.level)[:, :V],
-                          np.asarray(base.level)), exch
+plan = TraversalPlan(layout=("root", "group", "member"),
+                     mesh_shape=(2, 2, 2), exchange=exch)
+res = compile_plan(plan, pg).bfs(roots)
+assert np.array_equal(np.asarray(res.parent)[:, :V], np.asarray(base.parent))
+assert np.array_equal(np.asarray(res.level)[:, :V], np.asarray(base.level))
 print("OK")
 """)
     assert "OK" in out
-
-
-def test_codec_wire_bytes_drop_at_sparse_levels():
-    """Acceptance: modeled inter-group wire bytes at sparse levels
-    (frontier <= 256 vertices) drop >= 4x under the density-adaptive
-    codec vs raw hier_or at scale 12 on the 4x2 acceptance mesh, both
-    partitions.  Host-side: the level array comes from a numpy BFS, the
-    byte model from repro.core.distributed_bfs.modeled_wire_bytes."""
-    import numpy as np
-
-    from repro.core import build_csr, degree_reorder, generate_edges
-    from repro.core.distributed_bfs import modeled_wire_bytes
-    from repro.core.graph_build import csr_to_edge_arrays
-    from repro.core.heavy import padded_bitmap_words
-    from repro.core.reorder import relabel_edges
-
-    edges = generate_edges(11, 12)
-    g0 = build_csr(edges)
-    r = degree_reorder(g0.degree)
-    g = build_csr(relabel_edges(edges, r))
-    src, dst, valid = (np.asarray(t) for t in csr_to_edge_arrays(g))
-    src, dst = src[valid], dst[valid]
-    V = g.num_vertices
-    level = np.full(V, -1, np.int32)
-    level[0] = 0
-    t = 0
-    while True:
-        hit = level[src] == t
-        nxt = np.unique(dst[hit])
-        nxt = nxt[level[nxt] == -1]
-        if nxt.size == 0:
-            break
-        level[nxt] = t + 1
-        t += 1
-    w_loc = -(-padded_bitmap_words(V) // 8)
-    for part in ("block", "word_cyclic"):
-        wb = modeled_wire_bytes(level, n_devices=8, w_loc=w_loc,
-                                group=4, member=2, partition=part)
-        sparse = [p for p in wb["per_level"] if p["frontier"] <= 256]
-        assert sparse, ("no sparse level at scale 12", wb["per_level"])
-        for p in sparse:
-            assert p["inter"]["raw"] >= 4 * p["inter"]["post_codec"], (part, p)
-            assert p["inter"]["post_sieve"] <= p["inter"]["raw"], (part, p)
 
 
 def test_vertex_sharded_batched_roots():

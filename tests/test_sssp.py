@@ -331,7 +331,7 @@ def test_plan_kernel_axis_validation_and_shims():
         validate_plan(TraversalPlan(engine="reference", kernel="sssp"))
     with pytest.raises(ValueError, match="unknown exchange"):
         validate_plan(TraversalPlan(layout=("group", "member"),
-                                    exchange="hier_or_sieve", kernel="sssp"))
+                                    exchange="hier_gather", kernel="sssp"))
     # the generic default exchange normalizes to the kernel's family
     p = TraversalPlan(layout=("group", "member"), kernel="sssp")
     assert p.exchange == "hier_min"
@@ -341,7 +341,7 @@ def test_plan_kernel_axis_validation_and_shims():
     assert TraversalPlan.from_dict(d).kernel == "bfs"
     # re-kerneling keeps the layout but swaps an alien exchange family
     tuned = TraversalPlan(layout=("group", "member"), mesh_shape=(2, 2),
-                          exchange="hier_or_packed", partition="word_cyclic")
+                          exchange="hier_gather", partition="word_cyclic")
     rp = rekernel_plan(tuned, "sssp")
     assert (rp.kernel, rp.exchange, rp.partition) == \
         ("sssp", "hier_min", "word_cyclic")
@@ -523,6 +523,5 @@ def test_two_proc_sssp_parity(tmp_path):
         assert rung["identical"] is True, name
         assert rung["validated"] is True, name
         assert all(v == 0 for v in rung["check_counts"].values()), name
-        # BFS-level wire reconstruction does not apply to δ-rounds
-        assert rung["wire_bytes"] is None
+        # the BFS-level exchange replay does not apply to δ-rounds
         assert rung["exchange_seconds"] is None

@@ -15,7 +15,7 @@ import textwrap
 
 import pytest
 
-from repro.core.plan import BFSPlan
+from repro.core.plan import TraversalPlan
 from repro.core.tune import (
     BUDGETS,
     SCHEMA_VERSION,
@@ -48,12 +48,10 @@ def test_enumerate_small_budget_is_canonical_and_unique():
     # the hand-picked BENCH rung is in the sweep, so the ranked table
     # always positions the winner against it
     assert (("root", "group", "member"), (2, 2, 2)) in layouts
-    # exchange only varies where a member axis exists: the smoke budget
-    # sweeps the §12 wire-codec variants on vertex layouts and stays
-    # pinned to hier_or everywhere else
+    # the smoke budget sweeps the default exchange only, on vertex
+    # layouts as everywhere else
     vertexy = [p for p in plans if "member" in p.layout]
-    assert ({p.exchange for p in vertexy}
-            == {"hier_or", "hier_or_packed", "hier_or_sieve"})
+    assert {p.exchange for p in vertexy} == {"hier_or"}
     assert all(p.exchange == "hier_or" for p in plans
                if "member" not in p.layout)
     # the partition axis sweeps BOTH owner maps on vertex-sharded
@@ -71,8 +69,7 @@ def test_enumerate_small_budget_is_canonical_and_unique():
 def test_enumerate_full_budget_crosses_axes():
     plans = enumerate_plans(8, BUDGETS["full"])
     vertex = [p for p in plans if "member" in p.layout]
-    assert {p.exchange for p in vertex} == {
-        "hier_or", "hier_gather", "flat", "hier_or_packed", "hier_or_sieve"}
+    assert {p.exchange for p in vertex} == {"hier_or", "hier_gather", "flat"}
     assert {(p.alpha, p.beta) for p in plans} == set(BUDGETS["full"].alpha_beta)
     assert {p.n_chunks for p in plans} == set(BUDGETS["full"].n_chunks)
     # root-only layouts never multiply by the (dead) exchange axis
@@ -94,8 +91,8 @@ def test_sweep_skips_too_few_devices_not_crashes():
     any CI leg) is recorded as skipped with compile_plan's ValueError
     text, never raised."""
     plans = [
-        BFSPlan(layout=(), batch_roots=True),
-        BFSPlan(layout=("group", "member"), mesh_shape=(16, 16)),
+        TraversalPlan(layout=(), batch_roots=True),
+        TraversalPlan(layout=("group", "member"), mesh_shape=(16, 16)),
     ]
     report = sweep(8, budget="small", seed=3, n_roots=2, reps=1,
                    plans=plans, log=lambda s: None)
@@ -140,12 +137,12 @@ def _cost_model(compiled, roots, reps):
 
 def test_winner_deterministic_under_fixed_seed():
     plans = [
-        BFSPlan(layout=(), batch_roots=True, n_chunks=32),
-        BFSPlan(layout=(), batch_roots=True, n_chunks=64),
-        BFSPlan(layout=(), batch_roots=True, alpha=8.0, beta=64.0),
+        TraversalPlan(layout=(), batch_roots=True, n_chunks=32),
+        TraversalPlan(layout=(), batch_roots=True, n_chunks=64),
+        TraversalPlan(layout=(), batch_roots=True, alpha=8.0, beta=64.0),
         # same cost as the n_chunks=64 default plan -> exercises the
         # deterministic JSON tie-break
-        BFSPlan(layout=(), batch_roots=True, beta=32.0),
+        TraversalPlan(layout=(), batch_roots=True, beta=32.0),
     ]
     reports = [sweep(8, seed=7, n_roots=2, reps=1, plans=list(plans),
                      measure=_cost_model, log=lambda s: None)
@@ -164,7 +161,7 @@ def test_winner_deterministic_under_fixed_seed():
 # ---------------------------------------------------------------------------
 
 def _report(scale=12, n_devices=8, backend="cpu", plan=None):
-    plan = plan or BFSPlan(layout=("root",), mesh_shape=(4,))
+    plan = plan or TraversalPlan(layout=("root",), mesh_shape=(4,))
     return TuneReport(
         scale=scale, n_devices=n_devices, backend=backend,
         interpret_mode=True, budget="small", seed=1, n_roots=4, reps=2,
@@ -179,9 +176,10 @@ def test_table_round_trip_and_lookup(tmp_path):
     doc = load_table(path)
     assert doc["schema_version"] == SCHEMA_VERSION
     got = tuned_plan(12, 8, "cpu", path=path)
-    assert got == BFSPlan(layout=("root",), mesh_shape=(4,))
+    assert got == TraversalPlan(layout=("root",), mesh_shape=(4,))
     # second sweep at another key merges, not clobbers
-    save_tuned(_report(scale=14, plan=BFSPlan(layout=(), batch_roots=True)),
+    save_tuned(_report(scale=14,
+                       plan=TraversalPlan(layout=(), batch_roots=True)),
                path)
     assert tuned_plan(12, 8, "cpu", path=path) is not None
     assert tuned_plan(14, 8, "cpu", path=path).layout == ()
@@ -202,8 +200,8 @@ def test_schema_version_rejection(tmp_path):
         save_tuned(_report(scale=14), path)
     assert json.load(open(path))["schema_version"] == SCHEMA_VERSION + 1
     # from_dict itself rejects foreign plan fields
-    with pytest.raises(ValueError, match="unknown BFSPlan fields"):
-        BFSPlan.from_dict({"engine": "bitmap", "warp_speed": 9})
+    with pytest.raises(ValueError, match="unknown TraversalPlan fields"):
+        TraversalPlan.from_dict({"engine": "bitmap", "warp_speed": 9})
 
 
 def test_v1_schema_rejected_with_actionable_message(tmp_path):
@@ -242,9 +240,9 @@ def test_tuned_plan_fallback_when_no_entry_matches(tmp_path):
 def test_tuned_exchange_nearest_scale_and_default(tmp_path):
     path = str(tmp_path / "TUNED_PLANS.json")
     assert tuned_exchange(22, 256, path=path) == ("hier_or", "default")
-    save_tuned(_report(plan=BFSPlan(layout=("group", "member"),
-                                    mesh_shape=(2, 4),
-                                    exchange="hier_gather")), path)
+    save_tuned(_report(plan=TraversalPlan(layout=("group", "member"),
+                                          mesh_shape=(2, 4),
+                                          exchange="hier_gather")), path)
     ex, src = tuned_exchange(22, 256, path=path)
     assert ex == "hier_gather" and src == "tuned:nearest_scale12"
     # exact (scale, n_devices) hit — with and without a backend pin
@@ -264,8 +262,8 @@ def test_pipeline_tuned_rung_consumes_table(tmp_path, monkeypatch):
     from repro.core import Graph500Config
 
     path = str(tmp_path / "TUNED_PLANS.json")
-    table_plan = BFSPlan(layout=(), batch_roots=True, alpha=9.0, beta=48.0,
-                         n_chunks=32)
+    table_plan = TraversalPlan(layout=(), batch_roots=True, alpha=9.0,
+                               beta=48.0, n_chunks=32)
     save_tuned(_report(scale=10, n_devices=len(jax.devices()),
                        backend=jax.default_backend(), plan=table_plan), path)
     monkeypatch.setenv("REPRO_TUNED_PLANS", path)
@@ -278,7 +276,7 @@ def test_pipeline_tuned_rung_consumes_table(tmp_path, monkeypatch):
     assert cfg2.to_plan() == dataclasses.replace(table_plan, alpha=11.0)
     # no matching entry -> untuned derivation (single-device batch)
     cfg3 = Graph500Config.ladder("pre-g500-tuned", scale=9)
-    assert cfg3.to_plan() == BFSPlan(layout=(), batch_roots=True)
+    assert cfg3.to_plan() == TraversalPlan(layout=(), batch_roots=True)
     # explicit layout or mesh_shape bypasses the table entirely
     cfg4 = Graph500Config.ladder("pre-g500-tuned", scale=10, layout=())
     assert cfg4.to_plan().alpha == 14.0
@@ -306,9 +304,9 @@ def test_graph500_cell_tuned_variant(tmp_path, monkeypatch):
     from repro.util import make_mesh
 
     path = str(tmp_path / "TUNED_PLANS.json")
-    save_tuned(_report(plan=BFSPlan(layout=("group", "member"),
-                                    mesh_shape=(2, 4),
-                                    exchange="hier_gather")), path)
+    save_tuned(_report(plan=TraversalPlan(layout=("group", "member"),
+                                          mesh_shape=(2, 4),
+                                          exchange="hier_gather")), path)
     monkeypatch.setenv("REPRO_TUNED_PLANS", path)
     mesh = make_mesh((1, 1), ("data", "model"))
     plan = build_cell("graph500", "bfs_s22", mesh, variant="tuned")
