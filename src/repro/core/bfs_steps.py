@@ -9,8 +9,9 @@ engine's top-down use.  The bitmap engine's bottom-up reads the same
 slots as rows instead: the CSR is symmetric and src-sorted, so each
 unvisited ``x`` takes the min frontier ``u`` over its own contiguous
 slots ``(x, u)`` — a pull with one bit gather per slot and a dense
-segmented min, no scatter (``hybrid_bfs._pull_relax``).  Direction also
-shows in
+segmented min, no scatter (``hybrid_bfs._pull_relax``; SSSP's pass A
+pulls distances through the same scan, ``sssp_steps._pull_dist``).
+Direction also shows in
 
   * the kernelized bottom-up core step (``kernels/frontier_spmv``), which
     scans the dense heavy-vertex corner bitmap-wide with early-exit-free

@@ -26,7 +26,8 @@ Engines (the Fig. 18 ladder, DESIGN.md §3):
     work, DESIGN.md §3).
     Bottom-up, the tail edges are a *pull* over the src-sorted rows: each
     unvisited row takes the min frontier neighbour among its own slots,
-    a dense segmented min with no scatter (``_pull_relax``).
+    a dense segmented min with no scatter (``_pull_relax``; its scan,
+    ``_pull_rows``, is SSSP's pass A too).
 
 Everything is a single ``lax.while_loop`` under jit; per-level statistics
 (direction, frontier size, scanned edges, scanned chunks) land in
@@ -300,7 +301,7 @@ def _chunked_relax(chunks: ChunkedEdgeView, live, frontier_bm,
     )
 
 
-#: Slots per tile of the bottom-up pull's in-tile scan (at most; it is
+#: Slots per tile of the pull's in-tile scan (at most; it is
 #: ``gcd(chunk_size, PULL_TILE)``, so tiles always divide a chunk).
 PULL_TILE = 1024
 
@@ -331,34 +332,32 @@ def _row_ends(degree: jax.Array) -> jax.Array:
                      -1)
 
 
-def _pull_relax(chunks: ChunkedEdgeView, core_k: int | None, row_end,
-                frontier_bm, visited_bm, parent_ext, v):
-    """Bottom-up tail relax as a pull over the src-sorted rows.
+def _pull_rows(chunks: ChunkedEdgeView, planes, slot_val, fill, row_end,
+               done_bm=None):
+    """Each row's min of a per-slot value over its own src-sorted slots:
+    the pull's scan, which the bottom-up relax and SSSP's pass A share.
 
-    The CSR is symmetric and sorted by ``(src, dst)`` with padding at the
-    tail, and the tail mask (valid slots not inside the dense core) is
-    symmetric too, so the push ``parent[d] = min{s in frontier : (s, d)}``
-    over the tail equals, for each unvisited row ``x``, the min of the
-    frontier ``u`` over the row's own slots ``(x, u)`` (I4 holds by
-    symmetry).  Per slot that is one bit gather (frontier by ``dst``);
-    the min over each row's contiguous slots is a dense segmented scan:
-    in tiles of ``PULL_TILE`` slots, then over the tiles' last slots,
-    carried from chunk to chunk in the loop (hub rows span many tiles
-    and chunks).  Each row's min is read at its last slot ``row_end[x]``
-    (``_row_ends``), and the visited test is per row.
+    ``slot_val(s2, *p2)`` maps one chunk's tiles of ``chunks.src`` and of
+    each of ``planes`` (laid out like it) to each slot's value, ``fill``
+    where the slot offers none; the values take ``fill``'s dtype (a
+    Python int is ``int32``).  The min over each row's
+    contiguous slots is a dense segmented scan: in tiles of
+    ``PULL_TILE`` slots, then over the tiles' last slots, carried from
+    chunk to chunk in the loop (hub rows span many tiles and chunks).
+    Each row's min is read at its last slot ``row_end[x]``
+    (``_row_ends``); a row with no slot, or whose bit is set in the
+    packed ``done_bm``, reads ``fill``.
     """
     c_size = chunks.chunk_size
     tile = math.gcd(c_size, PULL_TILE)
+    dtype = jnp.asarray(fill).dtype
 
     def body(c, carry):
         rowmin, last_src, last_min = carry
-        s2, d2, v2 = (jax.lax.dynamic_index_in_dim(
+        s2, *p2 = (jax.lax.dynamic_index_in_dim(
             a, c, 0, keepdims=False).reshape(-1, tile)
-            for a in (chunks.src, chunks.dst, chunks.valid))
-        tail = v2 if core_k is None else v2 & ~((s2 < core_k)
-                                                & (d2 < core_k))
-        m2 = _segmented_min(
-            s2, jnp.where(tail & testbit(frontier_bm, d2), d2, v))
+            for a in (chunks.src, *planes))
+        m2 = _segmented_min(s2, slot_val(s2, *p2))
         # the running min of the row open at each tile's first slot
         t_src = jnp.concatenate([last_src[None], s2[:, -1]])
         t_min = _segmented_min(t_src,
@@ -371,14 +370,38 @@ def _pull_relax(chunks: ChunkedEdgeView, core_k: int | None, row_end,
 
     rowmin, _, _ = jax.lax.fori_loop(
         0, chunks.n_chunks, body,
-        (jnp.full((chunks.n_chunks * c_size // tile, tile), v, jnp.int32),
-         jnp.int32(-1), jnp.int32(v)))
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    visited = ((visited_bm[:, None] >> shifts) & jnp.uint32(1)
-               ).reshape(-1)[:v].astype(bool)
+        (jnp.full((chunks.n_chunks * c_size // tile, tile), fill, dtype),
+         jnp.int32(-1), jnp.asarray(fill, dtype)))
+    done = None
+    if done_bm is not None:
+        shifts = jnp.arange(32, dtype=jnp.uint32)
+        done = ((done_bm[:, None] >> shifts) & jnp.uint32(1)
+                ).reshape(-1)[:chunks.num_vertices].astype(bool)
     end = jnp.maximum(row_end, 0)
-    found = jnp.where((row_end >= 0) & ~visited,
-                      rowmin[end // tile, end % tile], v)
+    live = row_end >= 0 if done is None else (row_end >= 0) & ~done
+    return jnp.where(live, rowmin[end // tile, end % tile], fill)
+
+
+def _pull_relax(chunks: ChunkedEdgeView, core_k: int | None, row_end,
+                frontier_bm, visited_bm, parent_ext, v):
+    """Bottom-up tail relax as a pull over the src-sorted rows.
+
+    The CSR is symmetric and sorted by ``(src, dst)`` with padding at the
+    tail, and the tail mask (valid slots not inside the dense core) is
+    symmetric too, so the push ``parent[d] = min{s in frontier : (s, d)}``
+    over the tail equals, for each unvisited row ``x``, the min of the
+    frontier ``u`` over the row's own slots ``(x, u)`` (I4 holds by
+    symmetry).  Per slot that is one bit gather (frontier by ``dst``);
+    the min over each row is :func:`_pull_rows`' segmented scan, and the
+    visited test is per row.
+    """
+    def slot_val(s2, d2, v2):
+        tail = v2 if core_k is None else v2 & ~((s2 < core_k)
+                                                & (d2 < core_k))
+        return jnp.where(tail & testbit(frontier_bm, d2), d2, v)
+
+    found = _pull_rows(chunks, (chunks.dst, chunks.valid), slot_val, v,
+                       row_end, done_bm=visited_bm)
     return jnp.minimum(parent_ext, jnp.pad(found, (0, 1), constant_values=v))
 
 

@@ -12,9 +12,15 @@ BFS one with three substitutions (the kernel interface of §16):
     frontier/visited pair, and a ``uint32`` distance plane rides along
     (``INF_U32`` = unreached); the per-round frontier is *derived*: the
     changed vertices in the minimum δ-bucket.
-  * **relax rule** — each round runs one scatter-min pass, pass A, which
+  * **relax rule** — each round runs one relax pass, pass A, which
     min-relaxes distances (``dist[v] <- min(dist[v], dist[u] + w)`` over
-    frontier out-edges).  Parents are not tracked through the rounds:
+    frontier out-edges).  On one device it is a pull over the src-sorted
+    rows: the graph and its weights are symmetric, so each row takes the
+    min of ``dist[u] + w`` over its own slots to frontier ``u`` — one
+    gather per slot and a dense segmented min, no scatter
+    (:func:`_pull_dist`).  The vertex-sharded engine's slots are
+    dst-owned, so there pass A stays a scatter-min push.  Parents are not
+    tracked through the rounds:
     pass B runs once per search, after the round loop, and takes each
     vertex's parent as the *minimum source among edges achieving its
     final distance* — ``parent[v] = min{u : dist[u] + w(u,v) ==
@@ -46,7 +52,8 @@ exact machinery built for BFS.  ``BFSStats.levels`` counts the rounds and
 The engines name each phase of a round with ``jax.named_scope``, so a
 device trace charges every op to one: ``sssp.init``, ``sssp.select``
 (the changed set's minimum bucket gives the frontier; the next changed
-set and the round's counters), ``sssp.relax`` (pass A),
+set and the round's counters), ``sssp.relax`` (pass A: the pull, or the
+sharded engine's push),
 ``sssp.exchange`` (vertex-sharded engine only) in the round loop, and
 after it ``sssp.parent`` (pass B, once per search) and ``sssp.finish``.
 """
@@ -71,6 +78,8 @@ from repro.core.hybrid_bfs import (
     _axis_names_tuple,
     _exchange_delta,
     _pack_delta_words,
+    _pull_rows,
+    _row_ends,
     _shard_index,
 )
 from repro.kernels.ref import popcount_u32
@@ -127,6 +136,33 @@ def _min_source_parents(src, dst, valid, wgt, dist_src_ext, dist_dst_ext,
     return parent[:n]
 
 
+def _pull_dist(chunks: ChunkedEdgeView, row_end, front, dist):
+    """Pass A as a pull over the src-sorted rows: each row ``x`` takes
+    ``min(dist[x], min{dist[u] + w(x, u) : u in front, slot (x, u)})``.
+
+    The graph is symmetric and so are its weights (the build symmetrizes
+    and dedupes the edges, ``graph_build.edge_weights`` hashes the
+    unordered pair), so this equals the push of ``dist[u] + w`` over the
+    frontier's out-edges scatter-min'd into ``dist[v]``, bit for bit.
+    The frontier masks the distance plane once (``INF`` off it, and at
+    the padding slots' ``dst`` = V); then each slot does one gather from
+    it, and the min over each row is :func:`hybrid_bfs._pull_rows`'
+    dense segmented scan, with no scatter.
+    """
+    inf = jnp.uint32(_hier.INF_U32)
+    dist_front = jnp.concatenate(
+        [jnp.where(front, dist, inf), jnp.full((1,), inf)])
+
+    def slot_val(s2, d2, v2, w2):
+        g = dist_front[d2]
+        # masked before the add, which would wrap in uint32
+        return jnp.where(v2 & (g != inf), g + w2, inf)
+
+    return jnp.minimum(dist, _pull_rows(
+        chunks, (chunks.dst, chunks.valid, chunks.weight), slot_val, inf,
+        row_end))
+
+
 # ---------------------------------------------------------------------------
 # Single-device engine.
 # ---------------------------------------------------------------------------
@@ -152,14 +188,16 @@ def _run_sssp_impl(
     max_rounds: int,
     fault=None,
 ) -> BFSResult:
-    """One δ-stepping SSSP from ``root`` (single device, flat relax).
+    """One δ-stepping SSSP from ``root`` (single device, pull relax).
 
     SSSP frontiers are thin slices of one δ-bucket, but *which* chunk a
-    bucket touches is weight-dependent, not degree-ordered — so the
-    engine relaxes the flat edge view every round (the chunked layout is
-    reshaped back, exactly like the BFS bottom-up tail).  The heavy core
-    is not consulted: the dense-corner SpMV is a boolean-semiring step
-    with no weight plane.
+    bucket touches is weight-dependent, not degree-ordered — so pass A
+    reads every slot every round, as a pull over the src-sorted rows of
+    the chunked view (:func:`_pull_dist`, the BFS bottom-up's scan):
+    one gather of the frontier-masked distance plane per slot and a
+    dense segmented min per row, no scatter.  Pass B, once per search,
+    reads the flat view.  The heavy core is not consulted: the
+    dense-corner SpMV is a boolean-semiring step with no weight plane.
     """
     assert chunks.weight is not None, "SSSP needs a weighted ChunkedEdgeView"
     v = chunks.num_vertices
@@ -172,6 +210,7 @@ def _run_sssp_impl(
         valid = chunks.valid.reshape(-1)
         wgt = chunks.weight.reshape(-1)
         ids = jnp.arange(v, dtype=jnp.int32)
+        row_end = _row_ends(degree)   # for the pull
 
         dist = jnp.full((v,), _hier.INF_U32, jnp.uint32).at[root].set(
             jnp.uint32(0))
@@ -193,15 +232,9 @@ def _run_sssp_impl(
             frontier_bm = _pack_delta_words(front, w)
             popped_bm = s.changed_bm & ~frontier_bm
 
-        # Pass A: distance min-relax over frontier out-edges.
+        # Pass A: each row pulls its distance over its frontier neighbours.
         with jax.named_scope("sssp.relax"):
-            dist_ext = jnp.concatenate(
-                [s.dist, jnp.full((1,), _hier.INF_U32, jnp.uint32)])
-            active = valid & testbit(frontier_bm, jnp.clip(src, 0, v - 1))
-            cand = jnp.where(active, dist_ext[src] + wgt, inf)
-            tgt = jnp.where(active, dst, v)
-            new_dist_ext = dist_ext.at[tgt].min(cand)
-            new_dist = new_dist_ext[:v]
+            new_dist = _pull_dist(chunks, row_end, front, s.dist)
             improved = new_dist < s.dist
 
         # The next round's changed set: the popped rest plus every

@@ -317,6 +317,64 @@ def test_pipeline_sssp_equals_the_reference(spec_builds):
         assert int(res.stats.levels) >= (2 if degree[root] else 1)
 
 
+@pytest.mark.parametrize("case", ["pre-g500-s14", "hub"])
+def test_pass_a_pull_matches_push(case):
+    """Pass A's pull over the src-sorted rows gives the distances the
+    push ``dist.at[dst].min(dist[src] + w)`` over the frontier's slots
+    gives, on random frontiers over random distances with some vertices
+    unreached: the graph and its weights are symmetric."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import pipeline
+    from repro.core.hybrid_bfs import PULL_TILE, _row_ends
+    from repro.core.sssp_steps import _pull_dist
+    from test_graph500 import _hub_graph
+
+    if case == "pre-g500-s14":
+        cfg = pipeline.Graph500Config.ladder(
+            "pre-g500", scale=14, n_roots=1, heavy_threshold=None,
+            kernel="sssp")
+        built = pipeline.build(cfg)
+        chunks = compile_plan(cfg.to_plan(), built).graph.chunks
+        degree = built.degree
+    else:
+        g, _ = _hub_graph()
+        chunks = chunk_edge_view(with_edge_weights(edge_view(g), seed=5), 4)
+        degree = g.degree
+    v = chunks.num_vertices
+    src, dst, valid, wgt = (a.reshape(-1) for a in (
+        chunks.src, chunks.dst, chunks.valid, chunks.weight))
+    deg = np.asarray(degree)
+    assert (deg == 0).any() and not bool(valid[-1])    # padding slots
+    if case == "hub":
+        assert chunks.chunk_size % PULL_TILE == 0
+        assert deg[0] >= 3 * PULL_TILE and deg[0] > chunks.chunk_size
+
+    inf = np.uint32(0xFFFFFFFF)
+    row_end = _row_ends(degree)
+
+    @jax.jit
+    def push(front, dist):
+        dist_ext = jnp.concatenate([dist, jnp.full((1,), inf)])
+        active = valid & jnp.append(front, False)[src]
+        cand = jnp.where(active, dist_ext[src] + wgt, inf)
+        return dist_ext.at[jnp.where(active, dst, v)].min(cand)[:v]
+
+    pull = jax.jit(lambda front, dist: _pull_dist(chunks, row_end, front,
+                                                  dist))
+    rng = np.random.default_rng(0)
+    for _ in range(3):
+        reached = rng.random(v) < rng.uniform(0.1, 0.9)
+        dist = np.where(reached, rng.integers(0, 3 << 20, v), inf)
+        # a frontier holds reached vertices only (a bucket of them)
+        front = reached & (rng.random(v) < rng.uniform(0.05, 1.0))
+        args = (jnp.asarray(front), jnp.asarray(dist, jnp.uint32))
+        want = np.asarray(push(*args))
+        np.testing.assert_array_equal(np.asarray(pull(*args)), want)
+        assert (want < dist).any()   # the draw relaxed something
+
+
 # ---------------------------------------------------------------------------
 # Plan layer: the kernel axis
 # ---------------------------------------------------------------------------

@@ -256,6 +256,15 @@ def test_sssp_parents_built_once_after_the_round_loop(
                for i in comps[name])
 
 
+def test_sssp_pass_a_has_no_scatter(sssp_text, sssp_sharded_text):
+    """One device, pass A is a pull: a distance gather per slot and a
+    dense segmented min, with no scatter.  The vertex-sharded engine
+    still pushes, which shows the search would see one."""
+    assert not _scatters(sssp_text, "sssp.relax"), \
+        _scatters(sssp_text, "sssp.relax")
+    assert _scatters(sssp_sharded_text, "sssp.relax")
+
+
 def test_batched_program_keeps_one_copy_of_the_core(one_chip):
     """Batched over roots, the bottom-up step reads the dense core as it
     is: no instruction holds a per-root copy of it, which a vmapped
